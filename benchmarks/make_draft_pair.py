@@ -113,6 +113,9 @@ def acceptance_stats(params, cfg, dparams, dcfg, corpus, rng, *,
 
 
 def main():
+    from hpc_patterns_tpu import compile_cache
+
+    compile_cache.enable()
     on_tpu = jax.default_backend() == "tpu"
     out = arg("out", "draft_pair", str)
     steps = arg("steps", 400 if on_tpu else 30)

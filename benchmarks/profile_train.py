@@ -1,5 +1,5 @@
 """Step profile: trace N training steps and print the op_profile
-category breakdown (the table RESULTS.md quotes).
+category breakdown.
 
 Builds the same step as benchmarks/bench_train.py (same args), runs a
 warmup, traces a few steps with jax.profiler, and parses the trace via
@@ -62,6 +62,9 @@ def _print_tree(prog, min_pct=0.5, top_children=3):
 
 
 def main():
+    from hpc_patterns_tpu import compile_cache
+
+    compile_cache.enable()
     on_tpu = jax.default_backend() == "tpu"
     cfg = TransformerConfig(
         vocab=arg("vocab", 32768 if on_tpu else 256, int),

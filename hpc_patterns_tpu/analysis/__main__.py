@@ -2,9 +2,8 @@
 
 With no paths, analyzes the installed ``hpc_patterns_tpu`` package —
 the tree CI gates on. ``--ci`` exits 1 on any unsuppressed,
-unbaselined finding (0 on a clean tree), so the tier-1 suite and
-``benchmarks/reground_r5.sh`` can both gate on it; the default mode
-always exits 0 and just reports.
+unbaselined finding (0 on a clean tree), so the tier-1 suite can gate
+on it; the default mode always exits 0 and just reports.
 
 ``--log FILE`` appends the verdict as a ``kind=analysis`` RunLog
 record (rule counts, suppression count) to a JSONL log, where

@@ -21,7 +21,7 @@ Tree resolution (``tables_for``): a module under the live repo (an
 ancestor directory holding both ``bench.py`` and the
 ``hpc_patterns_tpu`` package) is judged against tables merged over
 the whole repo — package + ``bench.py`` + ``benchmarks/`` +
-``tests/`` (fixture corpora excluded). A module under a ``fixtures``
+``chip_smoke.py`` + ``tests/`` (fixture corpora excluded). A module under a ``fixtures``
 directory — or outside any repo root — is judged SELF-CONTAINED: its
 own file is the whole tree, which is what makes the bad/clean fixture
 twins reproducible without dragging the live tables in.
@@ -545,6 +545,7 @@ def tree_files(root: Path) -> list[tuple[Path, bool]]:
     out: list[tuple[Path, bool]] = []
     roots = [(root / "hpc_patterns_tpu", False),
              (root / "tests", False),
+             (root / "chip_smoke.py", False),
              (root / "bench.py", True),
              (root / "benchmarks", True)]
     for base, is_bench in roots:

@@ -33,7 +33,7 @@ matched; on mismatch the merge names the first divergent
 ``(rank, op, seq)``. Under ``apps/launch.py`` the chain additionally
 persists a tiny per-rank progress file on every record, so a TIMED-OUT
 rank's position is readable post-mortem — a hang reads as "rank 2 is
-at allreduce#17, rank 0 at sendrecv_ring#17" instead of a dead tunnel.
+at allreduce#17, rank 0 at sendrecv_ring#17" instead of a bare timeout.
 
 **Strict semaphores** (:func:`strict_semaphores`, behind
 ``dma-sem-balance``/``dma-slot-reuse``). The hazard is PR 8's

@@ -16,14 +16,77 @@ from hpc_patterns_tpu import topology
 from hpc_patterns_tpu.comm import Communicator
 
 
-def run_instrumented(run_fn: Callable[[object], int], args) -> int:
-    """The shared ``--metrics``/``--trace`` session every app main()
-    runs through: install a fresh process-wide metrics registry AND
-    flight recorder from the flags (both no-ops without their flag —
-    the disabled fast path), run the app, and on ANY exit path append
-    the closing snapshot records to ``--log``: one ``kind=metrics``
-    (aggregated by `python -m hpc_patterns_tpu.harness.report`) and one
-    ``kind=trace`` (exported to Chrome-trace JSON by `python -m
+def device_header() -> dict:
+    """What this process runs on, as jax reports it: versions,
+    ``platform``, ``device_kind`` and device count of the default
+    backend. Every app prints it as its first line and writes it as its
+    first ``--log`` record, so no result line is read without its
+    device."""
+    import jaxlib
+
+    try:
+        import libtpu
+
+        libtpu_version = libtpu.__version__
+    except ImportError:
+        libtpu_version = None
+    devices = jax.devices()
+    return {
+        "jax": jax.__version__,
+        "jaxlib": jaxlib.__version__,
+        "libtpu": libtpu_version,
+        "platform": devices[0].platform,
+        "device_kind": devices[0].device_kind,
+        "device_count": len(devices),
+    }
+
+
+def refuse_backend(args, log, devices=None) -> bool:
+    """The shared ``--backend`` check every app's ``run()`` makes:
+    ``--backend X`` given and the devices the app will use are not
+    platform X -> ``ERROR``/``FAILURE`` (True: the caller returns 1).
+    ``devices``: the ones the app placed explicitly; None for an app
+    that runs on jax's default device. Asking for the chip and not
+    getting it is an error, never a quiet run somewhere else."""
+    backend = getattr(args, "backend", None)
+    if not backend:
+        return False
+    if devices is None:
+        devices = jax.devices()[:1]
+    got = sorted({d.platform for d in devices})
+    if got and all(p.startswith(backend) for p in got):
+        return False
+    log.print(f"ERROR: --backend {backend} was asked for, but this run "
+              f"would use {got or 'no'} devices")
+    log.print("FAILURE")
+    return True
+
+
+def run_instrumented(run_fn: Callable[[object], int], args, *,
+                     join_rendezvous: bool = True) -> int:
+    """The shared session every app main() runs through.
+
+    Before the app: place the persistent compile cache
+    (``compile_cache.enable``), join a launcher rendezvous when one is
+    in the environment (apps/launch.py ≙ mpirun; init is the MPI_Init
+    analog and must precede the first device query —
+    ``join_rendezvous=False`` for an app whose ranks talk over their
+    own sockets), print the device header (:func:`device_header`) and,
+    with ``--log``, start the log with it as a ``kind=device`` record
+    (the app's own ``RunLog`` then appends).
+
+    Around the app: install a fresh process-wide metrics registry AND
+    flight recorder from ``--metrics``/``--trace`` (both no-ops without
+    their flag — the disabled fast path). A device discovery that finds
+    nothing of the platform asked for (``topology.TopologyError``) ends
+    the run with the apps' ``ERROR``/``FAILURE`` protocol, exit 1.
+
+    After the app, on ANY exit path: print which mode every Pallas
+    kernel was traced in and append the closing records to ``--log`` —
+    one ``kind=kernels`` (``ops.tiling.kernel_modes``: compiled vs
+    interpreted, per kernel), one ``kind=metrics`` (aggregated by
+    `python -m hpc_patterns_tpu.harness.report`) and one ``kind=trace``
+    (exported to Chrome-trace JSON by `python -m
     hpc_patterns_tpu.harness.trace`). Appending (never truncating)
     keeps the app's own records: the snapshots are the log's closing
     records, like run.sh's trailing grep summary.
@@ -34,8 +97,22 @@ def run_instrumented(run_fn: Callable[[object], int], args) -> int:
     ``--log`` — the launcher, not the child, owns the merged artifact),
     where the launcher collects every rank's ring for the clock-aligned
     merge (harness/collect.py)."""
+    from hpc_patterns_tpu import compile_cache
     from hpc_patterns_tpu.harness import metrics, trace
     from hpc_patterns_tpu.harness.runlog import RunLog
+    from hpc_patterns_tpu.ops import tiling
+
+    compile_cache.enable()
+    if join_rendezvous:
+        topology.init_distributed_from_env()
+    header = device_header()
+    print("device: " + " ".join(f"{k}={v}" for k, v in header.items()),
+          flush=True)
+    log_path = getattr(args, "log", None)
+    if log_path:
+        RunLog(log_path, truncate=not getattr(args, "log_append", False)
+               ).emit(kind="device", **header)
+        args.log_append = True  # the app appends below the header
 
     # mirror_traces stays off here: profiling.maybe_trace toggles it
     # (and restores it) around the actual traced region, so spans only
@@ -48,17 +125,26 @@ def run_instrumented(run_fn: Callable[[object], int], args) -> int:
                           **trace_kw)
     try:
         return run_fn(args)
+    except topology.TopologyError as e:
+        print(f"ERROR: {e}")
+        print("FAILURE")
+        return 1
     finally:
+        modes = tiling.kernel_modes()
+        if modes:
+            print("pallas kernels: " + " ".join(
+                k + "=" + "+".join(mode for mode, n in counts.items() if n)
+                for k, counts in modes.items()), flush=True)
         # ONE snapshot serves both sinks: the --log record and the
         # per-rank handoff file must carry identical events and clock
         # anchors (the offline re-merge from --log files and the
         # launcher's merge would otherwise disagree)
         trace_dir = os.environ.get(topology.ENV_TRACE_DIR)
         rec_snap = (rec.snapshot()
-                    if rec.enabled and (getattr(args, "log", None)
-                                        or trace_dir) else None)
-        if getattr(args, "log", None) and (m.enabled or rec.enabled):
-            log = RunLog(args.log, truncate=False)
+                    if rec.enabled and (log_path or trace_dir) else None)
+        if log_path:
+            log = RunLog(log_path, truncate=False)
+            log.emit(kind="kernels", modes=modes)
             if m.enabled:
                 log.emit(kind="metrics", **m.snapshot())
             if rec.enabled:
@@ -102,9 +188,7 @@ def make_communicator(
         # barrier = a cross-process allgather: no process receives the
         # gathered value before every process contributed, so the
         # returns cluster inside the release-propagation window. The
-        # same primitive reduce_across_processes uses — NOT
-        # sync_global_devices, whose jitted psum the CPU backend
-        # rejects for multiprocess computations on jax 0.4.x.
+        # same primitive reduce_across_processes uses.
         import numpy as np
         from jax.experimental import multihost_utils
 
@@ -121,6 +205,25 @@ def make_communicator(
         world -= 1
     mesh = topology.make_mesh({axis: world}, devices[:world])
     return Communicator(mesh, axis)
+
+
+def device_placement(*trees) -> list[dict]:
+    """Where the work actually sits: per addressable device, the bytes
+    of each tree's shards it holds and the allocator's
+    ``bytes_in_use`` — what shows that a mesh run is spread over its
+    devices rather than parked on one. One entry per device that holds
+    any shard, ``shard_bytes`` in ``trees`` order."""
+    held: dict = {}
+    for i, tree in enumerate(trees):
+        for leaf in jax.tree.leaves(tree):
+            for shard in leaf.addressable_shards:
+                per_tree = held.setdefault(shard.device, [0] * len(trees))
+                per_tree[i] += shard.data.nbytes
+    return [
+        {"device": d.id, "shard_bytes": per_tree,
+         "bytes_in_use": (d.memory_stats() or {}).get("bytes_in_use")}
+        for d, per_tree in sorted(held.items(), key=lambda kv: kv[0].id)
+    ]
 
 
 def allreduce_bus_bandwidth_gbps(nbytes: int, seconds: float, world: int) -> float:

@@ -145,11 +145,14 @@ def _onchip_supported(args, mode) -> bool:
     kinds = tuple(sorted(k.upper() for k in args.commands))
     import jax
 
+    # the kernel runs on the default device: that has to be a TPU, and
+    # --backend (when given) has to agree
     return (
         kinds in _ONCHIP_PAIRS
         and mode in ("serial", "async")
         and args.n_queues <= 1
         and jax.default_backend() == "tpu"
+        and args.backend in (None, "tpu")
     )
 
 
@@ -273,6 +276,8 @@ def run(args) -> int:
             return 1
         return run_onchip(args, log, mode)
     devices = topology.get_devices(args.backend)
+    if common.refuse_backend(args, log, devices):
+        return 1
     command_list, tune_info = build_commands(args, devices)
     names = [c.name for c in command_list]
     for key, info in tune_info.items():

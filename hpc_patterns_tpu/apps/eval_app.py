@@ -17,7 +17,6 @@ import sys
 import jax
 import jax.numpy as jnp
 
-from hpc_patterns_tpu import topology
 from hpc_patterns_tpu.apps import common
 from hpc_patterns_tpu.harness import RunLog, Verdict
 from hpc_patterns_tpu.harness import metrics as metricslib
@@ -57,7 +56,8 @@ def build_parser():
 
 def run(args) -> int:
     log = RunLog(args.log, truncate=not args.log_append)
-    topology.init_distributed_from_env()
+    if common.refuse_backend(args, log):
+        return 1
     try:
         cfg = TransformerConfig(
             vocab=args.vocab, d_model=args.d_model, n_heads=args.n_heads,

@@ -100,6 +100,10 @@ def _native_driver_leg(log, n: int) -> bool:
 
 def run(args) -> int:
     log = RunLog(args.log, truncate=not args.log_append)
+    # the staging leg picks a device of --backend; the device-side
+    # proofs run on the default device, so that is what must match
+    if common.refuse_backend(args, log):
+        return 1
     checks: list[tuple[str, bool]] = []
 
     if not native.available() and not native.build():
@@ -140,7 +144,7 @@ def run(args) -> int:
         log.print("SKIP: torch unavailable, torch bridge legs skipped")
 
     # 3. native memory -> accelerator and back (staged: DMA by physics)
-    dev = jax.devices(args.backend)[0] if args.backend else jax.devices()[0]
+    dev = jax.devices()[0]
     staged = jax.device_put(buf.as_numpy(), dev)
     tripled = np.asarray(_triple(staged))
     # compare in f32 with tolerance: exact f64 equality would fail for

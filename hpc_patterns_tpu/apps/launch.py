@@ -12,10 +12,15 @@ multi-host communication path (cross-process collectives, cross-process
 MAX timing) exercised for real, which the reference cannot do without a
 GPU cluster (SURVEY.md §4's gap).
 
-On an actual TPU pod this launcher is not needed: one process per host
-is started by the pod runtime and ``jax.distributed.initialize`` reads
-everything from the environment (topology.init_distributed with no
-args).
+The children are CPU workers by construction
+(``topology.cpu_worker_env`` pins ``JAX_PLATFORMS=cpu``): this launcher
+cannot start chip processes, and must not — a chip belongs to one
+process at a time. On a host with several chips ONE process drives all
+of them (``python -m hpc_patterns_tpu.apps.allreduce_app`` sees every
+chip as a rank; ``chip_smoke.py --chips 4`` runs that way). On an actual
+TPU pod one process per host is started by the pod runtime and
+``jax.distributed.initialize`` reads everything from the environment
+(topology.init_distributed with no args).
 
 Usage:
     python -m hpc_patterns_tpu.apps.launch -np 2 -- \
@@ -298,7 +303,7 @@ def _attempt(cmd, base_env, nprocs, args, trace_dir) -> tuple[
             if e:
                 # the collective-schedule fingerprint: a hang now reads
                 # as "rank 2 is at allreduce#17, rank 0 at
-                # sendrecv_ring#17" instead of a dead tunnel
+                # sendrecv_ring#17" instead of a bare timeout
                 print(f"  rank {pid}: is at {e['last']['op']}"
                       f"#{e['last']['seq']} ({e['n']} collective(s) "
                       f"issued, digest {e['digest']})")

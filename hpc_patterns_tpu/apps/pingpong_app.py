@@ -47,6 +47,8 @@ def run(args) -> int:
         log.print("FAILURE")
         return 1
     comm = common.make_communicator(args.backend, args.world, even=True)
+    if common.refuse_backend(args, log, comm.mesh.devices.flat):
+        return 1
     if comm.size < 2:
         log.print("SKIP: ping-pong needs >= 2 devices (even ranks, "
                   "allreduce-mpi-sycl.cpp:95-97)")

@@ -20,8 +20,7 @@ Two engine tiers behind one protocol:
   tests/test_launch.py).
 - real engines (default): each replica boots a small model
   (identically seeded, so ``request_key`` agrees across replicas) and
-  serves through :class:`~hpc_patterns_tpu.models.serving.EngineCore`
-  — the reground leg's shape.
+  serves through :class:`~hpc_patterns_tpu.models.serving.EngineCore`.
 
 Chaos composes through the launcher: ``--chaos
 'die:replica=2,at=5,site=replica_round'`` kills ONE replica of many
@@ -307,6 +306,11 @@ def _run_replica(args, rank: int, role: str) -> int:
 
 
 def run(args) -> int:
+    from hpc_patterns_tpu.apps import common
+    from hpc_patterns_tpu.harness import RunLog
+
+    if common.refuse_backend(args, RunLog()):
+        return 1
     pid = int(os.environ.get("HPCPAT_PROCESS_ID") or 0)
     nprocs = int(os.environ.get("HPCPAT_NUM_PROCESSES") or 1)
     if nprocs < 2:
@@ -342,7 +346,8 @@ def main(argv=None) -> int:
     from hpc_patterns_tpu.apps import common
 
     args = build_parser().parse_args(argv)
-    return common.run_instrumented(run, args)
+    # ranks talk over the plane's own sockets, not jax.distributed
+    return common.run_instrumented(run, args, join_rendezvous=False)
 
 
 if __name__ == "__main__":

@@ -8,7 +8,12 @@ sequence validated token-exact against its standalone
 ``paged_generate`` — greedy AND sampled (per-request key streams keep
 sampled serving standalone-exact); draft-assisted sampling is the one
 law-only combination (its distribution oracle lives in
-tests/test_serving.py). The reference's benchmark-IS-the-test
+tests/test_serving.py). On the TPU, where bf16 matmuls round
+differently at the engine's batch geometry than at B=1, a greedy
+stream that is not token-exact is judged by the teacher-forced
+precision law against the float32 reference instead
+(models/quantization.emitted_stream_law), and the result says which
+oracle held. The reference's benchmark-IS-the-test
 discipline (SURVEY.md §4: the binary measures its own claim and exits
 SUCCESS/FAILURE). Reports tokens/s, the admission-bubble fraction,
 and the prefill compile count (bounded by the bucket ladder); with
@@ -26,7 +31,6 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from hpc_patterns_tpu import topology
 from hpc_patterns_tpu.apps import common
 from hpc_patterns_tpu.harness import RunLog, Verdict
 from hpc_patterns_tpu.harness import metrics as metricslib
@@ -105,7 +109,8 @@ def build_parser():
 
 def run(args) -> int:
     log = RunLog(args.log, truncate=not args.log_append)
-    topology.init_distributed_from_env()
+    if common.refuse_backend(args, log):
+        return 1
     from hpc_patterns_tpu.models.decode import paged_generate
     from hpc_patterns_tpu.models.serving import ContinuousBatcher
 
@@ -144,8 +149,10 @@ def run(args) -> int:
         log.print("FAILURE")
         return 1
     # off-TPU serving takes the pure-XLA gather route on BOTH branches
-    # (the pallas kernels interpret per grid point there)
-    attn = "flash" if jax.default_backend() == "tpu" else "gather"
+    # (the pallas kernels interpret per grid point there); the result
+    # record names the route that ran
+    on_tpu = jax.devices()[0].platform == "tpu"
+    attn = "flash" if on_tpu else "gather"
     try:
         if args.draft_pair:
             import json
@@ -319,6 +326,26 @@ def run(args) -> int:
     # bound: cold compiles ≤ ladder rungs (x2 with a draft pair — the
     # draft prefill compiles per rung under its own config), and the
     # warm measured run adds none
+    law = None
+    if not exact and on_tpu and not sampled:
+        # bf16 on the MXU is not batch-geometry invariant: the 8-slot
+        # ragged engine and the B=1 standalone decode flip near-tie
+        # argmaxes against each other, so equality is not a law here.
+        # The law that is: every emitted token, teacher-forced through
+        # the float32 reference (stated as such in the result — this is
+        # the repo's precision law, not a loosened equality)
+        from hpc_patterns_tpu.models.quantization import (
+            emitted_stream_law,
+        )
+
+        law = emitted_stream_law(params, cfg, [p for p, _ in reqs],
+                                 [out[i] for i in range(len(reqs))])
+        try:
+            law.check()
+        except AssertionError as e:
+            log.print(f"PRECISION-LAW VIOLATION: {e}")
+            law = None
+    oracle = "exact" if exact else "law" if law is not None else "mismatch"
     max_compiles = (len(buckets) * (2 if spec else 1)
                     if buckets is not None else None)
     bounded = (compiles_warm == 0 and
@@ -327,8 +354,11 @@ def run(args) -> int:
         log.print(f"COMPILE-BOUND VIOLATION: {compiles_cold} cold + "
                   f"{compiles_warm} warm prefill compiles vs ladder "
                   f"bound {max_compiles} (warm must add none)")
-    ok = exact and bounded and served > 0
+    ok = oracle != "mismatch" and bounded and served > 0
     log.emit(kind="result", name="serve", success=ok,
+             decode_attn=attn, oracle=oracle,
+             law_greedy_agreement=law.greedy_agreement if law else None,
+             law_tv_max=law.tv_max if law else None,
              requests=args.requests, slots=args.slots,
              pool_pages=pool_pages, page_size=args.page_size,
              chunk=args.chunk, served_tokens=served,
@@ -347,7 +377,13 @@ def run(args) -> int:
               f"{compiles_cold} prefill compiles"
               f"{f' (ladder {len(buckets)})' if buckets else ''}"
               f"{f' +{compiles_warm} warm' if compiles_warm else ''}, "
+              f"decode_attn={attn}, "
               f"oracle[{mode}] {'ok' if exact else 'MISMATCH'}")
+    if law is not None:
+        log.print(f"oracle[precision law] ok: greedy agreement "
+                  f"{law.greedy_agreement:.4f} with the float32 "
+                  f"reference over {law.steps} teacher-forced steps; "
+                  f"largest TV distance a flip implies: {law.tv_max:.2e}")
 
     rtr = reqtracelib.active()
     if rtr is not None:

@@ -33,7 +33,7 @@ from hpc_patterns_tpu.comm.communicator import record_collective_bandwidth
 from hpc_patterns_tpu.harness import RunLog, Verdict, measure
 from hpc_patterns_tpu.harness import metrics as metricslib
 from hpc_patterns_tpu.harness.cli import add_msg_size_args, base_parser
-from hpc_patterns_tpu.topology import shard_map
+from jax import shard_map
 from hpc_patterns_tpu.harness.timing import blocking, max_across_processes
 
 
@@ -50,6 +50,8 @@ def build_parser():
 def run(args) -> int:
     log = RunLog(args.log, truncate=not args.log_append)
     comm = common.make_communicator(args.backend, args.world)
+    if common.refuse_backend(args, log, comm.mesh.devices.flat):
+        return 1
     mesh, axis = comm.mesh, comm.axis
     world = comm.size
     n = 1 << args.log2_elements  # global domain size (2**p, like -p)

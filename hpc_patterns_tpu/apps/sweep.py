@@ -45,10 +45,10 @@ def run(args) -> int:
     app_parser = concurrency_app.build_parser()
     modes = args.modes
     if modes is None:
-        import jax
+        from hpc_patterns_tpu import topology
 
-        single_tpu = (jax.default_backend() == "tpu"
-                      and len(jax.devices()) == 1)
+        devices = topology.get_devices(args.backend)
+        single_tpu = devices[0].platform == "tpu" and len(devices) == 1
         modes = ["async"] if single_tpu else ["async", "threads"]
     for commands in DEFAULT_MATRIX:
         for mode in modes:

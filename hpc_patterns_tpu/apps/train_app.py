@@ -303,6 +303,13 @@ def _train_loop(args, log, cfg, mesh, params, opt_state, step_fn, *,
                       f"sample {arr[0, :8].tolist()}")
 
     ok = finite and learned and resume_ok and generate_ok
+    # which devices hold the state and the batch (a mesh run must be
+    # spread over all of its devices)
+    placement = common.device_placement(params, tokens)
+    for p in placement:
+        log.print(f"device {p['device']}: params {p['shard_bytes'][0]:,} B"
+                  f", batch {p['shard_bytes'][1]:,} B, in use "
+                  f"{p['bytes_in_use']}")
     # steady state excludes the compile step
     steady = t_steps[1:] or t_steps
     step_s = min(steady)
@@ -312,6 +319,7 @@ def _train_loop(args, log, cfg, mesh, params, opt_state, step_fn, *,
         steps=args.steps, loss_first=losses[0], loss_last=losses[-1],
         step_time_s=step_s, tokens_per_s=tokens_per_s,
         mesh=dict(mesh.shape) if mesh else None,
+        placement=placement,
         attention=args.attention, checkpoint=ckpt_path,
         **result_extra,
     )
@@ -404,6 +412,8 @@ def _run_pp(args, log, cfg) -> int:
             axes["tp"] = tp  # innermost: tp rides nearest ICI neighbors
         mesh = topology.make_mesh(
             axes, devices[:max(dp, 1) * fs * args.pp * tp])
+    if common.refuse_backend(args, log, mesh.devices.flat):
+        return 1
     if args.batch % (args.microbatches * max(dp, 1) * fs):
         log.print(f"ERROR: --batch {args.batch} must divide by "
                   f"--microbatches*--dp*--fsdp = "
@@ -459,10 +469,10 @@ def _run_pp(args, log, cfg) -> int:
 
 def run(args) -> int:
     log = RunLog(args.log, truncate=not args.log_append)
-    # join a launcher rendezvous when present (apps/launch.py ≙ mpirun):
-    # the mesh below is then global and the train step is true
-    # multi-process SPMD — the multi-host training path, minus hardware
-    topology.init_distributed_from_env()
+    # under a launcher (apps/launch.py ≙ mpirun) run_instrumented has
+    # joined the rendezvous: the mesh below is then global and the
+    # train step is true multi-process SPMD — the multi-host training
+    # path, minus hardware
     if args.prefetch < 0:
         log.print(f"ERROR: --prefetch must be >= 0, got {args.prefetch}")
         log.print("FAILURE")
@@ -563,6 +573,10 @@ def run(args) -> int:
             if args.ep > 1:
                 axes["ep"] = args.ep
             mesh = topology.make_mesh(axes, devices[:n_mesh])
+    # a meshless run places nothing and lands on the default device
+    if common.refuse_backend(
+            args, log, mesh.devices.flat if mesh is not None else None):
+        return 1
 
     optimizer = _make_cli_optimizer(args, log)
     if optimizer is None:

@@ -28,7 +28,7 @@ from hpc_patterns_tpu.comm import collectives, fused, ring
 from hpc_patterns_tpu.harness import chaos as chaoslib
 from hpc_patterns_tpu.harness import metrics as metricslib
 from hpc_patterns_tpu.harness import trace as tracelib
-from hpc_patterns_tpu.topology import shard_map
+from jax import shard_map
 
 Algorithm = Literal["collective", "ring", "ring_chunked", "fused"]
 
@@ -237,7 +237,9 @@ class Communicator:
         mapped = shard_map(
             per_rank, mesh=mesh,
             in_specs=specs if len(specs) > 1 else specs[0],
-            out_specs=specs[0])
+            out_specs=specs[0],
+            check_vma=False,  # the Pallas interpreter's internals carry no vma
+        )
         if g is None:
             return jax.jit(mapped)
         idx = jnp.asarray(g.positions())

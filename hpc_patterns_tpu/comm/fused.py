@@ -50,10 +50,14 @@ Execution modes:
   pallaslint semaphore ledger (``analysis/pallas_rules.py``, review
   time) and the strict-semaphore shim the parity battery runs under
   (``analysis/runtime.strict_semaphores``, trace time); what stays
-  hardware-empirical is Mosaic's lowering and real DMA rates — the
-  documented reground step.
+  hardware-empirical is Mosaic's lowering and real DMA rates
+  (ROADMAP.md Speed 8).
 - **compiled** (TPU): the same kernel lowered by Mosaic; neighbor ids
-  ride ``DeviceIdType.LOGICAL`` scalars.
+  ride ``DeviceIdType.LOGICAL`` scalars. Not yet: jax 0.9.0 refuses to
+  lower these kernels on the chip (``collective_id has to be
+  unspecified or None when not using a custom barrier`` — they carry
+  an id but no barrier-semaphore handshake; ROADMAP.md Speed 8), and
+  the refusal propagates to the caller.
 
 Multi-axis meshes: jax's dma-discharge rule (and the LOGICAL id space)
 supports a single named mesh axis, so the kernels always run under a
@@ -90,7 +94,7 @@ from hpc_patterns_tpu.comm import ring
 from hpc_patterns_tpu.ops.tiling import (
     collective_id as _registered_collective_id,
     default_interpret,
-    tpu_compiler_params,
+    resolve_interpret,
 )
 
 #: reduce ops the fused ring implements. ``prod`` is deliberately
@@ -318,10 +322,9 @@ def fused_permute(x, axis: str, perm, *, interpret: bool | None = None,
     size = g.size
     perm = [(int(s), int(d)) for s, d in perm]
     ring.check_permutation(perm, size)
-    if interpret is None:
-        interpret = default_interpret()
     if size == 1:
         return x
+    interpret = resolve_interpret(interpret, "fused_permute")
     dst_table = [0] * size
     for s, d in perm:
         dst_table[s] = d
@@ -346,8 +349,8 @@ def fused_permute(x, axis: str, perm, *, interpret: bool | None = None,
                   pl.BlockSpec(memory_space=pltpu.VMEM)],
         out_specs=pl.BlockSpec(memory_space=pltpu.VMEM),
         scratch_shapes=[pltpu.SemaphoreType.DMA, pltpu.SemaphoreType.DMA],
-        compiler_params=tpu_compiler_params(has_side_effects=True,
-                                            collective_id=collective_id),
+        compiler_params=pltpu.CompilerParams(has_side_effects=True,
+                                             collective_id=collective_id),
         interpret=interpret,
     )(dsts, x2)
     return out.reshape(shape)
@@ -398,12 +401,9 @@ def fused_allreduce(x, axis: str, *, op: str = "sum",
     a multi-axis mesh (replica ranks reduce redundantly, bitwise-equal
     — the Communicator's multi-axis route)."""
     _check_op(op)
-    if interpret is None:
-        interpret = default_interpret()
     g = _resolve_geometry(axis, geometry)
     size = g.size
     shape = x.shape
-    m, n, cn, n_pad = ring_layout(shape, size, interpret=interpret)
     if size == 1:
         # same dtype discipline as the kernel path: bias joins in x's
         # dtype, the epilogue's result lands back in it
@@ -411,6 +411,8 @@ def fused_allreduce(x, axis: str, *, op: str = "sum",
         if epilogue is not None:
             out = epilogue(out)
         return out.astype(x.dtype)
+    interpret = resolve_interpret(interpret, "fused_allreduce")
+    m, n, cn, n_pad = ring_layout(shape, size, interpret=interpret)
     x2 = x.reshape(m, n)
     if n_pad != n:
         x2 = jnp.pad(x2, ((0, 0), (0, n_pad - n)))
@@ -512,7 +514,7 @@ def fused_allreduce(x, axis: str, *, op: str = "sum",
             pltpu.SemaphoreType.DMA((size - 1,)),
             pltpu.SemaphoreType.DMA((size - 1,)),
         ],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             has_side_effects=True,
             collective_id=_registered_collective_id(
                 "comm.fused.allreduce"),
@@ -557,8 +559,6 @@ def allgather_matmul(x, w, axis: str, *, interpret: bool | None = None,
             f"allgather_matmul wants x (m, k) @ w (k, n), got "
             f"{x.shape} @ {w.shape}"
         )
-    if interpret is None:
-        interpret = default_interpret()
     g = _resolve_geometry(axis, geometry)
     size = g.size
     m, k = x.shape
@@ -566,6 +566,7 @@ def allgather_matmul(x, w, axis: str, *, interpret: bool | None = None,
     if size == 1:
         return jnp.dot(x, w, preferred_element_type=jnp.float32
                        ).astype(x.dtype)
+    interpret = resolve_interpret(interpret, "allgather_matmul")
 
     def kernel(x_ref, w_ref, o_ref, buf, send_sem, recv_sem):
         me, dst = g.me_and_right()
@@ -602,7 +603,7 @@ def allgather_matmul(x, w, axis: str, *, interpret: bool | None = None,
             pltpu.SemaphoreType.DMA((size - 1,)),
             pltpu.SemaphoreType.DMA((size - 1,)),
         ],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             has_side_effects=True,
             collective_id=_registered_collective_id(
                 "comm.fused.allgather_matmul")),

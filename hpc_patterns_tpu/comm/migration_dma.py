@@ -27,6 +27,11 @@ back-copy is the source's own payload (the kernel is an exchange), is
 byte-inert, and keeps the kernel a single SPMD program — the form the
 dma-discharge interpreter and Mosaic's collective matcher both accept.
 
+On the chip the exchange kernel does not lower yet (jax 0.9.0 refuses a
+``collective_id`` without a barrier-semaphore handshake — ROADMAP.md
+Speed 8); that ``ValueError`` is not a :class:`MigrationDmaError`, so
+the router's fallback ladder does not swallow it.
+
 Entry points mirror the socket plane's (``serving_plane/service.py``):
 :func:`send_migration` runs on the dispatch side and returns the
 bundle re-homed to the destination device with ``transport="dma"``;
@@ -47,11 +52,9 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from hpc_patterns_tpu import topology
 from hpc_patterns_tpu.ops.tiling import (
     collective_id as _registered_collective_id,
-    default_interpret,
-    tpu_compiler_params,
+    resolve_interpret,
 )
 
 #: the transient 2-device mesh axis the send/recv pair binds
@@ -135,7 +138,7 @@ def _exchange_fn(src_device, dst_device, n_pages: int, row: int,
             out_specs=pl.BlockSpec(memory_space=pltpu.VMEM),
             scratch_shapes=[pltpu.SemaphoreType.DMA((chunks,)),
                             pltpu.SemaphoreType.DMA((chunks,))],
-            compiler_params=tpu_compiler_params(
+            compiler_params=pltpu.CompilerParams(
                 has_side_effects=True, collective_id=cid,
                 vmem_limit_bytes=_VMEM_LIMIT),
             interpret=interpret,
@@ -143,8 +146,10 @@ def _exchange_fn(src_device, dst_device, n_pages: int, row: int,
         return out[None]
 
     spec = P(MIGRATION_AXIS, None, None)
-    fn = jax.jit(topology.shard_map(local, mesh=mesh, in_specs=spec,
-                                    out_specs=spec))
+    fn = jax.jit(jax.shard_map(
+        local, mesh=mesh, in_specs=spec, out_specs=spec,
+        check_vma=False,  # the Pallas interpreter's internals carry no vma
+    ))
     sharding = NamedSharding(mesh, spec)
     _XFER_CACHE[key] = (fn, sharding)
     return fn, sharding
@@ -188,8 +193,7 @@ def send_migration(bundle, src_device, dst_device, *,
     ok, reason = dma_reachable(src_device, dst_device)
     if not ok:
         raise MigrationDmaError(f"not DMA-reachable: {reason}")
-    if interpret is None:
-        interpret = default_interpret()
+    interpret = resolve_interpret(interpret, "migration_dma")
     payload = {
         name: tuple(
             _transfer_array(a, src_device, dst_device,

@@ -31,13 +31,9 @@ from jax import lax
 
 
 def axis_size(axis: str) -> int:
-    """World size of a mesh axis, inside shard_map (MPI_Comm_size analog).
-    ``lax.psum(1, axis)`` on builds without ``lax.axis_size`` (0.4.x) —
-    a concrete reduction of a concrete 1, so it stays a Python int
-    (usable in loop bounds/shapes) on both routes."""
-    if hasattr(lax, "axis_size"):
-        return lax.axis_size(axis)
-    return lax.psum(1, axis)
+    """World size of a mesh axis, inside shard_map (MPI_Comm_size
+    analog) — a Python int, usable in loop bounds and shapes."""
+    return lax.axis_size(axis)
 
 
 def axis_index(axis: str):

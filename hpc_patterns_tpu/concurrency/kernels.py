@@ -24,6 +24,8 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from hpc_patterns_tpu.ops.tiling import resolve_interpret
+
 # FMAs per work-item per trip, matching the reference's unrolled factor 64
 # (sycl_con.cpp:29-31: eight outer * eight inner in the original).
 FMA_UNROLL = 8
@@ -69,9 +71,8 @@ def busy_wait(x, tripcount, *, interpret: bool | None = None):
     scalar: changing it does NOT recompile (the reference re-runs its
     autotuner the same way, sycl_con.cpp:257-268).
     """
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
-    return _busy_wait_call(x, tripcount, interpret=interpret)
+    return _busy_wait_call(
+        x, tripcount, interpret=resolve_interpret(interpret, "busy_wait"))
 
 
 def compute_buffer(n_elements: int, device=None):

@@ -48,6 +48,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from hpc_patterns_tpu.concurrency.kernels import FMA_UNROLL
+from hpc_patterns_tpu.ops.tiling import resolve_interpret
 
 MODES = (
     "overlap", "serial", "dma", "compute", "compute2",
@@ -320,8 +321,7 @@ def overlap_run(
     that compute — the oracle for tests)."""
     if mode not in MODES:
         raise ValueError(f"mode {mode!r} not in {MODES}")
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = resolve_interpret(interpret, f"overlap_pipeline.{mode}")
     if hbm_array.ndim != 3 or hbm_array.shape[2] != 128 or hbm_array.shape[1] % 8:
         raise ValueError(
             f"want (num_chunks, 8k rows, 128) float32, got {hbm_array.shape}"

@@ -6,12 +6,15 @@ use (mpi_datatype.hpp). The library is built by ``make -C native`` (or
 :func:`build` — loading never compiles as a side effect); when the .so
 is absent the module degrades gracefully (``available()`` → False,
 Python fallbacks take over), the reference's whole-GPU-fallback
-philosophy (devices.hpp:33-38).
+philosophy (devices.hpp:33-38). A .so that the ``hpcpat.cpp`` in this
+checkout did not produce counts as absent: the build stamps the library
+with its source's hash and :func:`_load` compares.
 """
 
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import subprocess
 from pathlib import Path
 
@@ -25,13 +28,15 @@ _load_failed = False
 
 
 def build() -> bool:
-    """Explicitly build the native library (``make -C native``). The
+    """Explicitly build the native library (``make -B -C native``:
+    this runs only when no loadable library exists, and a stale one can
+    be newer than its source, so make's timestamps are not asked). The
     only place a compiler run happens — loading never builds as a side
     effect, so a fresh checkout's first timing call stays cheap."""
     global _load_failed
     try:
         subprocess.run(
-            ["make", "-C", str(_NATIVE_DIR)],
+            ["make", "-B", "-C", str(_NATIVE_DIR)],
             check=True, capture_output=True, timeout=120,
         )
         _load_failed = False
@@ -47,6 +52,14 @@ def _load():
     try:
         if not _SO.exists():
             raise FileNotFoundError(f"{_SO} not built (run native.build())")
+        # the stamp is read from the file, not through dlopen: a stale
+        # library must never be mapped (and once mapped, the loader
+        # would hand the same image back after a rebuild)
+        want = hashlib.sha256(
+            (_NATIVE_DIR / "hpcpat.cpp").read_bytes()).hexdigest()[:16]
+        if want.encode() not in _SO.read_bytes():
+            raise RuntimeError(f"{_SO} was not built from this "
+                               "checkout's hpcpat.cpp")
         lib = ctypes.CDLL(str(_SO))
         lib.hp_stats.argtypes = [
             ctypes.POINTER(ctypes.c_double), ctypes.c_int64,

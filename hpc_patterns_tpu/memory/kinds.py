@@ -87,7 +87,8 @@ def memory_kind_placement_works(device=None,
     backend — the gate for one-way offloads (``offload_opt_state``):
     a backend that rejects the placement must return the input
     unchanged instead of paying a doomed transfer. Memoized per
-    (platform, kind)."""
+    (platform, kind). The TPU has the kind: a rejection there is an
+    error and propagates."""
     device = device if device is not None else jax.devices()[0]
     key = (device.platform, kind)
     if key not in _PLACEMENT_PROBE:
@@ -99,6 +100,8 @@ def memory_kind_placement_works(device=None,
             jax.block_until_ready(tiny)
             _PLACEMENT_PROBE[key] = True
         except Exception:
+            if device.platform == "tpu":
+                raise
             _PLACEMENT_PROBE[key] = False
     return _PLACEMENT_PROBE[key]
 
@@ -115,7 +118,8 @@ def memory_kind_transfers_work(device=None) -> bool:
     executes the SAME cached transfer program real copy commands and
     residency-manager pulls use (a fresh ``jax.jit`` here would
     re-trace on every probe — jaxlint: recompile-hazard — and prove a
-    different executable than the one that ships)."""
+    different executable than the one that ships). On the TPU a
+    failed round trip is an error and propagates."""
     device = device if device is not None else jax.devices()[0]
     key = device.platform
     if key not in _TRANSFER_PROBE:
@@ -128,5 +132,7 @@ def memory_kind_transfers_work(device=None) -> bool:
             jax.block_until_ready(moved)
             _TRANSFER_PROBE[key] = True
         except Exception:
+            if device.platform == "tpu":
+                raise
             _TRANSFER_PROBE[key] = False
     return _TRANSFER_PROBE[key]

@@ -50,7 +50,7 @@ from jax.sharding import PartitionSpec as P
 
 from hpc_patterns_tpu.harness import trace as tracelib
 from hpc_patterns_tpu.models.sharding_util import mesh_axis_size, resolve_spec
-from hpc_patterns_tpu.topology import shard_map
+from jax import shard_map
 from hpc_patterns_tpu.models.transformer import (
     TransformerConfig,
     _rmsnorm,

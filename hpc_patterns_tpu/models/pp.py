@@ -68,7 +68,7 @@ from jax.sharding import PartitionSpec as P
 
 import optax
 
-from hpc_patterns_tpu.topology import shard_map
+from jax import shard_map
 
 from hpc_patterns_tpu.models.transformer import (
     TransformerConfig,

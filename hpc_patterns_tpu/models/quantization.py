@@ -21,6 +21,14 @@ for draft-assisted sampling, applied to precision:
 quantized number (bounds in :data:`DEFAULT_BOUNDS`), and the tier-1
 tests pin the same bounds per precision (int8/fp8 KV, int8 weights,
 and the composed forms). docs/quantization.md has the full matrix.
+
+The same law judges a token stream an engine ALREADY emitted
+(:func:`emitted_stream_law`): on the TPU, bf16 matmuls round
+differently at different batch geometries, so the 8-slot ragged engine
+and a B=1 standalone decode flip near-tie argmaxes against each other
+(measured on a v5e at d=1024 with random weights: 4 of 8 sequences) —
+token identity between them is not a law there, teacher-forced
+agreement with the float32 reference is.
 """
 
 from __future__ import annotations
@@ -31,10 +39,13 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from functools import partial
+
 from hpc_patterns_tpu.models.decode import decode_step, prefill
 from hpc_patterns_tpu.models.transformer import (  # noqa: F401  (re-export)
     QUANT_SCALE_SUFFIX,
     TransformerConfig,
+    forward,
     matmul_weight,
     quantize_weights_int8,
 )
@@ -122,4 +133,68 @@ def precision_law(params_ref, cfg_ref: TransformerConfig, params_q,
         tv_mean=float(np.mean(tvs)),
         tv_max=float(np.max(tvs)),
         steps=steps,
+    )
+
+
+@partial(jax.jit, static_argnames=("cfg",))
+def _reference_walk(params, tokens, cfg: TransformerConfig):
+    """Per position: the float32 reference's argmax token, its
+    probability, and the full softmax — one dense causal forward, so
+    every position is judged on the exact prefix before it."""
+    with jax.default_matmul_precision("highest"):
+        logits = forward(params, tokens, cfg)
+    probs = jax.nn.softmax(logits, axis=-1)
+    return jnp.argmax(logits, axis=-1).astype(jnp.int32), probs
+
+
+def emitted_stream_law(params, cfg: TransformerConfig, prompts,
+                       emitted) -> PrecisionLaw:
+    """The precision law for token streams an engine already emitted:
+    each stream ``emitted[i]`` (greedy continuation of ``prompts[i]``)
+    is walked TEACHER-FORCED through the float32 reference — one dense
+    ``forward`` at ``attention="full"``, highest matmul precision, no
+    kernel, no cache, no batching in common with the engine — and every
+    emitted token is judged against the reference's argmax on the
+    engine's own prefix, so one near-tie flip costs one step, not the
+    rest of the stream.
+
+    ``greedy_agreement`` is the fraction of emitted tokens that ARE the
+    reference argmax. The engine's distributions are not observable, so
+    the TV entries are the lower bound a flip implies: where the engine
+    chose ``e`` over the reference's ``r``, any distribution with
+    argmax ``e`` is at least ``(p_ref(r) - p_ref(e)) / 2`` away from the
+    reference in total variation (0 where they agree). A stream from a
+    broken engine disagrees almost everywhere; rounding flips only
+    near-ties. Check with :meth:`PrecisionLaw.check`."""
+    ref_cfg = dataclasses.replace(cfg, dtype="float32", attention="full",
+                                  kv_cache_dtype="compute", remat=False)
+    rows = [np.concatenate([np.asarray(p, np.int32),
+                            np.asarray(e, np.int32)[:-1]])
+            for p, e in zip(prompts, emitted)]
+    width = max(len(r) for r in rows)
+    if width > ref_cfg.max_seq:
+        raise ValueError(
+            f"prompt + emitted {width + 1} exceeds max_seq "
+            f"{ref_cfg.max_seq}")
+    tokens = np.zeros((len(rows), width), np.int32)
+    for i, r in enumerate(rows):
+        tokens[i, :len(r)] = r  # right padding: causal, so inert
+    top, probs = _reference_walk(params, jnp.asarray(tokens), ref_cfg)
+    agree, tvs = [], []
+    for i, (p, e) in enumerate(zip(prompts, emitted)):
+        at = np.arange(len(e)) + len(p) - 1  # position predicting e[t]
+        e = np.asarray(e, np.int32)
+        ref = np.asarray(top[i, at])
+        p_row = probs[i, at]
+        margin = (np.asarray(p_row[np.arange(len(e)), ref])
+                  - np.asarray(p_row[np.arange(len(e)), e]))
+        agree.append(ref == e)
+        tvs.append(0.5 * margin)
+    agree = np.concatenate(agree)
+    tvs = np.concatenate(tvs)
+    return PrecisionLaw(
+        greedy_agreement=float(agree.mean()),
+        tv_mean=float(tvs.mean()),
+        tv_max=float(tvs.max()),
+        steps=int(agree.size),
     )

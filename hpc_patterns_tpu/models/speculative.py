@@ -2,8 +2,7 @@
 model verifies in one batched pass. Greedy AND sampling modes.
 
 The serving-latency play the KV-cache machinery enables: plain decode
-is one big-model forward per token (cache-read-bound,
-benchmarks/RESULTS.md); here a cheap draft model runs ``gamma``
+is one big-model forward per token (cache-read-bound); here a cheap draft model runs ``gamma``
 sequential steps and the target scores the whole proposed chunk with
 ONE ``decode.extend_step`` — large-matmul shapes instead of gamma
 sequential single-token reads. With greedy acceptance the output is

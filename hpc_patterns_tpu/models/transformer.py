@@ -22,7 +22,7 @@ Architecture choices driven by the hardware (SURVEY.md preamble +
   with a policy axis (``remat_policy``): the default "split" leaves the
   attention kernel outside any remat region so its custom_vjp
   residuals persist and the flash forward runs exactly once per step
-  (measured on chip — benchmarks/RESULTS.md "MFU push").
+  (builder-measured on an older toolchain — ROADMAP.md Design 9).
 
 Params are a plain pytree of f32 arrays (master weights); ``forward``
 casts to ``cfg.dtype`` (bf16 by default) at use.
@@ -41,7 +41,7 @@ from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import PartitionSpec as P
 
 from hpc_patterns_tpu.models.sharding_util import mesh_axis_size, resolve_spec
-from hpc_patterns_tpu.topology import shard_map
+from jax import shard_map
 from hpc_patterns_tpu.parallel.ring_attention import full_attention, ring_attention
 from hpc_patterns_tpu.parallel.ulysses import ulysses_attention
 
@@ -93,7 +93,8 @@ class TransformerConfig:
     # lax.scan body (fast compiles, the long-model default);
     # False unrolls the layer loop — each layer's weight slice becomes
     # static, XLA drops the per-iteration dynamic-slice copies of the
-    # weight stack and fuses better (measured on chip; see RESULTS.md)
+    # weight stack (builder-measured flat on an older toolchain —
+    # ROADMAP.md Design 9)
     scan_layers: bool = True
     # positional scheme: "learned" absolute table, or "rope" rotary
     # embeddings (relative; the long-context default — composes with

@@ -50,6 +50,8 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from hpc_patterns_tpu.ops.tiling import fit_block_pow2, resolve_interpret
+
 _NEG_INF = -1e30
 
 # NOTE on dimension_semantics: marking grid axes 0/1 "parallel" measured
@@ -352,12 +354,10 @@ def _fit_block(block, t):
     in :mod:`hpc_patterns_tpu.ops.tiling` (streamed kernels want big
     blocks; lengths that no 128-multiple divides still fail validation
     — pad upstream)."""
-    from hpc_patterns_tpu.ops.tiling import fit_block_pow2
-
     return fit_block_pow2(block, t)
 
 
-def _resolve(Tq, Tk, D, scale, block_q, block_k, interpret, *,
+def _resolve(Tq, Tk, D, scale, block_q, block_k, interpret, kernel, *,
              validate=True):
     """Resolve the shared per-call parameters (scale default, block
     fitting, interpret default). ``validate=False`` for the backward,
@@ -365,14 +365,11 @@ def _resolve(Tq, Tk, D, scale, block_q, block_k, interpret, *,
     must stay common so fwd and bwd never disagree on block sizes.
 
     ``block_q``/``block_k`` of None pick the defaults (512, 1024).
-    These were swept on chip at training shapes (benchmarks/RESULTS.md):
-    a standalone kernel microbench prefers (512, 512) at T=2048 by 26%,
-    but IN SITU — inside the full train step, competing with the
-    surrounding matmuls for VMEM and scheduling — (512, 1024) wins at
-    every measured shape. Round 3 re-confirmed at long T: standalone
-    fwd prefers (512, 2048) at T=8192 by 16% (133 vs 115 TF/s) and
-    LOSES in situ (175.9 vs 172.1 ms/step). Trust the end-to-end
-    number, not the microbench.
+    Builder-measured on an older toolchain (ROADMAP.md Design 9): a
+    standalone kernel microbench preferred (512, 512) at T=2048 and
+    (512, 2048) at T=8192, and both LOST inside the full train step,
+    where the kernel competes with the surrounding matmuls for VMEM
+    and scheduling. Trust the end-to-end number, not the microbench.
     """
     if scale is None:
         scale = 1.0 / (D ** 0.5)
@@ -386,9 +383,8 @@ def _resolve(Tq, Tk, D, scale, block_q, block_k, interpret, *,
         raise ValueError(
             f"seq ({Tq}, {Tk}) must divide by blocks ({block_q}, {block_k})"
         )
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
-    return float(scale), block_q, block_k, interpret
+    return (float(scale), block_q, block_k,
+            resolve_interpret(interpret, kernel))
 
 
 def _to_kernel_layout(x):
@@ -410,29 +406,23 @@ def _expand_rows(xr, B, Hkv, group):
 
 def _align_vma(*arrays):
     """Bring every array to the union of their varying-mesh-axes sets
-    (``lax.pvary``), so the kernels work inside ``shard_map``
+    (``lax.pcast`` to varying), so the kernels work inside ``shard_map``
     (check_vma=True) even when some inputs — e.g. the constant zero
-    offsets — are replicated. Returns (arrays, union_vma). On jax
-    builds without the varying-axes type machinery (no ``jax.typeof``
-    — e.g. 0.4.x, where shard_map's check is ``check_rep``) there is
-    nothing to align: arrays pass through with an empty vma."""
-    if not hasattr(jax, "typeof"):
-        return arrays, frozenset()
+    offsets — are replicated. Returns (arrays, union_vma); outside
+    ``shard_map`` the union is empty and nothing is cast."""
     vma = frozenset().union(*(jax.typeof(x).vma for x in arrays))
     out = tuple(
-        lax.pcast(x, tuple(vma - jax.typeof(x).vma), to='varying') if vma - jax.typeof(x).vma
-        else x
+        lax.pcast(x, tuple(vma - jax.typeof(x).vma), to="varying")
+        if vma - jax.typeof(x).vma else x
         for x in arrays
     )
     return out, vma
 
 
 def _sds(shape, dtype, vma):
-    """``jax.ShapeDtypeStruct`` with the varying-axes set — omitted on
-    jax builds whose ShapeDtypeStruct predates the ``vma`` kwarg."""
-    if hasattr(jax, "typeof"):
-        return jax.ShapeDtypeStruct(shape, dtype, vma=vma)
-    return jax.ShapeDtypeStruct(shape, dtype)
+    """``pallas_call`` out_shape entry declaring its varying mesh axes
+    (``shard_map``'s vma check refuses an undeclared one)."""
+    return jax.ShapeDtypeStruct(shape, dtype, vma=vma)
 
 
 def _masked_scores(qr, kr, offs, scale, causal):
@@ -501,7 +491,7 @@ def _forward_impl(q, k, v, offs, *, causal, scale, block_q, block_k,
         )
     group = H // Hkv
     scale, block_q, block_k, interpret = _resolve(
-        Tq, Tk, D, scale, block_q, block_k, interpret
+        Tq, Tk, D, scale, block_q, block_k, interpret, "flash_attention.fwd"
     )
 
     qr, kr, vr = map(_to_kernel_layout, (q, k, v))
@@ -615,7 +605,7 @@ def _backward_impl(qr, kr, vr, outr, lse, offs, g, g_lse, *, causal, scale,
         Tq, Tk, D, scale,
         block_q if block_q_bwd is None else block_q_bwd,
         block_k if block_k_bwd is None else block_k_bwd,
-        interpret, validate=False,
+        interpret, "flash_attention.bwd", validate=False,
     )
 
     dor = _to_kernel_layout(g)

@@ -3,9 +3,7 @@
 The serving-side analog of ops/flash_attention.py (the framework rule:
 hot loops are Pallas — docs/ARCHITECTURE.md; reference analog: the
 own-the-hot-loop principle of concurency/sycl_con.cpp:26-33). A decode
-step is cache-read-bound — the framework's own measurement proved GQA's
-full n_heads/kv_heads bandwidth saving shows up end-to-end
-(benchmarks/RESULTS.md "KV-cache decoding") — so the kernel's job is to
+step is cache-read-bound, so the kernel's job is to
 make exactly one streamed pass over the *live* prefix of the cache:
 
 - grid = (batch·kv_heads, S_max/BLOCK_S): each step loads one
@@ -37,6 +35,8 @@ import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from hpc_patterns_tpu.ops.tiling import resolve_interpret
 
 _NEG_INF = -1e30
 
@@ -158,8 +158,7 @@ def flash_decode_attention(
     if scale is None:
         scale = 1.0 / (D ** 0.5)
     block_s = min(block_s, S)
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = resolve_interpret(interpret, "flash_decode")
     g = H // Hkv
 
     quantized = k_scale is not None
@@ -325,8 +324,7 @@ def flash_decode_paged(
         raise ValueError(f"table rows {table.shape[0]} != batch {B}")
     if scale is None:
         scale = 1.0 / (D ** 0.5)
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = resolve_interpret(interpret, "flash_decode_paged")
     g = H // Hkv
 
     quantized = k_scale_pool is not None

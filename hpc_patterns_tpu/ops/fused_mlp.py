@@ -1,9 +1,9 @@
 """Fused transformer MLP (matmul → gelu → matmul) as Pallas TPU kernels.
 
-The round-3 step profile put 57.4% of the headline training step in
-matmul fusions running at ~50% MXU utilization while the same shapes
-hit 82-97% isolated (benchmarks/RESULTS.md) — the MLP block is most of
-that time. This kernel applies the framework's own-the-hot-loop rule
+A builder's step profile on an older toolchain put most of the
+training step in matmul fusions running well under the rate the same
+shapes reach in isolation (ROADMAP.md Speed 6, not re-measured) — the
+MLP block is most of that time. This kernel applies the framework's own-the-hot-loop rule
 (docs/ARCHITECTURE.md; reference analog concurency/sycl_con.cpp:26-33)
 to the d_ff block:
 
@@ -39,9 +39,8 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from hpc_patterns_tpu.ops.tiling import (
-    default_interpret,
     fit_block_divisor as _fit_block,
-    tpu_compiler_params as _compiler_params,
+    resolve_interpret,
 )
 
 _SQRT_2_OVER_PI = 0.7978845608028654
@@ -135,14 +134,12 @@ def _bwd_kernel(x_ref, dy_ref, w1_ref, w2_ref, dxs_ref, dw1_ref, dw2_ref,
         dw2_ref[...] = dw2_acc[...]
 
 
-def _resolve(N, D, F, block_t, block_f, interpret):
+def _resolve(N, D, F, block_t, block_f, interpret, kernel):
     # block fitting + interpret default live in ops.tiling, shared with
     # the flash and fused-collective kernels
     block_t = _fit_block(N, block_t)
     block_f = _fit_block(F, block_f)
-    if interpret is None:
-        interpret = default_interpret()
-    return block_t, block_f, interpret
+    return block_t, block_f, resolve_interpret(interpret, kernel)
 
 
 def _fwd_kernel_save_a(x_ref, w1_ref, w2_ref, o_ref, a_ref, acc_ref):
@@ -152,7 +149,8 @@ def _fwd_kernel_save_a(x_ref, w1_ref, w2_ref, o_ref, a_ref, acc_ref):
 def _forward(x2, w1, w2, block_t, block_f, interpret, save_a=False):
     N, D = x2.shape
     F = w1.shape[1]
-    bt, bf, interpret = _resolve(N, D, F, block_t, block_f, interpret)
+    bt, bf, interpret = _resolve(N, D, F, block_t, block_f, interpret,
+                                 "fused_mlp.fwd")
     out_specs = pl.BlockSpec((bt, D), lambda t, f: (t, 0),
                              memory_space=pltpu.VMEM)
     out_shape = jax.ShapeDtypeStruct((N, D), x2.dtype)
@@ -178,7 +176,7 @@ def _forward(x2, w1, w2, block_t, block_f, interpret, save_a=False):
         scratch_shapes=[pltpu.VMEM((bt, D), jnp.float32)],
         # big token blocks (f32 acc + double-buffered panels) can pass
         # Mosaic's 16 MB default scoped limit; physical VMEM is larger
-        compiler_params=_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=64 * 1024 * 1024,
         ),
         interpret=interpret,
@@ -188,7 +186,8 @@ def _forward(x2, w1, w2, block_t, block_f, interpret, save_a=False):
 def _backward(x2, w1, w2, dy2, block_t, block_f, interpret):
     N, D = x2.shape
     F = w1.shape[1]
-    bt, bf, interpret = _resolve(N, D, F, block_t, block_f, interpret)
+    bt, bf, interpret = _resolve(N, D, F, block_t, block_f, interpret,
+                                 "fused_mlp.bwd")
     n_f = F // bf
     dx_slab, dw1, dw2 = pl.pallas_call(
         _bwd_kernel,
@@ -223,7 +222,7 @@ def _backward(x2, w1, w2, dy2, block_t, block_f, interpret):
         # block set + f32 dW accumulators legitimately need ~18-24 MB
         # of VMEM at the flagship shape — above Mosaic's 16 MB default
         # scoped limit, well under the physical budget
-        compiler_params=_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=64 * 1024 * 1024,
         ),
         interpret=interpret,
@@ -255,7 +254,7 @@ def _backward_xla(x2, w1, w2, dy2):
 
 
 def _bwd_mode() -> str:
-    """Backward strategy (env knob, measured in benchmarks/RESULTS.md):
+    """Backward strategy (env knob):
 
     - "kernel": the one-pass fused backward kernel (5 matmuls,
       partial-dx slab) — residuals (x, w1, w2) only, lowest memory;

@@ -52,6 +52,8 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from hpc_patterns_tpu.ops.tiling import resolve_interpret
+
 # the mask constant the bitwise route-parity contract depends on; must
 # equal parallel.ring_attention._NEG_INF (importing it here is circular
 # via comm.ring -> ops; tests/test_quantization.py pins the equality)
@@ -155,8 +157,7 @@ def paged_attention_decode(
             "agree)")
     if scale is None:
         scale = 1.0 / (D ** 0.5)
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = resolve_interpret(interpret, "paged_attention")
     g = H // Hkv
 
     qr = q.reshape(B * Hkv, g, D)

@@ -1,12 +1,10 @@
 """Shared Pallas tiling/lowering helpers.
 
-Every Pallas call site in the tree had grown its own copy of three
-decisions — how to shrink a requested block to fit an off-size length,
-when to fall back to interpret mode, and how to spell
-``CompilerParams`` across the ``TPUCompilerParams`` rename
-(``ops/fused_mlp.py``, ``ops/flash_attention.py``, and now
-``comm/fused.py``). One module owns them so a kernel added tomorrow
-cannot disagree with the kernels that exist today.
+Every Pallas call site in the tree shares three decisions — how to
+shrink a requested block to fit an off-size length, whether the kernel
+runs compiled or interpreted (and the record of which it was), and
+which ``collective_id`` it carries. One module owns them so a kernel
+added tomorrow cannot disagree with the kernels that exist today.
 
 The module also owns the **collective-id registry**
 (:func:`collective_id`): every remote-DMA kernel that may run
@@ -83,38 +81,36 @@ def registered_collective_ids() -> dict[str, int]:
     pinned historical assignments)."""
     return dict(_COLLECTIVE_IDS)
 
-# CompilerParams was TPUCompilerParams before the pallas.tpu rename;
-# bind whichever this jax build exports
-_COMPILER_PARAMS_CLS = getattr(pltpu, "CompilerParams", None) or getattr(
-    pltpu, "TPUCompilerParams")
-
-#: kwargs the older TPUCompilerParams class rejects — dropped with a
-#: best-effort retry so one call shape serves both jax generations
-_OPTIONAL_PARAMS = ("collective_id", "has_side_effects")
-
-
-def tpu_compiler_params(**kwargs):
-    """``pltpu.CompilerParams(**kwargs)`` tolerant of the class rename
-    AND of fields the older class lacks (``collective_id`` /
-    ``has_side_effects`` are required for remote-DMA kernels on newer
-    builds but unknown to some 0.4.x ones)."""
-    kwargs = dict(kwargs)
-    while True:
-        try:
-            return _COMPILER_PARAMS_CLS(**kwargs)
-        except TypeError:
-            for name in _OPTIONAL_PARAMS:
-                if name in kwargs:
-                    del kwargs[name]
-                    break
-            else:
-                raise
-
 
 def default_interpret() -> bool:
     """The tree-wide interpret default: compiled on TPU, interpreted
     everywhere else (the 8-device CPU mesh the test suite runs on)."""
     return jax.default_backend() != "tpu"
+
+
+#: kernel name -> {"compiled": n, "interpret": n} — how many times each
+#: Pallas wrapper was TRACED in each mode this process (tracing happens
+#: once per jit compile, persistent-cache hits included). The apps
+#: print it and write it to ``--log`` so a run can prove which mode
+#: reached every kernel instead of inferring it from the platform.
+_KERNEL_MODES: dict[str, dict[str, int]] = {}
+
+
+def resolve_interpret(interpret: bool | None, kernel: str) -> bool:
+    """Resolve a wrapper's ``interpret=None`` through
+    :func:`default_interpret` and record the mode ``kernel`` was traced
+    in — the single place a Pallas wrapper decides compiled vs
+    interpreted."""
+    if interpret is None:
+        interpret = default_interpret()
+    modes = _KERNEL_MODES.setdefault(kernel, {"compiled": 0, "interpret": 0})
+    modes["interpret" if interpret else "compiled"] += 1
+    return interpret
+
+
+def kernel_modes() -> dict[str, dict[str, int]]:
+    """Snapshot of the per-kernel trace-mode counts."""
+    return {k: dict(v) for k, v in sorted(_KERNEL_MODES.items())}
 
 
 def fit_block_divisor(n: int, cap: int) -> int:

@@ -85,8 +85,8 @@ def _scatter_dispatch(x, gates, n_experts: int, capacity: int,
                       top_k: int):
     """Sort/scatter routing: the O(N·D + E·C·D) replacement for the
     one-hot einsum dispatch, whose (N, E, C) tensors are O(N²·cf/E)
-    and OOM a 16 GB chip near 16k tokens (measured — RESULTS.md
-    "MoE top-k rows"). Same assignment semantics as the einsum path by
+    and OOM a 16 GB chip near 16k tokens (builder-measured on an older
+    toolchain). Same assignment semantics as the einsum path by
     construction: a STABLE argsort of the choice-major expert ids gives
     each (token, choice) the same within-expert rank the cumsum
     formulation computes, so the kept set and slot layout are

@@ -42,10 +42,6 @@ def _varying(tree, axis: str):
     axis, so they can carry through a lax.scan whose body mixes them
     with genuinely per-rank values (ring hops, rank-masked updates) —
     scan requires carry-in and carry-out VMA types to match."""
-    if not hasattr(lax, "pcast"):
-        # pre-vma jax (0.4.x): shard_map's check is check_rep and scan
-        # carries no varying-axes types — nothing to mark
-        return tree
     return jax.tree.map(lambda a: lax.pcast(a, (axis,), to="varying"), tree)
 
 

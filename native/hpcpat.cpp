@@ -17,7 +17,17 @@
 #include <cstdlib>
 #include <cstring>
 
+// The Makefile stamps the library with the hash of THIS file, and the
+// loader (interop/native.py) looks for the hash of its checkout's
+// source in the library's bytes before mapping it — a binary left over
+// from other sources is never loaded.
+#ifndef HPCPAT_SOURCE_ID
+#define HPCPAT_SOURCE_ID "unstamped"
+#endif
+
 extern "C" {
+
+const char* hp_source_id() { return HPCPAT_SOURCE_ID; }
 
 // ---- timing statistics engine (≙ the min-of-reps protocol every app
 // hand-rolls, sycl_con.cpp:101-119) ------------------------------------

@@ -982,7 +982,7 @@ class TestStrictSemaphores:
         from jax.experimental import pallas as pl
         from jax.experimental.pallas import tpu as pltpu
         from jax.sharding import PartitionSpec as P
-        from hpc_patterns_tpu.topology import shard_map
+        from jax import shard_map
 
         x = jnp.arange(8 * 2 * 8, dtype=jnp.float32).reshape(16, 8)
 
@@ -997,8 +997,9 @@ class TestStrictSemaphores:
                 interpret=True,
             )(v)
 
+        # interpreted kernels carry no vma types (see test_fused_comm)
         f = jax.jit(shard_map(run, mesh=mesh8, in_specs=P("x"),
-                              out_specs=P("x")))
+                              out_specs=P("x"), check_vma=False))
         return jax.block_until_ready(f(x))
 
     def test_balanced_kernel_passes_and_is_counted(self, mesh8):

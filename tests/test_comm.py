@@ -8,7 +8,7 @@ reference's: allreduce of rank-valued buffers == size(size-1)/2
 
 import jax
 
-from hpc_patterns_tpu.topology import shard_map
+from jax import shard_map
 import jax.numpy as jnp
 import numpy as np
 import pytest

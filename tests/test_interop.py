@@ -130,7 +130,9 @@ class TestDeviceAliasing:
 
 
 class TestInteropApp:
-    def test_app_passes(self, capsys):
+    def test_app_passes(self, capsys, tmp_path):
+        import json
+
         from hpc_patterns_tpu.apps import interop_app
 
         try:
@@ -139,11 +141,25 @@ class TestInteropApp:
             min_passed = 7
         except ImportError:
             min_passed = 5
-        code = interop_app.main(["-n", "4096"])
+        log = tmp_path / "run.jsonl"
+        code = interop_app.main(["-n", "4096", "--log", str(log)])
         out = capsys.readouterr().out
         assert code == 0, out
         assert "SUCCESS" in out
         assert out.count("Passed") >= min_passed
+        # every app names its device first — on stdout and in the log —
+        # and closes the log with the mode each Pallas kernel ran in
+        first = out.splitlines()[0]
+        assert first.startswith("device: jax=")
+        for field in ("jaxlib=", "libtpu=", "platform=cpu",
+                      "device_kind=cpu", "device_count=8"):
+            assert field in first
+        recs = [json.loads(l) for l in log.read_text().splitlines()]
+        assert recs[0]["kind"] == "device"
+        assert recs[0]["device_count"] == 8
+        modes = [r for r in recs if r["kind"] == "kernels"][-1]["modes"]
+        assert modes["pallas_alias_proof"]["interpret"] >= 1
+        assert modes["pallas_alias_proof"]["compiled"] == 0
 
     @pytest.mark.slow  # compiles + embeds CPython, runs XLA in-process
     def test_native_driver_leg(self, capsys):

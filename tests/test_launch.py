@@ -523,8 +523,7 @@ class TestServingPlaneLaunch:
     processes, real sockets, real trace/schedule recording — stub
     token generators, so the router's mechanics (placement, KV-handoff
     forwarding, replica death recovery, shed accounting) run tier-1 in
-    seconds. The real-engine shape of the same path is the reground
-    step-7d leg."""
+    seconds."""
 
     def test_disaggregated_stub_plane_traced_merge(self, tmp_path,
                                                    capsys):

@@ -9,7 +9,7 @@ import pytest
 
 import jax
 
-from hpc_patterns_tpu.topology import shard_map
+from jax import shard_map
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 

@@ -564,3 +564,48 @@ class TestRefusalsAndProbe:
             dtypes._FP8_SUPPORT = prev
         with pytest.raises(Exception, match="kv-dtype"):
             resolve_kv_cache_dtype("int4")
+
+
+class TestEmittedStreamLaw:
+    """The precision law applied to a stream an engine already emitted
+    (what serve_app falls back to on the TPU, where bf16 rounding is
+    not batch-geometry invariant)."""
+
+    def _setup(self):
+        import numpy as np
+
+        from hpc_patterns_tpu.models import TransformerConfig, init_params
+        from hpc_patterns_tpu.models.decode import paged_generate
+
+        cfg = TransformerConfig(vocab=256, d_model=64, n_heads=4,
+                                n_layers=2, d_ff=256, max_seq=48,
+                                decode_attn="gather")
+        params = init_params(jax.random.PRNGKey(0), cfg)
+        rng = np.random.RandomState(0)
+        prompts = rng.randint(0, 256, size=(3, 9)).astype(np.int32)
+        outs = np.asarray(paged_generate(
+            params, jnp.asarray(prompts), cfg, 16, page_size=16))
+        return params, cfg, list(prompts), list(outs), rng
+
+    def test_greedy_stream_agrees_and_a_foreign_one_does_not(self):
+        from hpc_patterns_tpu.models.quantization import (
+            emitted_stream_law,
+        )
+
+        params, cfg, prompts, outs, rng = self._setup()
+        law = emitted_stream_law(params, cfg, prompts, outs)
+        assert law.steps == 48 and law.greedy_agreement >= 0.95
+        law.check()
+        # a flipped last token costs exactly its own step, and the flip
+        # implies a positive TV lower bound
+        flipped = [o.copy() for o in outs]
+        flipped[0][-1] = (flipped[0][-1] + 1) % 256
+        one = emitted_stream_law(params, cfg, prompts, flipped)
+        assert one.greedy_agreement == pytest.approx(
+            law.greedy_agreement - 1 / 48)
+        assert one.tv_max > 0
+        # a stream that is not this model's is refused by name
+        foreign = [rng.randint(0, 256, size=16).astype("int32")
+                   for _ in outs]
+        with pytest.raises(AssertionError, match="greedy top-1 agreement"):
+            emitted_stream_law(params, cfg, prompts, foreign).check()

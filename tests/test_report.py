@@ -230,13 +230,16 @@ class TestNoopGuard:
         args = argparse.Namespace(metrics=False, log=str(path))
 
         def fake_app(a):
-            RunLog(a.log).emit(kind="result", name="app", success=True)
+            RunLog(a.log, truncate=not a.log_append).emit(
+                kind="result", name="app", success=True)
             return 0
 
         assert common.run_instrumented(fake_app, args) == 0
         kinds = [json.loads(l)["kind"]
                  for l in path.read_text().splitlines()]
-        assert kinds == ["result"]
+        # the session's own bracket (device header first, kernel modes
+        # last) and nothing from the disabled registry
+        assert kinds == ["device", "result", "kernels"]
 
     def test_run_instrumented_enabled_appends_snapshot(self, tmp_path):
         import argparse
@@ -248,7 +251,7 @@ class TestNoopGuard:
         args = argparse.Namespace(metrics=True, log=str(path))
 
         def fake_app(a):
-            log = RunLog(a.log)
+            log = RunLog(a.log, truncate=not a.log_append)
             metricslib.get_metrics().counter("app.work").inc(7)
             log.emit(kind="result", name="app", success=True)
             return 0
@@ -256,8 +259,9 @@ class TestNoopGuard:
         assert common.run_instrumented(fake_app, args) == 0
         records = [json.loads(l)
                    for l in path.read_text().splitlines()]
-        assert [r["kind"] for r in records] == ["result", "metrics"]
-        assert records[1]["counters"]["app.work"] == 7
+        assert [r["kind"] for r in records] == [
+            "device", "result", "kernels", "metrics"]
+        assert records[-1]["counters"]["app.work"] == 7
         # and report aggregates the app log end to end
         agg = report.aggregate(records)
         assert agg["counters"]["app.work"] == 7

@@ -3,7 +3,7 @@ fission fallback, mesh construction)."""
 
 import jax
 
-from hpc_patterns_tpu.topology import shard_map
+from jax import shard_map
 import pytest
 
 from hpc_patterns_tpu import topology

@@ -324,14 +324,16 @@ class TestRunInstrumented:
         def fake_app(a):
             with metricslib.span("app.phase"):
                 pass
-            RunLog(a.log).emit(kind="result", name="app", success=True)
+            RunLog(a.log, truncate=not a.log_append).emit(
+                kind="result", name="app", success=True)
             return 0
 
         assert common.run_instrumented(fake_app, args) == 0
         records = [json.loads(l)
                    for l in path.read_text().splitlines()]
-        assert [r["kind"] for r in records] == ["result", "trace"]
-        trace_rec = records[1]
+        assert [r["kind"] for r in records] == [
+            "device", "result", "kernels", "trace"]
+        trace_rec = records[-1]
         assert trace_rec["by_cat"].get("span", 0) >= 2
         # the record is itself exportable
         chrome = tracelib.chrome_from_snapshots([trace_rec])
@@ -349,13 +351,14 @@ class TestRunInstrumented:
                                   trace_capacity=None, log=str(path))
 
         def fake_app(a):
-            RunLog(a.log).emit(kind="result", name="app", success=True)
+            RunLog(a.log, truncate=not a.log_append).emit(
+                kind="result", name="app", success=True)
             return 0
 
         assert common.run_instrumented(fake_app, args) == 0
         kinds = [json.loads(l)["kind"]
                  for l in path.read_text().splitlines()]
-        assert kinds == ["result"]
+        assert kinds == ["device", "result", "kernels"]
 
 
 class TestDistributedHandoff:
