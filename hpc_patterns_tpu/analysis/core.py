@@ -55,6 +55,7 @@ DEFAULT_DISPATCH_CRITICAL = frozenset({
     "_admit",
     "_admit_row",
     "_try_admit",
+    "_admit_pass",       # _try_admit's body, under its serve.admit_pass span
     "_ready_in_span",
     # the round-8 robustness entry points: preemption decision/eviction,
     # shedding, and the admission high-water check all run inside the
@@ -77,6 +78,7 @@ DEFAULT_DISPATCH_CRITICAL = frozenset({
     # justified suppressions in models/serving.py and
     # serving_plane/router.py.
     "service_round",
+    "_service_round",    # service_round's body, under its serve.round span
     "export_migration",
     "install_migration",
     "_dispatch_migration",

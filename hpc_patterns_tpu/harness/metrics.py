@@ -267,7 +267,10 @@ class Metrics:
         span body also runs under a ``jax.profiler.TraceAnnotation``
         of the same name, so XProf shows the identical phase tree.
         With a flight recorder installed (``--trace``), begin/end also
-        land as ring-buffer events carrying the same path."""
+        land as ring-buffer events carrying the same path. An attribute
+        given as a callable is called only when the span is live, so a
+        site may name a value that costs something to compute and still
+        pay nothing on the no-op path."""
         if not (self.enabled or self.mirror_traces
                 or _trace_sink is not None):
             return _NULL_SPAN
@@ -278,6 +281,7 @@ class Metrics:
         stack = getattr(self._local, "stack", None)
         if stack is None:
             stack = self._local.stack = []
+        attrs = {k: v() if callable(v) else v for k, v in attrs.items()}
         stack.append(name)
         path = "/".join(stack)
         annotation = _NULL_SPAN

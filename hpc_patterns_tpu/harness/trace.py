@@ -639,11 +639,14 @@ class _CompileWatch:
     """Context manager diffing a jitted fn's cache size around a call:
     growth means THIS call compiled, and the call's wall time is the
     compile-dominated cost the event records (the backend listener has
-    the pure-XLA time; this hook contributes function name + shapes)."""
+    the pure-XLA time; this hook contributes function name + shapes).
+    One probe, two sinks: the flight recorder's compile track (``rec``)
+    and, when the registry mirrors spans into the profiler, a
+    ``jit.compiled`` marker span on the device trace's own clock."""
 
     __slots__ = ("rec", "name", "fn", "attrs", "n0", "t0")
 
-    def __init__(self, rec: TraceRecorder, name: str, fn,
+    def __init__(self, rec: TraceRecorder | None, name: str, fn,
                  attrs: dict[str, Any]):
         self.rec, self.name, self.fn, self.attrs = rec, name, fn, attrs
 
@@ -656,21 +659,30 @@ class _CompileWatch:
         dt = time.perf_counter() - self.t0
         grew = jit_cache_size(self.fn) - self.n0
         if grew > 0:
-            # count=False: the backend listener already counted this
-            # compilation; the hook's job is the name + shapes
-            self.rec.compile_event(self.name, dt, count=False,
-                                   args={**self.attrs,
-                                         "new_variants": grew})
+            if self.rec is not None:
+                # count=False: the backend listener already counted
+                # this compilation; the hook's job is the name + shapes
+                self.rec.compile_event(self.name, dt, count=False,
+                                       args={**self.attrs,
+                                             "new_variants": grew})
+            if metricslib.get_metrics().mirror_traces:
+                # a marker, not a duration: it opens when the watched
+                # call has returned, under whatever span encloses it
+                with metricslib.span("jit.compiled", fn=self.name,
+                                     **self.attrs):
+                    pass
         return False
 
 
 def compile_watch(name: str, fn, **attrs):
     """``with compile_watch("serving._prefill_one", _prefill_one,
     padded_len=32): _prefill_one(...)`` — records a compile event iff
-    the call grew ``fn``'s jit cache. The disabled path returns a
-    shared nullcontext (nothing allocated per call)."""
+    the call grew ``fn``'s jit cache: on the flight recorder when one
+    is active, as a mirrored ``jit.compiled`` span when the metrics
+    registry mirrors into the profiler. With neither, the disabled
+    path returns a shared nullcontext (nothing allocated per call)."""
     rec = active()
-    if rec is None:
+    if rec is None and not metricslib.get_metrics().mirror_traces:
         return _NULL
     return _CompileWatch(rec, name, fn, attrs)
 

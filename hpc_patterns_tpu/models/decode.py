@@ -57,6 +57,7 @@ from hpc_patterns_tpu.models.transformer import (
     apply_rope,
     matmul_weight,
     project_qkv,
+    scoped,
 )
 from hpc_patterns_tpu.parallel.ring_attention import full_attention
 
@@ -214,6 +215,7 @@ def init_cache(cfg: TransformerConfig, batch: int, max_len: int):
     return cache
 
 
+@scoped("mlp")
 def _mlp(x, lp, cfg: TransformerConfig):
     dt = x.dtype
     h = _rmsnorm(x, lp["ln2_scale"])
@@ -268,11 +270,13 @@ def prefill(params, prompt, cfg: TransformerConfig, max_len: int,
             f"max_seq {cfg.max_seq}"
         )
     dt = jnp.dtype(cfg.dtype)
-    x = params["embed"].astype(dt)[prompt]
-    if cfg.pos_embed == "learned":
-        x = x + params["pos_embed"].astype(dt)[:T]
+    with jax.named_scope("embed"):
+        x = params["embed"].astype(dt)[prompt]
+        if cfg.pos_embed == "learned":
+            x = x + params["pos_embed"].astype(dt)[:T]
 
-    def body(h, lp):
+    @scoped("attn")
+    def attend(h, lp):
         hn = _rmsnorm(h, lp["ln1_scale"])
         q, k, v = project_qkv(hn, lp, cfg)
         if cfg.pos_embed == "rope":
@@ -304,22 +308,30 @@ def prefill(params, prompt, cfg: TransformerConfig, max_len: int,
             o = full_attention(q, k, v, causal=True)
         o = jnp.dot(o.reshape(B, T, cfg.d_model),
                     matmul_weight(lp, "wo", dt))
-        h = _mlp(h + o.astype(dt), lp, cfg)
+        return h + o.astype(dt), k, v
+
+    def body(h, lp):
+        h, k, v = attend(h, lp)
+        h = _mlp(h, lp, cfg)
         # capture in kernel layout (B, Hkv, T, D), padded to the static
         # cache length — one transpose at prefill, zero per decode step
-        kc = jnp.einsum("bthd->bhtd", k)
-        vc = jnp.einsum("bthd->bhtd", v)
-        pad = [(0, 0), (0, 0), (0, max_len - T), (0, 0)]
-        return h, (jnp.pad(kc, pad).astype(dt), jnp.pad(vc, pad).astype(dt))
+        with jax.named_scope("kv_write"):
+            kc = jnp.einsum("bthd->bhtd", k)
+            vc = jnp.einsum("bthd->bhtd", v)
+            pad = [(0, 0), (0, 0), (0, max_len - T), (0, 0)]
+            return h, (jnp.pad(kc, pad).astype(dt),
+                       jnp.pad(vc, pad).astype(dt))
 
     x, (ks, vs) = lax.scan(body, x, params["layers"])
-    x = _rmsnorm(x, params["ln_f_scale"])
-    if last_pos is None:
-        x_last = x[:, -1]
-    else:
-        lp = jnp.broadcast_to(jnp.asarray(last_pos, jnp.int32), (B,))
-        x_last = jnp.take_along_axis(x, lp[:, None, None], axis=1)[:, 0]
-    logits = jnp.dot(x_last, matmul_weight(params, "lm_head", dt))
+    with jax.named_scope("head"):
+        x = _rmsnorm(x, params["ln_f_scale"])
+        if last_pos is None:
+            x_last = x[:, -1]
+        else:
+            lp = jnp.broadcast_to(jnp.asarray(last_pos, jnp.int32), (B,))
+            x_last = jnp.take_along_axis(x, lp[:, None, None],
+                                         axis=1)[:, 0]
+        logits = jnp.dot(x_last, matmul_weight(params, "lm_head", dt))
     L = cfg.n_layers
     if _kv_quantized(cfg):
         kvd = cfg.kv_cache_dtype
@@ -357,34 +369,42 @@ def _token_step(params, pos, tokens, cfg: TransformerConfig,
     the two cannot drift."""
     dt = jnp.dtype(cfg.dtype)
     B = tokens.shape[0]
-    x = params["embed"].astype(dt)[tokens]  # (B, D)
-    if cfg.pos_embed == "learned":
-        pe = params["pos_embed"].astype(dt)
-        # scalar pos: one shared row (DUS slice); ragged (B,) pos:
-        # per-row gather. rope needs no branch — apply_rope broadcasts
-        # either shape over the heads
-        x = x + (pe[pos] if jnp.ndim(pos)
-                 else lax.dynamic_slice_in_dim(pe, pos, 1, axis=0))
+    with jax.named_scope("embed"):
+        x = params["embed"].astype(dt)[tokens]  # (B, D)
+        if cfg.pos_embed == "learned":
+            pe = params["pos_embed"].astype(dt)
+            # scalar pos: one shared row (DUS slice); ragged (B,) pos:
+            # per-row gather. rope needs no branch — apply_rope
+            # broadcasts either shape over the heads
+            x = x + (pe[pos] if jnp.ndim(pos)
+                     else lax.dynamic_slice_in_dim(pe, pos, 1, axis=0))
     new_states = []
     for l in range(cfg.n_layers):
         lp = jax.tree.map(lambda a: a[l], params["layers"])
-        hn = _rmsnorm(x, lp["ln1_scale"])
-        q, k_new, v_new = project_qkv(hn, lp, cfg)  # (B, H/Hkv, Dh)
-        if cfg.pos_embed == "rope":
-            q = apply_rope(q, pos, cfg)
-            k_new = apply_rope(k_new, pos, cfg)
+        with jax.named_scope("attn"):
+            hn = _rmsnorm(x, lp["ln1_scale"])
+            q, k_new, v_new = project_qkv(hn, lp, cfg)  # (B, H/Hkv, Dh)
+            if cfg.pos_embed == "rope":
+                q = apply_rope(q, pos, cfg)
+                k_new = apply_rope(k_new, pos, cfg)
         # GQA grouped attention against the UNEXPANDED cache: q head
         # k*g+j (project_qkv's order) reads kv head k directly — no
         # materialized n_heads-wide repeat, so per-step HBM traffic is
-        # the kv_heads-narrow cache read (the saving GQA exists for)
+        # the kv_heads-narrow cache read (the saving GQA exists for).
+        # Outside the scope: the paged attend_update names its own
+        # halves (the cache write is ``kv_write``, the attention
+        # ``attn``), so the two stay disjoint
         o, st = attend_update(q, k_new, v_new, layer_states[l])
-        o = jnp.dot(o.reshape(B, cfg.d_model).astype(dt),
-                    matmul_weight(lp, "wo", dt))
-        x = _mlp(x + o, lp, cfg)
+        with jax.named_scope("attn"):
+            o = jnp.dot(o.reshape(B, cfg.d_model).astype(dt),
+                        matmul_weight(lp, "wo", dt))
+            x = x + o
+        x = _mlp(x, lp, cfg)
         new_states.append(st)
-    x = _rmsnorm(x, params["ln_f_scale"])
-    logits = jnp.dot(x, matmul_weight(params, "lm_head", dt))
-    return logits.astype(jnp.float32), new_states
+    with jax.named_scope("head"):
+        x = _rmsnorm(x, params["ln_f_scale"])
+        logits = jnp.dot(x, matmul_weight(params, "lm_head", dt))
+        return logits.astype(jnp.float32), new_states
 
 
 def decode_step(params, cache, pos, tokens, cfg: TransformerConfig,
@@ -595,6 +615,7 @@ def _topk_mask(logits, top_k: int):
     return logits
 
 
+@scoped("sample")
 def _pick(logits, key, temperature, greedy: bool, top_k: int):
     """Next-token choice. ``greedy`` (static) picks the branch; the
     temperature itself stays traced so every sampling temperature
@@ -767,45 +788,49 @@ def paged_prefill(params, prompt, cfg: TransformerConfig, cache,
     # of the model maximum
     logits, lin = prefill(params, prompt, cfg, T, mesh=mesh,
                           last_pos=last_pos)
-    if t_pad > T:
-        # pad the sequence axis of every leaf (values are 4-D, int8
-        # scales 3-D)
-        lin = jax.tree.map(
-            lambda a: jnp.pad(
-                a, [(0, 0)] * 2 + [(0, t_pad - T)] + [(0, 0)] * (a.ndim - 3)
-            ),
-            lin,
-        )
-    idx = table[:, :n_used]  # (B, n_used)
-    out = {"table": table}
-    for name in ("k", "v"):
-        pool = list(cache[name])
-        for l in range(cfg.n_layers):
-            # (B, Hkv, t_pad, D) -> (B, n_used, Hkv, P, D) page blocks
-            pages = jnp.einsum(
-                "bhpsd->bphsd",
-                lin[name][l].reshape(B, cfg.kv_heads, n_used, P,
-                                     cfg.head_dim),
+    # everything after the prompt pass: pad to the page boundary and
+    # scatter each layer's pages into the pool through the table
+    with jax.named_scope("kv_write"):
+        if t_pad > T:
+            # pad the sequence axis of every leaf (values are 4-D, int8
+            # scales 3-D)
+            lin = jax.tree.map(
+                lambda a: jnp.pad(
+                    a, [(0, 0)] * 2 + [(0, t_pad - T)]
+                    + [(0, 0)] * (a.ndim - 3)
+                ),
+                lin,
             )
-            pool[l] = pool[l].at[idx].set(pages.astype(pool[l].dtype))
-        out[name] = tuple(pool)
-    if _kv_quantized(cfg):
-        for name in ("k_scale", "v_scale"):
+        idx = table[:, :n_used]  # (B, n_used)
+        out = {"table": table}
+        for name in ("k", "v"):
             pool = list(cache[name])
             for l in range(cfg.n_layers):
-                # (B, Hkv, t_pad) -> (B, n_used, Hkv, 1, P) lane-major
+                # (B, Hkv, t_pad, D) -> (B, n_used, Hkv, P, D) page blocks
                 pages = jnp.einsum(
-                    "bhps->bphs",
-                    lin[name][l].reshape(B, cfg.kv_heads, n_used, P),
-                )[:, :, :, None, :]
-                pool[l] = pool[l].at[idx].set(pages)
+                    "bhpsd->bphsd",
+                    lin[name][l].reshape(B, cfg.kv_heads, n_used, P,
+                                         cfg.head_dim),
+                )
+                pool[l] = pool[l].at[idx].set(pages.astype(pool[l].dtype))
             out[name] = tuple(pool)
-    if mesh is not None and _tp_size(mesh, cfg) > 1:
-        # pin every pool kv-head-sharded over tp (all pool leaves are
-        # 4-D with kv_heads on dim 1, scale pools included) so the
-        # per-step writes and the sharded kernel stay rank-local
-        out = {k: (v if k == "table" else _tp_pin_cache(v, mesh, cfg))
-               for k, v in out.items()}
+        if _kv_quantized(cfg):
+            for name in ("k_scale", "v_scale"):
+                pool = list(cache[name])
+                for l in range(cfg.n_layers):
+                    # (B, Hkv, t_pad) -> (B, n_used, Hkv, 1, P) lane-major
+                    pages = jnp.einsum(
+                        "bhps->bphs",
+                        lin[name][l].reshape(B, cfg.kv_heads, n_used, P),
+                    )[:, :, :, None, :]
+                    pool[l] = pool[l].at[idx].set(pages)
+                out[name] = tuple(pool)
+        if mesh is not None and _tp_size(mesh, cfg) > 1:
+            # pin every pool kv-head-sharded over tp (all pool leaves are
+            # 4-D with kv_heads on dim 1, scale pools included) so the
+            # per-step writes and the sharded kernel stay rank-local
+            out = {k: (v if k == "table" else _tp_pin_cache(v, mesh, cfg))
+                   for k, v in out.items()}
     return logits, out
 
 
@@ -1181,7 +1206,8 @@ def paged_decode_step(params, cache, pos, tokens, cfg: TransformerConfig,
                 q, kp, vp, tbl, p, k_scale_pool=ksp, v_scale_pool=vsp,
                 scale=scale, pages_per_step=pages_per_step)
 
-    def attend_update(q, k_new, v_new, state):
+    @scoped("kv_write")
+    def write(k_new, v_new, state):
         k_pool, v_pool, ks_pool, vs_pool = state
         if quant:
             k_new, k_s = _quantize_rows(k_new, cfg.kv_cache_dtype)
@@ -1194,6 +1220,15 @@ def paged_decode_step(params, cache, pos, tokens, cfg: TransformerConfig,
                              pages, ident)
         v_pool = _pool_write(v_pool, page_ids, page, offset, v_new,
                              pages, ident)
+        return k_pool, v_pool, ks_pool, vs_pool
+
+    def attend_update(q, k_new, v_new, state):
+        state = write(k_new, v_new, state)
+        with jax.named_scope("attn"):
+            return attend(q, state), state
+
+    def attend(q, state):
+        k_pool, v_pool, ks_pool, vs_pool = state
         if kernel_route is None:
             o = _paged_attend_gather(q, k_pool, v_pool, ks_pool,
                                      vs_pool, table, pos, cfg, scale)
@@ -1226,7 +1261,7 @@ def paged_decode_step(params, cache, pos, tokens, cfg: TransformerConfig,
         else:
             o = kernel_fn(q, k_pool, v_pool, table, pos, ks_pool,
                           vs_pool)
-        return o, (k_pool, v_pool, ks_pool, vs_pool)
+        return o
 
     states = [
         (cache["k"][l], cache["v"][l],

@@ -432,17 +432,18 @@ def _chunk_step(params, cache, pos, limit, tokens, keys, temps, *, cfg,
         active = pos < limit
         logits, cache = paged_decode_step(params, cache, pos, tok, cfg,
                                           mesh=mesh)
-        if greedy:
-            nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-        else:
-            split2 = jax.vmap(jax.random.split)(keys)  # (B, 2, 2)
-            masked = _topk_mask(logits, top_k) / temps[:, None]
-            nxt = jax.vmap(
-                lambda l, k: jax.random.categorical(k, l[None, :],
-                                                    axis=-1)[0]
-            )(masked, split2[:, 1]).astype(jnp.int32)
-            keys = jnp.where(active[:, None], split2[:, 0], keys)
-        nxt = jnp.where(active, nxt, tok)
+        with jax.named_scope("sample"):
+            if greedy:
+                nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+            else:
+                split2 = jax.vmap(jax.random.split)(keys)  # (B, 2, 2)
+                masked = _topk_mask(logits, top_k) / temps[:, None]
+                nxt = jax.vmap(
+                    lambda l, k: jax.random.categorical(k, l[None, :],
+                                                        axis=-1)[0]
+                )(masked, split2[:, 1]).astype(jnp.int32)
+                keys = jnp.where(active[:, None], split2[:, 0], keys)
+            nxt = jnp.where(active, nxt, tok)
         if eos_id >= 0:
             limit = jnp.where(active & (nxt == eos_id),
                               jnp.minimum(limit, pos + 1), limit)
@@ -868,6 +869,9 @@ class EngineCore:
         #: installed this round, window completion pending
         self._installed_prefetch: list[tuple] = []
         self._external_demand = 0  # router-signaled install pressure
+        #: scheduler rounds served: the ``round`` every span of one
+        #: round carries (docs/observability.md)
+        self._round = 0
         if residency is not None:
             if draft_params is not None:
                 raise ValueError(
@@ -1262,6 +1266,10 @@ class EngineCore:
         re-walking the queue per admission would put the trie work
         back in the admission window). Returns the number
         admitted."""
+        with metricslib.span("serve.admit_pass", round=self._round):
+            return self._admit_pass(overlapped)
+
+    def _admit_pass(self, overlapped: bool) -> int:
         self._shed_expired()
         # one pass-start stamp: every request seated THIS round closes
         # its queued segment here — the span from pass start to its
@@ -1357,14 +1365,16 @@ class EngineCore:
         # engine's live table with it
         one["table"] = jnp.asarray(self._table[slot:slot + 1])
         M = m * self.page_size
+        span_attrs = dict(prompt_len=T, padded_len=padded, matched=M,
+                          seq_id=req.seq_id, slot=slot,
+                          overlapped=overlapped)
         if m:
             # tail-only prefill: positions [M, padded) computed against
             # the mapped prefix pages; the matched span's compute AND
             # page writes are skipped — the TTFT lever the skip-frac
             # gauge measures
             tail = prompt[M:]
-            with metricslib.span("serve.prefill", prompt_len=T,
-                                 padded_len=padded, matched=M), \
+            with metricslib.span("serve.prefill", **span_attrs), \
                     tracelib.compile_watch("serving._tail_prefill_one",
                                            _tail_prefill_one,
                                            padded_len=padded, matched=M):
@@ -1375,8 +1385,7 @@ class EngineCore:
                     n_prefix_pages=m, mesh=self.mesh,
                 )
         else:
-            with metricslib.span("serve.prefill", prompt_len=T,
-                                 padded_len=padded), \
+            with metricslib.span("serve.prefill", **span_attrs), \
                     tracelib.compile_watch("serving._prefill_one",
                                            _prefill_one,
                                            padded_len=padded):
@@ -1484,7 +1493,9 @@ class EngineCore:
         limit already froze them out of the chunks)."""
         for slot in self._pending:
             st = self._slots[slot]
-            first = int(jax.device_get(st.first_dev))
+            with metricslib.span("serve.first_token", seq_id=st.seq_id,
+                                 slot=slot):
+                first = int(jax.device_get(st.first_dev))
             st.first_dev = None
             # a resumed row's output re-opens with everything it had
             # already emitted before preemption (its prompt carries
@@ -1765,7 +1776,8 @@ class EngineCore:
         # cursors, which _collect_chunk already resolved
         pos_start = np.array(self.pos)
         parts = [i for i, s in enumerate(self._slots) if s.active]
-        with metricslib.span("serve.decode_dispatch", chunk=self.chunk), \
+        with metricslib.span("serve.decode_dispatch", chunk=self.chunk,
+                             rows=len(parts), round=self._round), \
                 tracelib.compile_watch("serving._chunk_step",
                                        _chunk_step, chunk=self.chunk):
             (self.cache, self.pos, self.limit, self.tokens, self.keys,
@@ -1889,14 +1901,27 @@ class EngineCore:
         overlapped admission. Returns ``{"admitted", "exposed_s"
         (admission host time with nothing in flight), "stalled" (queue
         waits but nothing admitted and nothing runs — the transport
-        decides whether that is a deadlock), "active"}``."""
+        decides whether that is a deadlock), "active"}``.
+
+        The whole round is one ``serve.round`` span, its phases the
+        children (docs/observability.md has the tree)."""
+        self._round += 1
+        with metricslib.span("serve.round", round=self._round,
+                             rows=lambda: self.active_count,
+                             queued=len(self._queue)):
+            return self._service_round(decode, chaos_index, pre_collect)
+
+    def _service_round(self, decode: bool, chaos_index,
+                       pre_collect) -> dict:
         if chaos_index is not None and chaoslib.active() is not None:
             chaoslib.maybe_inject("engine_round", chaos_index)
         # fresh round, fresh head-match memo (_memo_match): the memo's
         # validity argument is scoped to one round's mutations
         self._match_memo = None
         if self.preempt:
-            self._maybe_preempt()
+            with metricslib.span("serve.preempt_policy",
+                                 round=self._round):
+                self._maybe_preempt()
         if self.residency is not None:
             self.residency.begin_round()
             for si, s in enumerate(self._slots):
@@ -1941,7 +1966,11 @@ class EngineCore:
         if pre_collect is not None:
             pre_collect(inflight is not None)
         if inflight is not None:
-            collect(inflight)
+            # the readback (serve.decode_round / serve.spec_round) is
+            # the child; the host bookkeeping is this span's self time
+            with metricslib.span("serve.collect", rows=len(inflight[0]),
+                                 round=self._round):
+                collect(inflight)
             if self.track_chunk_windows:
                 # host-clock (dispatch, readback-resolved) stamps of
                 # this chunk — the serving plane intersects migration
@@ -2529,6 +2558,27 @@ class ContinuousBatcher(EngineCore):
     this class exists so the single-process path keeps its pre-split
     surface byte-identically."""
 
+    def _submit_due(self, pending_arrivals, due: int, t_run0: float):
+        """Submit the first ``due`` arrivals of the schedule."""
+        for _ in range(due):
+            t_arr, kw = pending_arrivals.popleft()
+            sid = self.submit(**kw)
+            # the request entered on the SCHEDULE's clock, not when
+            # the loop got around to draining it: TTFT, deadlines, and
+            # the gated goodput must charge the queueing delay the
+            # user actually experienced (the drain can lag a whole
+            # chunk round or an injected stall behind the arrival
+            # instant)
+            t_abs = t_run0 + t_arr
+            self._queue[-1].t_submit = t_abs
+            self.stats[sid]["t_submit"] = t_abs
+            rtr = reqtracelib.active()
+            if rtr is not None:
+                # the queued segment starts where t_submit does, or
+                # the drain lag would finalize as a leading untracked
+                # gap
+                rtr.restamp_submit(sid, t_abs)
+
     def run(self, *, arrivals=None, max_rounds: int | None = None):
         """Serve until queue, slots, and (open-loop) arrivals drain.
         Returns ``finished``: {seq_id: np.ndarray of emitted tokens
@@ -2572,25 +2622,15 @@ class ContinuousBatcher(EngineCore):
         while True:
             if pending_arrivals:
                 now_rel = time.perf_counter() - t_run0
-                while pending_arrivals \
-                        and pending_arrivals[0][0] <= now_rel:
-                    t_arr, kw = pending_arrivals.popleft()
-                    sid = self.submit(**kw)
-                    # the request entered on the SCHEDULE's clock, not
-                    # when the loop got around to draining it: TTFT,
-                    # deadlines, and the gated goodput must charge the
-                    # queueing delay the user actually experienced
-                    # (the drain can lag a whole chunk round or an
-                    # injected stall behind the arrival instant)
-                    t_abs = t_run0 + t_arr
-                    self._queue[-1].t_submit = t_abs
-                    self.stats[sid]["t_submit"] = t_abs
-                    rtr = reqtracelib.active()
-                    if rtr is not None:
-                        # the queued segment starts where t_submit
-                        # does, or the drain lag would finalize as a
-                        # leading untracked gap
-                        rtr.restamp_submit(sid, t_abs)
+                due = 0
+                while due < len(pending_arrivals) \
+                        and pending_arrivals[due][0] <= now_rel:
+                    due += 1
+                if due:
+                    # only a drain that submits is a phase: an empty
+                    # look at the schedule gets no span
+                    with metricslib.span("serve.arrivals", n=due):
+                        self._submit_due(pending_arrivals, due, t_run0)
             if not self.has_work():
                 if not pending_arrivals:
                     break
@@ -2602,7 +2642,8 @@ class ContinuousBatcher(EngineCore):
                 # arrival — wait on the schedule's clock, boundedly
                 wait = pending_arrivals[0][0] - (time.perf_counter()
                                                  - t_run0)
-                time.sleep(min(max(wait, 0.0), 0.005))
+                with metricslib.span("serve.idle_wait"):
+                    time.sleep(min(max(wait, 0.0), 0.005))
                 continue
             if max_rounds is not None and rounds >= max_rounds:
                 break

@@ -28,7 +28,12 @@ from hpc_patterns_tpu.harness import metrics as metricslib
 from hpc_patterns_tpu.harness import trace as tracelib
 from hpc_patterns_tpu.memory import kinds as kindslib
 from hpc_patterns_tpu.models import sharding as shardlib
-from hpc_patterns_tpu.models.transformer import TransformerConfig, init_params, loss_fn
+from hpc_patterns_tpu.models.transformer import (
+    TransformerConfig,
+    init_params,
+    loss_fn,
+    scoped,
+)
 
 
 def record_step_metrics(step: int, loss: float, dt_s: float,
@@ -225,8 +230,10 @@ def make_train_step(cfg: TransformerConfig, mesh=None, optimizer=None,
         if hbm_sh is not None:
             opt_state = jax.device_put(opt_state, hbm_sh)
         loss, grads = accum_grads(params, tokens)
-        updates, opt_state = optimizer.update(grads, opt_state, params)
-        params = optax.apply_updates(params, updates)
+        with jax.named_scope("update"):   # clip + AdamW + apply
+            updates, opt_state = optimizer.update(grads, opt_state,
+                                                  params)
+            params = optax.apply_updates(params, updates)
         if host_sh is not None:
             opt_state = jax.device_put(opt_state, host_sh)
         return loss, params, opt_state
@@ -275,6 +282,7 @@ def _make_streamed_step(optimizer, accum_grads, host_sh, hbm_sh,
     accum_jit = tracelib.instrument_jit(jax.jit(accum_grads),
                                         "train.accum")
 
+    @scoped("update")
     def apply_update(params, grads, opt_state):
         updates, opt_state = optimizer.update(grads, opt_state, params)
         params = optax.apply_updates(params, updates)

@@ -32,7 +32,7 @@ from __future__ import annotations
 
 import dataclasses
 import math
-from functools import partial
+from functools import partial, wraps
 
 import jax
 import jax.numpy as jnp
@@ -375,6 +375,23 @@ def matmul_weight(tree, name, dt):
             * qs.astype(jnp.float32)[..., None, :]).astype(dt)
 
 
+def scoped(name: str):
+    """``jax.named_scope(name)`` around every call of the decorated
+    function. The names are the phases a device trace is read by
+    (docs/observability.md): metadata of the operations, never part of
+    the program. A scope is made anew for each call: one
+    ``jax.named_scope`` object used as a decorator keeps the enclosing
+    name stack on itself, which two threads tracing at once would
+    share."""
+    def decorate(fn):
+        @wraps(fn)
+        def inside(*args, **kwargs):
+            with jax.named_scope(name):
+                return fn(*args, **kwargs)
+        return inside
+    return decorate
+
+
 def _rmsnorm(x, scale):
     var = jnp.mean(jnp.square(x.astype(jnp.float32)), axis=-1, keepdims=True)
     return (x * lax.rsqrt(var + 1e-6).astype(x.dtype)) * scale.astype(x.dtype)
@@ -561,6 +578,7 @@ def _moe_block(h, lp, cfg: TransformerConfig, mesh, with_stats=False):
     return out
 
 
+@scoped("attn")
 def _qkv_block(x, lp, cfg: TransformerConfig, mesh):
     """Pre-attention: norm + fused qkv projection + rope + the GQA
     narrow-vs-expand decision. Split out so remat_policy="split" can
@@ -601,13 +619,17 @@ def _post_attn(x, o, lp, cfg: TransformerConfig, mesh, act_spec):
     be saved by any policy from outside the call)."""
     B, T, D = x.shape
     dt = x.dtype
-    o = jnp.dot(o.reshape(B, T, D), matmul_weight(lp, "wo", dt))  # row-parallel
-    x = x + o
-    if mesh is not None:
-        x = lax.with_sharding_constraint(x, act_spec)
-    return x, _rmsnorm(x, lp["ln2_scale"])
+    with jax.named_scope("attn"):
+        o = jnp.dot(o.reshape(B, T, D),
+                    matmul_weight(lp, "wo", dt))  # row-parallel
+        x = x + o
+        if mesh is not None:
+            x = lax.with_sharding_constraint(x, act_spec)
+    with jax.named_scope("mlp"):
+        return x, _rmsnorm(x, lp["ln2_scale"])
 
 
+@scoped("mlp")
 def _mlp_fused(h, lp, cfg: TransformerConfig, mesh):
     """The Pallas fused MLP on ``h`` (post-norm activations). Single
     device runs the kernel directly; under a mesh it runs shard_mapped
@@ -654,19 +676,21 @@ def _post_block(x, o, lp, cfg: TransformerConfig, mesh, act_spec,
         return lax.with_sharding_constraint(y, spec) if mesh is not None else y
 
     x, h = _post_attn(x, o, lp, cfg, mesh, act_spec)
-    if cfg.n_experts:
-        h, aux, *st = _moe_block(h, lp, cfg, mesh, with_stats=with_stats)
-        h = h.astype(dt)
-    elif cfg.mlp_impl == "fused":
-        h = _mlp_fused(h, lp, cfg, mesh).astype(dt)
-        aux = jnp.zeros((), jnp.float32)
-        st = [jnp.ones((), jnp.float32)] if with_stats else []
-    else:
-        h = jax.nn.gelu(jnp.dot(h, matmul_weight(lp, "w1", dt)))  # column-parallel
-        h = jnp.dot(h, matmul_weight(lp, "w2", dt))  # row-parallel (psum by XLA)
-        aux = jnp.zeros((), jnp.float32)
-        st = [jnp.ones((), jnp.float32)] if with_stats else []
-    return (c(x + h, act_spec), aux, *st)
+    with jax.named_scope("mlp"):
+        if cfg.n_experts:
+            h, aux, *st = _moe_block(h, lp, cfg, mesh,
+                                     with_stats=with_stats)
+            h = h.astype(dt)
+        elif cfg.mlp_impl == "fused":
+            h = _mlp_fused(h, lp, cfg, mesh).astype(dt)
+            aux = jnp.zeros((), jnp.float32)
+            st = [jnp.ones((), jnp.float32)] if with_stats else []
+        else:
+            h = jax.nn.gelu(jnp.dot(h, matmul_weight(lp, "w1", dt)))  # column-parallel
+            h = jnp.dot(h, matmul_weight(lp, "w2", dt))  # row-parallel (psum by XLA)
+            aux = jnp.zeros((), jnp.float32)
+            st = [jnp.ones((), jnp.float32)] if with_stats else []
+        return (c(x + h, act_spec), aux, *st)
 
 
 def _layer(x, lp, cfg: TransformerConfig, mesh, act_spec,
@@ -692,7 +716,8 @@ def _layer(x, lp, cfg: TransformerConfig, mesh, act_spec,
         pre = jax.checkpoint(pre, policy=dots)
         post = jax.checkpoint(post, policy=dots)
     q, k, v = pre(x, lp)
-    o = _attention(q, k, v, cfg, mesh)
+    with jax.named_scope("attn"):
+        o = _attention(q, k, v, cfg, mesh)
     # named so remat_policy="attn" can pin it under whole-layer remat
     o = checkpoint_name(o, "attn_out")
     if fused_split:
@@ -705,10 +730,11 @@ def _layer(x, lp, cfg: TransformerConfig, mesh, act_spec,
             policy=jax.checkpoint_policies.dots_with_no_batch_dims_saveable,
         )
         x1, hn = pa(x, o, lp)
-        h = _mlp_fused(hn, lp, cfg, mesh).astype(x.dtype)
-        out = x1 + h
-        if mesh is not None:
-            out = lax.with_sharding_constraint(out, act_spec)
+        with jax.named_scope("mlp"):
+            h = _mlp_fused(hn, lp, cfg, mesh).astype(x.dtype)
+            out = x1 + h
+            if mesh is not None:
+                out = lax.with_sharding_constraint(out, act_spec)
         return out, jnp.zeros((), jnp.float32)
     return post(x, o, lp)
 
@@ -721,13 +747,15 @@ def forward(params, tokens, cfg: TransformerConfig, mesh=None, *,
     ``return_aux=True`` also returns the summed MoE load-balance loss
     (zeros for dense models)."""
     x, aux = forward_hidden(params, tokens, cfg, mesh)
-    logits = jnp.dot(x, matmul_weight(params, "lm_head", x.dtype))
-    logits = logits.astype(jnp.float32)
+    with jax.named_scope("head"):
+        logits = jnp.dot(x, matmul_weight(params, "lm_head", x.dtype))
+        logits = logits.astype(jnp.float32)
     if return_aux:
         return logits, aux
     return logits
 
 
+@scoped("embed")
 def _embed_tokens(params, tokens, cfg: TransformerConfig, mesh, dt):
     """Token + learned-position embedding lookup. Under fsdp the bf16
     working copies of the feature-sharded tables are constrained
@@ -801,7 +829,8 @@ def forward_hidden(params, tokens, cfg: TransformerConfig, mesh=None):
             x, aux_i = layer(x, lp)
             aux_list.append(aux_i)
         auxes = jnp.stack(aux_list)
-    return _rmsnorm(x, params["ln_f_scale"]), jnp.sum(auxes)
+    with jax.named_scope("head"):
+        return _rmsnorm(x, params["ln_f_scale"]), jnp.sum(auxes)
 
 
 def moe_drop_rates(params, tokens, cfg: TransformerConfig, mesh=None):
@@ -916,13 +945,16 @@ def loss_fn(params, tokens, cfg: TransformerConfig, mesh=None):
     """
     if cfg.loss_chunk:
         x, aux = forward_hidden(params, tokens, cfg, mesh)
-        loss = chunked_masked_causal_nll(
-            x, matmul_weight(params, "lm_head", x.dtype), tokens,
-            chunk=cfg.loss_chunk,
-        )
+        # the head's matmul lives inside the chunked loss: one scope
+        with jax.named_scope("loss"):
+            loss = chunked_masked_causal_nll(
+                x, matmul_weight(params, "lm_head", x.dtype), tokens,
+                chunk=cfg.loss_chunk,
+            )
     else:
         logits, aux = forward(params, tokens, cfg, mesh, return_aux=True)
-        loss = masked_causal_nll(logits, tokens)
+        with jax.named_scope("loss"):
+            loss = masked_causal_nll(logits, tokens)
     if cfg.n_experts:
         loss = loss + cfg.moe_aux_weight * aux
     return loss

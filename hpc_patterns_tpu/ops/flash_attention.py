@@ -543,6 +543,7 @@ def _forward_impl(q, k, v, offs, *, causal, scale, block_q, block_k,
             ],
         ),
         out_shape=tuple(out_shape),
+        name="flash_fwd",
         interpret=interpret,
     )(offs, qr, kr, vr)
     outr = results[0]
@@ -698,6 +699,7 @@ def _backward_impl(qr, kr, vr, outr, lse, offs, g, g_lse, *, causal, scale,
                 _sds((B * Hkv, Tk, D), vr.dtype, vma),
                 _sds((n_kv, B * H, Tq_c, D), qr.dtype, vma),
             ),
+            name="flash_bwd_fused",
             interpret=interpret,
         )
 
@@ -745,6 +747,7 @@ def _backward_impl(qr, kr, vr, outr, lse, offs, g, g_lse, *, causal, scale,
             scratch_shapes=[pltpu.VMEM((block_q, D), jnp.float32)],
         ),
         out_shape=_sds((B * H, Tq, D), qr.dtype, vma),
+        name="flash_bwd_dq",
         interpret=interpret,
     )(offs, qr, kr, vr, dor, lse, delta)
 
@@ -764,6 +767,7 @@ def _backward_impl(qr, kr, vr, outr, lse, offs, g, g_lse, *, causal, scale,
             _sds((B * Hkv, Tk, D), kr.dtype, vma),
             _sds((B * Hkv, Tk, D), vr.dtype, vma),
         ),
+        name="flash_bwd_dkv",
         interpret=interpret,
     )(offs, qr, dor, lse, delta, kr, vr)
 

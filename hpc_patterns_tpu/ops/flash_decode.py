@@ -207,6 +207,7 @@ def flash_decode_attention(
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((B * Hkv, g, D), jnp.float32),
+        name="flash_decode",
         interpret=interpret,
     )(*operands)
     return out.reshape(B, H, D)
@@ -384,6 +385,7 @@ def flash_decode_paged(
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((B * Hkv, g, D), jnp.float32),
+        name="flash_decode_paged",
         interpret=interpret,
     )(*operands)
     return out.reshape(B, H, D)

@@ -179,6 +179,7 @@ def _forward(x2, w1, w2, block_t, block_f, interpret, save_a=False):
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=64 * 1024 * 1024,
         ),
+        name="fused_mlp_fwd",
         interpret=interpret,
     )(x2, w1, w2)
 
@@ -225,6 +226,7 @@ def _backward(x2, w1, w2, dy2, block_t, block_f, interpret):
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=64 * 1024 * 1024,
         ),
+        name="fused_mlp_bwd",
         interpret=interpret,
     )(x2, dy2, w1, w2)
     # the partial-dx slab sums outside the kernel (flash's dQ pattern);

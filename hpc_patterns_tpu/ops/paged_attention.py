@@ -206,6 +206,7 @@ def paged_attention_decode(
             scratch_shapes=scratch,
         ),
         out_shape=jax.ShapeDtypeStruct((B * Hkv, g, D), jnp.float32),
+        name="paged_attention_decode",
         interpret=interpret,
     )(*operands)
     return out.reshape(B, H, D)
