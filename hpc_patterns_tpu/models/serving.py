@@ -26,6 +26,16 @@ chunk never writes past a row's allocation. Idle slots point their
 table row at a dedicated TRASH page and their writes land there —
 garbage in, never read, discarded.
 
+Weights: training keeps float32 masters and casts at use; an engine
+holds its weights in ``cfg.dtype``. :class:`EngineCore` passes the
+tree it is handed (and the draft's) through
+``transformer.serving_weights`` once, at construction, so no prefill
+and no decode chunk copies a weight stack (``engine.weight_bytes`` and
+the ``serve.weights_cast`` span record the cast; int8 trees, float32
+configs and trees already cast are held as they come). The programs
+compute the same bits either way: the use sites' ``astype`` to a
+leaf's own dtype emits nothing.
+
 Production shape (round 6), three coupled levers:
 
 - **prompt-length bucketing**: prompts pad to a small ladder of
@@ -181,7 +191,11 @@ from hpc_patterns_tpu.models.decode import (
     paged_prefill,
     paged_tail_prefill,
 )
-from hpc_patterns_tpu.models.transformer import TransformerConfig
+from hpc_patterns_tpu.models.transformer import (
+    TransformerConfig,
+    serving_cast_leaves,
+    serving_weights,
+)
 
 
 def bucket_ladder(max_len: int, *, lo: int = 16,
@@ -551,6 +565,20 @@ def _tail_prefill_one(params, tail, last_rel, cache_one, *, cfg,
                               last_pos=last_rel)
 
 
+def _held_weights(params, cfg: TransformerConfig):
+    """``serving_weights(params, cfg)`` under the ``serve.weights_cast``
+    span, and the span's attributes as the engine's own record
+    (``weight_bytes``): ``leaves`` cast, their ``bytes_in`` and
+    ``bytes_out``. All zero for a tree that is held as it came."""
+    wide = serving_cast_leaves(params, cfg).values()
+    size = jnp.dtype(cfg.dtype).itemsize
+    record = {"leaves": len(wide),
+              "bytes_in": sum(a.nbytes for a in wide),
+              "bytes_out": sum(a.size * size for a in wide)}
+    with metricslib.span("serve.weights_cast", **record):
+        return serving_weights(params, cfg), record
+
+
 def prefill_cache_size() -> int:
     """Compiled admission-prefill variants in this process (the jit
     cache of :func:`_prefill_one`) — THE compile-count observable the
@@ -793,13 +821,18 @@ class EngineCore:
         base, spec = jax.random.split(jax.random.PRNGKey(seed))
         self._req_key_base = base
         self._spec_key = spec
-        self.draft_params = draft_params
         self.draft_cfg = draft_cfg
         self.gamma = gamma
         # speculative rounds touch positions up to pos+gamma; the page
         # allocation (NOT max_seq) must cover the overshoot
         self.spec_slack = gamma + 1 if draft_params is not None else 0
-        self.params = params
+        # the engine holds its weights in the compute dtype: one cast
+        # here instead of one in every prefill and every decode chunk
+        # (the caller's tree is read, never donated or deleted)
+        self.params, self.weight_bytes = _held_weights(params, cfg)
+        self.draft_params = (
+            None if draft_params is None
+            else _held_weights(draft_params, draft_cfg)[0])
         self.cfg = cfg
         self.slots = slots
         self.page_size = page_size

@@ -24,8 +24,11 @@ Architecture choices driven by the hardware (SURVEY.md preamble +
   residuals persist and the flash forward runs exactly once per step
   (builder-measured on an older toolchain — ROADMAP.md Design 9).
 
-Params are a plain pytree of f32 arrays (master weights); ``forward``
-casts to ``cfg.dtype`` (bf16 by default) at use.
+Params are a plain pytree of f32 arrays (master weights). Training
+keeps them and ``forward`` casts to ``cfg.dtype`` (bf16 by default) at
+use; a server, which never updates them, holds
+:func:`serving_weights` of the tree: the same cast made once
+(models/serving.EngineCore), after which the casts at use emit nothing.
 """
 
 from __future__ import annotations
@@ -373,6 +376,61 @@ def matmul_weight(tree, name, dt):
     # explicit lane broadcast also covers a still-stacked (L, ...) tree
     return (w.astype(jnp.float32)
             * qs.astype(jnp.float32)[..., None, :]).astype(dt)
+
+
+#: leaves whose use sites compute in float32 whatever ``cfg.dtype`` is:
+#: the MoE router (parallel/moe._route); the ``*_qscale`` siblings
+#: (:func:`matmul_weight`) are matched by suffix
+_FLOAT32_AT_USE = ("router",)
+
+
+def serving_cast_leaves(params, cfg: TransformerConfig) -> dict:
+    """Which leaves :func:`serving_weights` casts: ``{index into
+    jax.tree.leaves(params): leaf}`` for every floating leaf wider than
+    ``cfg.dtype`` whose use sites cast it to ``cfg.dtype`` anyway.
+    Integer leaves (int8 weights), their ``*_qscale`` scales and the
+    MoE router stay as they are: their math is float32 at use."""
+    dt = jnp.dtype(cfg.dtype)
+    wide = {}
+    for i, (path, leaf) in enumerate(
+            jax.tree_util.tree_flatten_with_path(params)[0]):
+        name = str(getattr(path[-1], "key", ""))
+        if (jnp.issubdtype(leaf.dtype, jnp.floating)
+                and leaf.dtype.itemsize > dt.itemsize
+                and not name.endswith(QUANT_SCALE_SUFFIX)
+                and name not in _FLOAT32_AT_USE):
+            wide[i] = leaf
+    return wide
+
+
+@partial(jax.jit, static_argnames=("dt",))
+def _cast_leaves(leaves, dt):
+    return [a.astype(dt) for a in leaves]
+
+
+def serving_weights(params, cfg: TransformerConfig):
+    """The tree a server holds: ``params`` with every leaf of
+    :func:`serving_cast_leaves` cast to ``cfg.dtype``, once, in one
+    program. The use sites (:func:`matmul_weight`, the embedding
+    gather, ``_rmsnorm``) round the same float32 values to the same
+    ``cfg.dtype`` on every call; ``astype`` to the dtype a leaf already
+    has emits nothing, so a program over this tree computes the same
+    bits without the per-call copy of every weight. Training keeps the
+    float32 masters and casts at use.
+
+    Nothing to cast (a float32 config, a tree already cast) returns
+    ``params`` itself: no copy, no dispatch, so the call is idempotent
+    and several engines over one tree can share one result. Untouched
+    leaves are the caller's own arrays; a cast leaf keeps its input's
+    sharding (an elementwise program)."""
+    wide = serving_cast_leaves(params, cfg)
+    if not wide:
+        return params
+    leaves, treedef = jax.tree.flatten(params)
+    for i, a in zip(wide, _cast_leaves(list(wide.values()),
+                                       jnp.dtype(cfg.dtype))):
+        leaves[i] = a
+    return jax.tree.unflatten(treedef, leaves)
 
 
 def scoped(name: str):

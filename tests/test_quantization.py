@@ -42,6 +42,7 @@ from hpc_patterns_tpu.models.serving import ContinuousBatcher, EngineCore
 from hpc_patterns_tpu.models.transformer import (
     QUANT_SCALE_SUFFIX,
     matmul_weight,
+    serving_weights,
 )
 from hpc_patterns_tpu.ops.paged_attention import paged_attention_decode
 
@@ -316,6 +317,37 @@ class TestQuantizedWeights:
         np.testing.assert_array_equal(
             np.asarray(w), np.asarray(params["layers"]["wo"],
                                       np.float32))
+
+    def test_serving_weights_leave_int8_and_scales_alone(self):
+        # an engine over an int8 tree in a bfloat16 config: the int8
+        # values and their float32 scales are the caller's own arrays
+        # (matmul_weight multiplies them in float32; a bfloat16 scale
+        # would be another result); the embedding and the norm scales,
+        # which the use sites cast to cfg.dtype, are cast once
+        cfg, params = _setup(dtype="bfloat16")
+        qp = quantize_weights_int8(params)
+        held = serving_weights(qp, cfg)
+        flat = lambda t: {jax.tree_util.keystr(k): v for k, v in
+                          jax.tree_util.tree_flatten_with_path(t)[0]}
+        before, after = flat(qp), flat(held)
+        assert before.keys() == after.keys()
+        cast = sorted(k for k in before if after[k] is not before[k])
+        assert cast == sorted(["['embed']", "['pos_embed']",
+                               "['ln_f_scale']",
+                               "['layers']['ln1_scale']",
+                               "['layers']['ln2_scale']"])
+        for k in cast:
+            assert after[k].dtype == jnp.bfloat16
+            np.testing.assert_array_equal(
+                np.asarray(after[k], np.float32),
+                np.asarray(before[k].astype(jnp.bfloat16), np.float32))
+        assert {after[k].dtype for k in after
+                if k.endswith(QUANT_SCALE_SUFFIX + "']")} == {
+                    jnp.dtype(jnp.float32)}
+        eng = EngineCore(qp, cfg, slots=2, pool_pages=4, pages_per_seq=2,
+                         page_size=8)
+        assert eng.weight_bytes["leaves"] == len(cast)
+        assert eng.params["lm_head"] is qp["lm_head"]
 
     def test_moe_refused(self):
         cfg = TransformerConfig(**{**BASE, "n_experts": 2})

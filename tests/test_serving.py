@@ -790,3 +790,188 @@ class TestDraftSampledDistribution:
             f"draft-assisted sampled law diverged: TV {tv:.3f} "
             f"(support emp {np.count_nonzero(emp)}, "
             f"law {np.count_nonzero(q > 1e-6)})")
+
+
+def _weight_casts(fn, params, *args):
+    """Every ``convert_element_type`` in ``fn``'s jaxpr (sub-jaxprs
+    included) whose operand is float32, has two or more dimensions and
+    the shape of a weight leaf or of one layer of a stacked leaf."""
+    shapes = set()
+    for leaf in jax.tree.leaves(params):
+        shapes |= {leaf.shape, leaf.shape[1:]}
+
+    def subjaxprs(value):
+        if hasattr(value, "eqns"):
+            yield value
+        elif hasattr(value, "jaxpr"):
+            yield from subjaxprs(value.jaxpr)
+        elif isinstance(value, (tuple, list)):
+            for v in value:
+                yield from subjaxprs(v)
+
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            aval = eqn.invars[0].aval if eqn.invars else None
+            if (eqn.primitive.name == "convert_element_type"
+                    and aval.dtype == jnp.float32 and aval.ndim >= 2
+                    and aval.shape in shapes):
+                yield aval.shape
+            for value in eqn.params.values():
+                for sub in subjaxprs(value):
+                    yield from walk(sub)
+
+    return list(walk(jax.make_jaxpr(fn)(params, *args).jaxpr))
+
+
+class TestHeldWeights:
+    """The engine holds its weights in ``cfg.dtype``
+    (transformer.serving_weights): cast once at construction, the same
+    bits in every program after it."""
+
+    # slots != n_layers and rungs unlike any weight axis: an
+    # activation's shape never passes for a weight's in _weight_casts
+    GEOM = dict(slots=3, pool_pages=9, pages_per_seq=3, page_size=8)
+
+    def _programs(self, cfg):
+        from hpc_patterns_tpu.models.decode import init_paged_cache
+        from hpc_patterns_tpu.models.serving import (
+            _chunk_step,
+            _prefill_one,
+        )
+
+        B, pps, page = 3, 3, 8
+        prompt = jnp.arange(16, dtype=jnp.int32)[None, :] % cfg.vocab
+
+        def prefill(tree):
+            one = init_paged_cache(cfg, 1, pps, page)
+            return _prefill_one(tree, prompt, jnp.int32(12), one, cfg=cfg,
+                                page_size=page, mesh=None)
+
+        def chunk(tree):
+            cache = init_paged_cache(cfg, B, pps, page)
+            return _chunk_step(
+                tree, cache, jnp.array([3, 9, 0], jnp.int32),
+                jnp.array([12, 11, 0], jnp.int32),
+                jnp.array([5, 7, 0], jnp.int32),
+                jnp.zeros((B, 2), jnp.uint32), jnp.ones((B,), jnp.float32),
+                cfg=cfg, chunk=4, eos_id=-1, greedy=True, top_k=0,
+                mesh=None)
+
+        return {"prefill": prefill, "chunk": chunk}
+
+    @pytest.mark.parametrize("tree", ["plain", "int8"])
+    def test_programs_bit_equal_over_cast_tree(self, tree):
+        # what the engine served before it cast (the float32 tree, cast
+        # at use in every program) against what it serves now
+        from hpc_patterns_tpu.models.transformer import (
+            quantize_weights_int8,
+            serving_weights,
+        )
+
+        cfg, params = _setup(dtype="bfloat16", decode_attn="gather")
+        if tree == "int8":
+            params = quantize_weights_int8(params)
+        held = serving_weights(params, cfg)
+        assert held is not params
+        for name, program in self._programs(cfg).items():
+            before, after = program(params), program(held)
+            for a, b in zip(jax.tree.leaves(before), jax.tree.leaves(after)):
+                assert a.dtype == b.dtype, name
+                np.testing.assert_array_equal(
+                    np.asarray(a, np.float32), np.asarray(b, np.float32),
+                    err_msg=name)
+
+    def test_bfloat16_engine_matches_standalone(self):
+        # end to end: the engine over its cast tree against standalone
+        # paged decode over the float32 tree (which casts at use)
+        cfg, params = _setup(dtype="bfloat16", decode_attn="gather")
+        eng = ContinuousBatcher(params, cfg, chunk=4, **self.GEOM)
+        reqs = _requests(cfg, 4, seed=3)
+        ids = [eng.submit(p, m) for p, m in reqs]
+        got = eng.run()
+        for sid, (prompt, max_new) in zip(ids, reqs):
+            np.testing.assert_array_equal(
+                got[sid], _standalone(params, cfg, prompt, max_new))
+
+    @pytest.mark.parametrize("program", ["prefill", "chunk"])
+    def test_no_weight_cast_left_in_program(self, program):
+        # the test that would have caught the cost: over the float32
+        # tree every program re-casts every matrix; over engine.params
+        # no weight-shaped float32 operand is converted at all
+        cfg, params = _setup(dtype="bfloat16", decode_attn="gather")
+        eng = ContinuousBatcher(params, cfg, **self.GEOM)
+        fn = self._programs(cfg)[program]
+        cast_at_use = _weight_casts(fn, params)
+        # embed, lm_head and the four matrices of the layer body
+        assert len(cast_at_use) >= 6, cast_at_use
+        assert _weight_casts(fn, eng.params) == []
+
+    @pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
+    def test_weight_bytes_record(self, dtype):
+        cfg, params = _setup(dtype=dtype)
+        eng = ContinuousBatcher(params, cfg, **self.GEOM)
+        n_leaves = len(jax.tree.leaves(params))
+        total = sum(a.nbytes for a in jax.tree.leaves(params))
+        if dtype == "float32":
+            assert eng.params is params
+            assert eng.weight_bytes == {"leaves": 0, "bytes_in": 0,
+                                        "bytes_out": 0}
+        else:
+            assert eng.weight_bytes == {"leaves": n_leaves,
+                                        "bytes_in": total,
+                                        "bytes_out": total // 2}
+            assert all(a.dtype == jnp.bfloat16
+                       for a in jax.tree.leaves(eng.params))
+            # the caller's tree is read, not consumed
+            assert all(a.dtype == jnp.float32 and not a.is_deleted()
+                       for a in jax.tree.leaves(params))
+
+    def test_weights_cast_span(self):
+        # the counter that says the mechanism engaged, in the repo's
+        # own tracing: one serve.weights_cast span a held tree
+        from hpc_patterns_tpu.harness import metrics as metricslib
+
+        cfg, params = _setup(dtype="bfloat16")
+        try:
+            m = metricslib.configure(enabled=True)
+            ContinuousBatcher(params, cfg, **self.GEOM)
+            hist = m.snapshot()["histograms"]
+        finally:
+            metricslib.configure(enabled=False)
+        assert hist["span.serve.weights_cast"]["count"] == 1
+
+    def test_draft_held_in_draft_dtype(self):
+        cfg, params = _setup()
+        dcfg = TransformerConfig(**{**BASE, "d_model": 16, "d_ff": 32,
+                                    "n_layers": 1, "n_heads": 2,
+                                    "dtype": "bfloat16"})
+        dparams = init_params(jax.random.PRNGKey(42), dcfg)
+        eng = ContinuousBatcher(params, cfg, draft_params=dparams,
+                                draft_cfg=dcfg, gamma=2, **self.GEOM)
+        assert eng.params is params  # float32 config: held as it came
+        assert all(a.dtype == jnp.bfloat16
+                   for a in jax.tree.leaves(eng.draft_params))
+
+    @pytest.mark.parametrize("case", ["float32_config", "already_cast"])
+    def test_nothing_to_cast_returns_the_argument(self, case):
+        from hpc_patterns_tpu.models.transformer import serving_weights
+
+        cfg, params = _setup(
+            dtype="float32" if case == "float32_config" else "bfloat16")
+        if case == "already_cast":
+            params = serving_weights(params, cfg)
+        assert serving_weights(params, cfg) is params
+
+    def test_cast_keeps_sharding(self, mesh_dp_sp_tp):
+        from hpc_patterns_tpu.models.sharding import shard_params
+        from hpc_patterns_tpu.models.transformer import serving_weights
+
+        cfg, params = _setup(dtype="bfloat16")
+        sharded = shard_params(params, mesh_dp_sp_tp, cfg)
+        held = serving_weights(sharded, cfg)
+        split = 0
+        for a, b in zip(jax.tree.leaves(sharded), jax.tree.leaves(held)):
+            assert b.dtype == jnp.bfloat16
+            assert b.sharding.is_equivalent_to(a.sharding, a.ndim)
+            split += not a.sharding.is_fully_replicated
+        assert split >= 4  # the tp rules did shard the matrices
