@@ -298,7 +298,7 @@ SERVING_POISON_TARGETS: dict[str, tuple[int, ...]] = {
     "_chunk_step": (1, 2, 3, 4, 5),
     "_spec_chunk": (2, 3, 4, 5, 6, 7),
     "_prefill_one": (3,),
-    "_admit_row": (0, 1, 2, 3, 4),
+    "_admit_row": (0, 1, 2, 3, 4, 11),
     # the serving plane's KV-handoff install scatter (round 10): the
     # pool is donated — an aliased host view of it would be the exact
     # PR 2 bug class resurfacing on the migration path

@@ -81,7 +81,7 @@ def build_parser():
     p.add_argument("--n-kv-heads", type=int, default=0)
     p.add_argument("--vocab", type=int, default=256)
     p.add_argument("--pos-embed", default="learned",
-                   choices=["learned", "rope"])
+                   choices=["learned", "rope", "none"])
     # the shared serving-precision knob; bf16 = the config's default
     # compute dtype with a scale-free cache (the pre-knob behavior)
     add_kv_dtype_arg(p, default="bf16")

@@ -55,9 +55,14 @@ from hpc_patterns_tpu.models.transformer import (
     TransformerConfig,
     _rmsnorm,
     apply_rope,
+    attn_out,
+    layer_params,
     matmul_weight,
+    moe_mixer,
     project_qkv,
     scoped,
+    ssm_mixer,
+    ssm_mixer_step,
 )
 from hpc_patterns_tpu.parallel.ring_attention import full_attention
 
@@ -206,19 +211,59 @@ def init_cache(cfg: TransformerConfig, batch: int, max_len: int):
     # each buffer (silent copy fallback — exactly the in-place update
     # this layout exists for)
     fresh = lambda sh, d: tuple(jnp.zeros(sh, d)
-                                for _ in range(cfg.n_layers))
+                                for _ in range(cfg.n_attn_layers))
     cache = {"k": fresh(shape, dt), "v": fresh(shape, dt)}
     if _kv_quantized(cfg):
         # per-row dequant scales ride alongside (tiny: D times smaller)
         cache["k_scale"] = fresh(shape[:-1], jnp.float32)
         cache["v_scale"] = fresh(shape[:-1], jnp.float32)
+    cache.update(init_layer_state(cfg, batch))
     return cache
+
+
+#: cache entries that are per-ROW state of a patterned model's "M"
+#: layers (one row a sequence, beside the K/V of its attention layers)
+STATE_KEYS = ("conv", "ssm")
+
+
+def init_layer_state(cfg: TransformerConfig, batch: int) -> dict:
+    """What a patterned model's cache holds beside K/V: ``conv`` / ``ssm``,
+    one (batch, ...) array an "M" layer (the convolution's tail and the
+    recurrent state S, models/ssm.py), and ``moe_stats``, the route's
+    running sums (parallel/moe.ROUTE_STATS; row 0 prefills, row 1 decode
+    steps). Empty for the default pattern."""
+    out = {}
+    n_m = cfg.layer_pattern.count("M")
+    if n_m:
+        from hpc_patterns_tpu.models import ssm
+
+        out.update(_state_entries(
+            [ssm.init_state(cfg, batch) for _ in range(n_m)]))
+    if "E" in cfg.layer_pattern:
+        from hpc_patterns_tpu.parallel.moe import ROUTE_STATS
+
+        out["moe_stats"] = jnp.zeros((2, len(ROUTE_STATS)), jnp.int32)
+    return out
+
+
+def _state_entries(pairs) -> dict:
+    """The "M" layers' (conv tail, S) pairs, in layer order, as the
+    cache's ``STATE_KEYS`` entries; none gives none."""
+    return dict(zip(STATE_KEYS, map(tuple, zip(*pairs)))) if pairs else {}
+
+
+def _dense_only(cfg: TransformerConfig, what: str) -> None:
+    if cfg.layer_pattern:
+        raise ValueError(
+            f"{what} covers the default layer pattern only: a patterned "
+            f"model ({cfg.layer_pattern!r}) has per-row recurrent state "
+            "that this route neither carries nor rewinds")
 
 
 @scoped("mlp")
 def _mlp(x, lp, cfg: TransformerConfig):
     dt = x.dtype
-    h = _rmsnorm(x, lp["ln2_scale"])
+    h = _rmsnorm(x, lp["ln2_scale"], cfg.norm_eps)
     if cfg.n_experts:
         from hpc_patterns_tpu.parallel import moe
 
@@ -277,7 +322,7 @@ def prefill(params, prompt, cfg: TransformerConfig, max_len: int,
 
     @scoped("attn")
     def attend(h, lp):
-        hn = _rmsnorm(h, lp["ln1_scale"])
+        hn = _rmsnorm(h, lp["ln1_scale"], cfg.norm_eps)
         q, k, v = project_qkv(hn, lp, cfg)
         if cfg.pos_embed == "rope":
             # the cache stores POST-rope K: a key's rotation depends
@@ -310,9 +355,10 @@ def prefill(params, prompt, cfg: TransformerConfig, max_len: int,
                     matmul_weight(lp, "wo", dt))
         return h + o.astype(dt), k, v
 
-    def body(h, lp):
+    def body(h, lp, mlp=True):
         h, k, v = attend(h, lp)
-        h = _mlp(h, lp, cfg)
+        if mlp:
+            h = _mlp(h, lp, cfg)
         # capture in kernel layout (B, Hkv, T, D), padded to the static
         # cache length — one transpose at prefill, zero per decode step
         with jax.named_scope("kv_write"):
@@ -322,17 +368,43 @@ def prefill(params, prompt, cfg: TransformerConfig, max_len: int,
             return h, (jnp.pad(kc, pad).astype(dt),
                        jnp.pad(vc, pad).astype(dt))
 
-    x, (ks, vs) = lax.scan(body, x, params["layers"])
+    rows_last = lambda: jnp.broadcast_to(
+        jnp.asarray(last_pos, jnp.int32), (B,))
+    extra = {}
+    if cfg.layer_pattern:
+        last = None if last_pos is None else rows_last()
+        # one mixer a layer, by its type: "M" layers hand back the state
+        # at the prompt's TRUE last position, "E" layers route the true
+        # tokens alone (a bucket's padding picks no expert)
+        valid = (None if last is None else
+                 jnp.arange(T, dtype=jnp.int32)[None, :] <= last[:, None])
+        ks, vs, states, stats = [], [], [], []
+        for kind, lp in zip(cfg.layer_pattern, params["layers"]):
+            if kind == "*":
+                x, (kc, vc) = body(x, lp, mlp=False)
+                ks.append(kc)
+                vs.append(vc)
+            elif kind == "M":
+                x, st = ssm_mixer(x, lp, cfg, last)
+                states.append(st)
+            else:
+                x, st = moe_mixer(x, lp, cfg, valid)
+                stats.append(st)
+        extra = _state_entries(states)
+        if stats:
+            extra["moe_stats"] = jnp.stack(
+                [sum(stats), jnp.zeros_like(stats[0])])
+    else:
+        x, (ks, vs) = lax.scan(body, x, params["layers"])
     with jax.named_scope("head"):
-        x = _rmsnorm(x, params["ln_f_scale"])
+        x = _rmsnorm(x, params["ln_f_scale"], cfg.norm_eps)
         if last_pos is None:
             x_last = x[:, -1]
         else:
-            lp = jnp.broadcast_to(jnp.asarray(last_pos, jnp.int32), (B,))
-            x_last = jnp.take_along_axis(x, lp[:, None, None],
+            x_last = jnp.take_along_axis(x, rows_last()[:, None, None],
                                          axis=1)[:, 0]
         logits = jnp.dot(x_last, matmul_weight(params, "lm_head", dt))
-    L = cfg.n_layers
+    L = cfg.n_attn_layers
     if _kv_quantized(cfg):
         kvd = cfg.kv_cache_dtype
         kq, ksc = zip(*(_quantize_rows(ks[l], kvd) for l in range(L)))
@@ -351,11 +423,13 @@ def prefill(params, prompt, cfg: TransformerConfig, max_len: int,
         # dynamic_update_slice and attention read stay rank-local (the
         # sharded decode step's shard_map consumes exactly this layout)
         cache = _tp_pin_cache(cache, mesh, cfg)
+    cache.update(extra)
     return logits.astype(jnp.float32), cache
 
 
 def _token_step(params, pos, tokens, cfg: TransformerConfig,
-                layer_states, attend_update):
+                layer_states, attend_update, row_states=None,
+                active=None):
     """Shared single-token transformer skeleton: embed, the UNROLLED
     layer loop (static per-layer param slices fuse; a lax.scan would
     stack the updated caches into a fresh (L, ...) block — a full
@@ -366,7 +440,12 @@ def _token_step(params, pos, tokens, cfg: TransformerConfig,
     cache write + attention, the ONLY part that differs between the
     linear cache (flash/gather/int8/tp routes, :func:`decode_step`)
     and the paged cache (:func:`paged_decode_step`). One skeleton, so
-    the two cannot drift."""
+    the two cannot drift. The loop reads ``cfg.pattern``: a "B" layer is
+    that block, "*" its attention half alone, "M" one step of the
+    recurrence against ``row_states`` (the (conv tail, S) pairs, written
+    back only where ``active``), "E" the expert layer (idle rows pick
+    nothing). Returns (logits, the attention layers' new states, the
+    other layers' cache entries)."""
     dt = jnp.dtype(cfg.dtype)
     B = tokens.shape[0]
     with jax.named_scope("embed"):
@@ -378,11 +457,20 @@ def _token_step(params, pos, tokens, cfg: TransformerConfig,
             # broadcasts either shape over the heads
             x = x + (pe[pos] if jnp.ndim(pos)
                      else lax.dynamic_slice_in_dim(pe, pos, 1, axis=0))
-    new_states = []
-    for l in range(cfg.n_layers):
-        lp = jax.tree.map(lambda a: a[l], params["layers"])
+    new_states, new_rows, stats = [], [], []
+    for l, kind in enumerate(cfg.pattern):
+        lp = layer_params(params, l)
+        if kind == "M":   # the recurrence; idle rows keep their state
+            x, st = ssm_mixer_step(x, lp, cfg, row_states[len(new_rows)],
+                                   active)
+            new_rows.append(st)
+            continue
+        if kind == "E":   # idle rows pick no expert
+            x, st = moe_mixer(x, lp, cfg, active)
+            stats.append(st)
+            continue
         with jax.named_scope("attn"):
-            hn = _rmsnorm(x, lp["ln1_scale"])
+            hn = _rmsnorm(x, lp["ln1_scale"], cfg.norm_eps)
             q, k_new, v_new = project_qkv(hn, lp, cfg)  # (B, H/Hkv, Dh)
             if cfg.pos_embed == "rope":
                 q = apply_rope(q, pos, cfg)
@@ -394,17 +482,42 @@ def _token_step(params, pos, tokens, cfg: TransformerConfig,
         # Outside the scope: the paged attend_update names its own
         # halves (the cache write is ``kv_write``, the attention
         # ``attn``), so the two stay disjoint
-        o, st = attend_update(q, k_new, v_new, layer_states[l])
+        o, st = attend_update(q, k_new, v_new,
+                              layer_states[len(new_states)])
         with jax.named_scope("attn"):
             o = jnp.dot(o.reshape(B, cfg.d_model).astype(dt),
                         matmul_weight(lp, "wo", dt))
             x = x + o
-        x = _mlp(x, lp, cfg)
+        if kind == "B":
+            x = _mlp(x, lp, cfg)
         new_states.append(st)
     with jax.named_scope("head"):
-        x = _rmsnorm(x, params["ln_f_scale"])
+        x = _rmsnorm(x, params["ln_f_scale"], cfg.norm_eps)
         logits = jnp.dot(x, matmul_weight(params, "lm_head", dt))
-        return logits.astype(jnp.float32), new_states
+    return (logits.astype(jnp.float32), new_states,
+            _step_extra(new_rows, stats))
+
+
+def _row_states(cache):
+    """The "M" layers' (conv tail, S) pairs of a cache, in layer order."""
+    return list(zip(*(cache.get(k, ()) for k in STATE_KEYS)))
+
+
+def _step_extra(new_rows, stats) -> dict:
+    """What one token step adds to a patterned model's cache entries:
+    the "M" layers' new state and the route's sums of this step."""
+    extra = _state_entries(new_rows)
+    if stats:
+        extra["moe_stats"] = sum(stats)
+    return extra
+
+
+def _apply_extra(cache, out, extra) -> None:
+    """Fold :func:`_step_extra` into the step's new cache ``out``."""
+    stats = extra.pop("moe_stats", None)
+    out.update(extra)
+    if stats is not None:   # row 1: decode steps
+        out["moe_stats"] = cache["moe_stats"].at[1].add(stats)
 
 
 def decode_step(params, cache, pos, tokens, cfg: TransformerConfig,
@@ -515,15 +628,17 @@ def decode_step(params, cache, pos, tokens, cfg: TransformerConfig,
         (cache["k"][l], cache["v"][l],
          cache["k_scale"][l] if quant_cache else None,
          cache["v_scale"][l] if quant_cache else None)
-        for l in range(cfg.n_layers)
+        for l in range(cfg.n_attn_layers)
     ]
-    logits, new_states = _token_step(params, pos, tokens, cfg,
-                                     states, attend_update)
+    logits, new_states, extra = _token_step(
+        params, pos, tokens, cfg, states, attend_update,
+        _row_states(cache))
     new_cache = {"k": tuple(s[0] for s in new_states),
                  "v": tuple(s[1] for s in new_states)}
     if quant_cache:
         new_cache["k_scale"] = tuple(s[2] for s in new_states)
         new_cache["v_scale"] = tuple(s[3] for s in new_states)
+    _apply_extra(cache, new_cache, extra)
     return logits, new_cache
 
 
@@ -546,6 +661,7 @@ def extend_step(params, cache, pos, tokens, cfg: TransformerConfig):
     """
     if cfg.kv_cache_dtype != "compute":
         raise ValueError("extend_step supports compute-dtype caches only")
+    _dense_only(cfg, "extend_step")
     dt = jnp.dtype(cfg.dtype)
     B, c = tokens.shape
     scale = 1.0 / (cfg.head_dim ** 0.5)
@@ -751,13 +867,16 @@ def init_paged_cache(cfg: TransformerConfig, batch: int,
     dt = _kv_storage_dtype(cfg)
     shape = (pool_pages, cfg.kv_heads, page_size, cfg.head_dim)
     fresh = lambda sh, d: tuple(jnp.zeros(sh, d)
-                                for _ in range(cfg.n_layers))
+                                for _ in range(cfg.n_attn_layers))
     cache = {"k": fresh(shape, dt), "v": fresh(shape, dt),
              "table": jnp.asarray(table, jnp.int32)}
     if quant:
         sshape = (pool_pages, cfg.kv_heads, 1, page_size)
         cache["k_scale"] = fresh(sshape, jnp.float32)
         cache["v_scale"] = fresh(sshape, jnp.float32)
+    # a patterned model's per-row state lives beside the pools: one row
+    # a sequence, no paging (its size does not grow with the context)
+    cache.update(init_layer_state(cfg, batch))
     return cache
 
 
@@ -788,6 +907,15 @@ def paged_prefill(params, prompt, cfg: TransformerConfig, cache,
     # of the model maximum
     logits, lin = prefill(params, prompt, cfg, T, mesh=mesh,
                           last_pos=last_pos)
+    # a patterned model's rows of state pass through as the prompt pass
+    # left them (B rows: the caller's own batch, or the engine's one
+    # admission, which installs them at its slot); the route's sums add
+    # to the cache's
+    extra = {k: lin.pop(k) for k in STATE_KEYS if k in lin}
+    if "moe_stats" in lin:
+        stats = lin.pop("moe_stats")
+        extra["moe_stats"] = (cache["moe_stats"] + stats
+                              if "moe_stats" in cache else stats)
     # everything after the prompt pass: pad to the page boundary and
     # scatter each layer's pages into the pool through the table
     with jax.named_scope("kv_write"):
@@ -802,10 +930,10 @@ def paged_prefill(params, prompt, cfg: TransformerConfig, cache,
                 lin,
             )
         idx = table[:, :n_used]  # (B, n_used)
-        out = {"table": table}
+        out = {"table": table, **extra}
         for name in ("k", "v"):
             pool = list(cache[name])
-            for l in range(cfg.n_layers):
+            for l in range(cfg.n_attn_layers):
                 # (B, Hkv, t_pad, D) -> (B, n_used, Hkv, P, D) page blocks
                 pages = jnp.einsum(
                     "bhpsd->bphsd",
@@ -817,7 +945,7 @@ def paged_prefill(params, prompt, cfg: TransformerConfig, cache,
         if _kv_quantized(cfg):
             for name in ("k_scale", "v_scale"):
                 pool = list(cache[name])
-                for l in range(cfg.n_layers):
+                for l in range(cfg.n_attn_layers):
                     # (B, Hkv, t_pad) -> (B, n_used, Hkv, 1, P) lane-major
                     pages = jnp.einsum(
                         "bhps->bphs",
@@ -829,7 +957,8 @@ def paged_prefill(params, prompt, cfg: TransformerConfig, cache,
             # pin every pool kv-head-sharded over tp (all pool leaves are
             # 4-D with kv_heads on dim 1, scale pools included) so the
             # per-step writes and the sharded kernel stay rank-local
-            out = {k: (v if k == "table" else _tp_pin_cache(v, mesh, cfg))
+            out = {k: (_tp_pin_cache(v, mesh, cfg)
+                       if k in ("k", "v", "k_scale", "v_scale") else v)
                    for k, v in out.items()}
     return logits, out
 
@@ -873,6 +1002,7 @@ def paged_tail_prefill(params, tail, cfg: TransformerConfig, cache,
     the monolithic prefill attends to the EXACT K/V and quantizes only
     for storage, so a tail computed from dequantized prefix pages
     could not be bit-equal."""
+    _dense_only(cfg, "paged_tail_prefill")
     if _kv_quantized(cfg):
         raise ValueError(
             f"paged_tail_prefill: kv_cache_dtype="
@@ -1087,7 +1217,7 @@ def _paged_attend_gather(q, k_pool, v_pool, ks_pool, vs_pool, table,
 
 def paged_decode_step(params, cache, pos, tokens, cfg: TransformerConfig,
                       identity_layout: bool = False, mesh=None,
-                      pages_per_step: int | None = None):
+                      pages_per_step: int | None = None, active=None):
     """One token per sequence against the paged cache: the new K/V row
     scatters into page ``table[:, pos // P]`` at offset ``pos % P``,
     and attention streams the live pages through
@@ -1267,10 +1397,11 @@ def paged_decode_step(params, cache, pos, tokens, cfg: TransformerConfig,
         (cache["k"][l], cache["v"][l],
          cache["k_scale"][l] if quant else None,
          cache["v_scale"][l] if quant else None)
-        for l in range(cfg.n_layers)
+        for l in range(cfg.n_attn_layers)
     ]
-    logits, new_states = _token_step(params, pos, tokens, cfg,
-                                     states, attend_update)
+    logits, new_states, extra = _token_step(
+        params, pos, tokens, cfg, states, attend_update,
+        _row_states(cache), active)
     out = {
         "k": tuple(s[0] for s in new_states),
         "v": tuple(s[1] for s in new_states),
@@ -1279,6 +1410,7 @@ def paged_decode_step(params, cache, pos, tokens, cfg: TransformerConfig,
     if quant:
         out["k_scale"] = tuple(s[2] for s in new_states)
         out["v_scale"] = tuple(s[3] for s in new_states)
+    _apply_extra(cache, out, extra)
     return logits, out
 
 
@@ -1306,6 +1438,7 @@ def paged_extend_step(params, cache, pos, tokens, cfg: TransformerConfig):
     """
     quant = _kv_quantized(cfg)
     dt = jnp.dtype(cfg.dtype)
+    _dense_only(cfg, "paged_extend_step")
     B, c = tokens.shape
     if jnp.ndim(pos) != 1 or jnp.shape(pos)[0] != B:
         raise ValueError(

@@ -184,6 +184,7 @@ from hpc_patterns_tpu.harness import trace as tracelib
 from hpc_patterns_tpu.memory.prefix_cache import RadixPrefixCache
 from hpc_patterns_tpu.models.decode import (
     PREFIX_ALIGN,
+    STATE_KEYS,
     _pick,
     _topk_mask,
     init_paged_cache,
@@ -445,7 +446,7 @@ def _chunk_step(params, cache, pos, limit, tokens, keys, temps, *, cfg,
         cache, pos, limit, tok, keys = carry
         active = pos < limit
         logits, cache = paged_decode_step(params, cache, pos, tok, cfg,
-                                          mesh=mesh)
+                                          mesh=mesh, active=active)
         with jax.named_scope("sample"):
             if greedy:
                 nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
@@ -603,9 +604,10 @@ def tail_prefill_cache_size() -> int:
 
 
 @partial(jax.jit, static_argnames=("eos_id", "greedy", "top_k"),
-         donate_argnums=(0, 1, 2, 3, 4))
+         donate_argnums=(0, 1, 2, 3, 4, 11))
 def _admit_row(pos, limit, tokens, keys, temps, logits, key, temp, slot,
-               true_len, budget, *, eos_id, greedy, top_k):
+               true_len, budget, state=None, row_state=None, *, eos_id,
+               greedy, top_k):
     """All device-side admission bookkeeping in ONE dispatch: pick the
     first token from the prefill logits (the same split/pick sequence
     decode._generation_scan opens with, so sampled rows stay
@@ -613,7 +615,17 @@ def _admit_row(pos, limit, tokens, keys, temps, logits, key, temp, slot,
     ``true_len`` when the row is already done (budget 1, or the first
     token IS eos) — all decided on device, so admission never forces a
     host readback. ``slot``/``true_len``/``budget`` ride as traced
-    scalars: one compilation serves every admission."""
+    scalars: one compilation serves every admission.
+
+    ``state`` (donated) / ``row_state``: a patterned model's per-row
+    state (decode.STATE_KEYS) and the one row the prefill left; the row
+    is installed at ``slot`` over whatever the slot's last tenant left
+    there, so a reused slot starts from its own prompt alone."""
+    if state is not None:
+        with jax.named_scope("state_write"):
+            state = jax.tree.map(
+                lambda a, r: lax.dynamic_update_slice_in_dim(
+                    a, r.astype(a.dtype), slot, axis=0), state, row_state)
     newk, sub = jax.random.split(key)
     first = _pick(logits, sub, temp, greedy, top_k)[0]
     # budget b emits 1 token at admit + (lim - true_len) from chunks
@@ -625,7 +637,7 @@ def _admit_row(pos, limit, tokens, keys, temps, logits, key, temp, slot,
     tokens = tokens.at[slot].set(first)
     keys = keys.at[slot].set(newk)
     temps = temps.at[slot].set(temp)
-    return pos, limit, tokens, keys, temps, first
+    return pos, limit, tokens, keys, temps, first, state
 
 
 @partial(jax.jit, donate_argnums=(0,))
@@ -744,9 +756,34 @@ class EngineCore:
                  admit_highwater: float = 1.0,
                  slo: dict[int, slolib.SLOTarget] | None = None,
                  residency=None, prefix_cache: bool = False):
-        if cfg.n_experts:
-            # paged serving is dense-model territory so far
-            raise ValueError("continuous batching: dense models only")
+        if cfg.layer_pattern:
+            # what a model with per-row recurrent state cannot do yet,
+            # each by the mechanism that is missing
+            for on, what, why in (
+                (prefix_cache, "prefix_cache",
+                 "a shared prefix would need the recurrent state AT the "
+                 "prefix's end kept beside its pages (a state snapshot "
+                 "per cached chain), and the tail prefill to start from "
+                 "it"),
+                (preempt, "preempt",
+                 "a preempted row resumes by prefilling prompt + output "
+                 "again, which rebuilds the recurrent state by the "
+                 "chunked form: equal to the stepped state only to "
+                 "rounding, where a resumed row has to continue exactly "
+                 "as the uninterrupted one; that needs the row's state "
+                 "snapshotted at eviction"),
+                (residency is not None, "residency",
+                 "swap-out moves a row's pages to the host tier; its "
+                 "recurrent state has no page and no host pool yet"),
+                (draft_params is not None, "draft_params",
+                 "a rejected draft token must rewind the recurrence, "
+                 "which needs a state checkpoint per speculated "
+                 "position"),
+            ):
+                if on:
+                    raise ValueError(
+                        f"{what} with a patterned model "
+                        f"({cfg.layer_pattern!r}): {why}")
         if draft_params is not None:
             if draft_cfg is None:
                 raise ValueError("draft_params needs draft_cfg")
@@ -846,6 +883,11 @@ class EngineCore:
             cfg, slots, pages_per_seq, page_size,
             pool_pages=pool_pages + 1, table=jnp.asarray(table),
         )
+        #: bytes of per-row recurrent state held beside the pools
+        self.state_bytes = sum(
+            int(a.nbytes) for k in STATE_KEYS for a in self.cache.get(k, ()))
+        #: what ``_count_route`` last saw of the expert route's sums
+        self._route_seen = None
         if draft_params is not None:
             # the draft pool mirrors the target's page geometry and
             # SHARES the page table (one allocation decision serves
@@ -1391,7 +1433,9 @@ class EngineCore:
                 [prompt, np.zeros(padded - T, np.int32)])
         # one-row prefill THROUGH the shared pool: the scatter touches
         # only this row's pages (compiles once per bucket rung)
-        one = dict(self.cache)
+        # (the rows of recurrent state stay behind: the prefill hands
+        # back this admission's one row, _admit_row installs it)
+        one = {k: v for k, v in self.cache.items() if k not in STATE_KEYS}
         # fresh upload from the host mirror, NOT a slice of the device
         # table: a full-range slice can alias the same buffer, and
         # _prefill_one donates its table — an alias would delete the
@@ -1401,6 +1445,8 @@ class EngineCore:
         span_attrs = dict(prompt_len=T, padded_len=padded, matched=M,
                           seq_id=req.seq_id, slot=slot,
                           overlapped=overlapped)
+        if self.state_bytes:
+            span_attrs["state_bytes"] = self.state_bytes // self.slots
         if m:
             # tail-only prefill: positions [M, padded) computed against
             # the mapped prefix pages; the matched span's compute AND
@@ -1428,6 +1474,7 @@ class EngineCore:
                     cfg=self.cfg, page_size=self.page_size,
                     mesh=self.mesh,
                 )
+        row_state = {k: out.pop(k) for k in STATE_KEYS if k in out}
         for k, v in out.items():
             if k != "table":
                 self.cache[k] = v
@@ -1455,12 +1502,16 @@ class EngineCore:
             req.seq_id)
         temp = (req.temperature if req.temperature is not None
                 else self.temperature)
+        state = ({k: self.cache[k] for k in row_state}
+                 if row_state else None)
         (self.pos, self.limit, self.tokens, self.keys, self.temps,
-         first_dev) = _admit_row(
+         first_dev, state) = _admit_row(
             self.pos, self.limit, self.tokens, self.keys, self.temps,
             logits, key, jnp.float32(max(temp, 1e-6)), slot, T,
-            req.max_new, eos_id=self.eos_id, greedy=self.greedy,
-            top_k=self.top_k)
+            req.max_new, state, row_state or None, eos_id=self.eos_id,
+            greedy=self.greedy, top_k=self.top_k)
+        if state is not None:
+            self.cache.update(state)
         st = self._slots[slot]
         st.seq_id, st.pages, st.prompt_len = req.seq_id, pages, T
         st.budget = req.max_new
@@ -1501,6 +1552,8 @@ class EngineCore:
         if mx.enabled:
             mx.gauge("serve.queue_depth").set(len(self._queue))
             mx.gauge("serve.free_pages").set(len(self.free_pages))
+            if self.state_bytes:
+                mx.gauge("engine.state_bytes").set(self.state_bytes)
             mx.counter("serve.admitted").inc()
             if overlapped:
                 mx.counter("serve.admit_overlapped").inc()
@@ -1838,6 +1891,8 @@ class EngineCore:
             rec.mark_complete("serve.chunk", t_disp,
                               {"chunk": self.chunk, "rows": len(parts)})
         limit_new = np.asarray(self.limit)
+        if metricslib.get_metrics().enabled:
+            self._count_route()
         # the chunk's tokens all became host-visible at THIS readback —
         # one shared availability instant (honest: intra-chunk device
         # timing is invisible; the inter-token digest tiles stall
@@ -1855,6 +1910,35 @@ class EngineCore:
                 rec_s.setdefault("token_ts", []).extend([now] * valid)
             if pos_start[i] + valid >= limit_new[i]:
                 self._finish(i)
+
+    def route_stats(self):
+        """The expert route's running sums read to the host now (rows:
+        prefills, decode steps; columns: ``parallel/moe.ROUTE_STATS``),
+        None for a model without expert layers. int32 that wrap: take
+        differences. Waits for the programs still adding to them."""
+        stats = self.cache.get("moe_stats")
+        return None if stats is None else np.asarray(stats)
+
+    def _count_route(self) -> None:
+        """The engine's route counters (docs/observability.md), from what
+        the sums grew by since the last look. After a chunk's readback:
+        every program that adds to them (the chunk, and the prefills
+        whose first tokens ``_resolve_pending`` already read) has
+        finished, so the read waits for nothing."""
+        new = self.route_stats()
+        if new is None:
+            return
+        old, self._route_seen = self._route_seen, new
+        grew = new - (0 if old is None else old)   # int32: wraps rightly
+        picks, tokens = grew.sum(axis=0)[:2]
+        mx = metricslib.get_metrics()
+        mx.counter("moe.local_picks").inc(int(picks))
+        mx.counter("moe.tokens").inc(int(tokens))
+        d_picks, _, max_load, touched, calls = (int(v) for v in grew[1])
+        if calls > 0:   # decode steps: fullest held expert over the mean
+            mx.gauge("moe.load_max_over_mean").set(
+                max_load * self.cfg.experts_held / max(d_picks, 1))
+            mx.gauge("moe.experts_touched").set(touched / calls)
 
     def _dispatch_spec(self):
         """``chunk`` draft-assisted rounds per dispatch: budget/EOS
@@ -2080,6 +2164,14 @@ class EngineCore:
         return [i for i, s in enumerate(self._slots)
                 if s.active and i not in self._pending]
 
+    def _no_state_migration(self) -> None:
+        if self.state_bytes:
+            raise ValueError(
+                "migration with a patterned model "
+                f"({self.cfg.layer_pattern!r}): a MigrationBundle carries "
+                "a row's pages and cursors; the row's recurrent state "
+                "would have to travel with them and has no wire form yet")
+
     def _detach_row(self, slot: int) -> MigrationBundle:
         """Detach one active row into a :class:`MigrationBundle` and
         release its slot/pages — the snapshot half SHARED by
@@ -2096,6 +2188,7 @@ class EngineCore:
         aliases buffers a later ``_chunk_step`` donates. The KV pages
         are GATHERED device-side (``pool[idx]`` — a new buffer, no
         host readback of K/V anywhere on the in-process path)."""
+        self._no_state_migration()
         st = self._slots[slot]
         if not st.active or slot in self._pending or st.prompt is None:
             raise ValueError(f"slot {slot} has no exportable row")
@@ -2256,6 +2349,7 @@ class EngineCore:
         handoff) and the residency manager's swap-in (the prefetched
         host-tier row returning to HBM). Admissibility is the
         CALLER's to have checked. Returns the slot."""
+        self._no_state_migration()
         slot = next(i for i, s in enumerate(self._slots) if not s.active)
         # jaxlint: disable=host-sync-in-dispatch — host-list packing of
         # the wire bundle's prompt, not a device readback (the same

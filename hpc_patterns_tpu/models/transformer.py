@@ -164,6 +164,38 @@ class TransformerConfig:
     # same byte win with ~2 more bits of mantissa headroom; probe
     # backend support with dtypes.supports_fp8, docs/quantization.md)
     kv_cache_dtype: str = "compute"
+    # the layer pattern: "" = ``n_layers`` of the one block (attention +
+    # MLP/MoE, the default and everything above). Otherwise one character
+    # a layer, each layer ONE mixer under a pre-norm residual: "*"
+    # attention alone (GQA, no MLP), "M" a Mamba-2 mixer
+    # (models/ssm.py), "E" a LatentMoE layer on this chip's share of the
+    # experts (parallel/moe.latent_moe). A patterned model's
+    # ``params["layers"]`` is a tuple of per-layer dicts, its layer loop
+    # is unrolled, and it runs unsharded (mesh=None)
+    layer_pattern: str = ""
+    norm_eps: float = 1e-6
+    # "M": heads x head_dim = d_inner; B and C are shared by the heads of
+    # a group; the convolution's width; positions a chunk of the prefill's
+    # chunked form (the recurrent state is held in float32)
+    ssm_heads: int = 0
+    ssm_head_dim: int = 64
+    ssm_groups: int = 1
+    ssm_state: int = 128
+    ssm_conv: int = 4
+    ssm_chunk: int = 128
+    # "E": the router scores all ``moe_experts`` and picks ``moe_top_k``;
+    # this chip holds experts [moe_held_start, moe_held_start + moe_held)
+    # (0 = all) and computes their picks alone; the routed experts are
+    # latent -> moe_d_ff -> latent, the shared one d_model ->
+    # moe_shared_d_ff -> d_model; gates sum to ``moe_scale``
+    moe_experts: int = 0
+    moe_held: int = 0
+    moe_held_start: int = 0
+    moe_top_k: int = 1
+    moe_latent: int = 0
+    moe_d_ff: int = 0
+    moe_shared_d_ff: int = 0
+    moe_scale: float = 1.0
     # mesh axis names (data / sequence(context) / tensor / expert)
     axis_dp: str = "dp"
     axis_sp: str = "sp"
@@ -190,16 +222,54 @@ class TransformerConfig:
         return self.n_kv_heads or self.n_heads
 
     @property
+    def pattern(self) -> str:
+        """One character a layer; "B" spells the default's block, which
+        no explicit pattern names."""
+        return self.layer_pattern or "B" * self.n_layers
+
+    @property
+    def n_attn_layers(self) -> int:
+        """Layers that hold K/V: the cache has one pool for each."""
+        return sum(c in "B*" for c in self.pattern)
+
+    @property
+    def experts_held(self) -> int:
+        return self.moe_held or self.moe_experts
+
+    @property
     def head_dim(self) -> int:
         if self.d_model % self.n_heads:
             raise ValueError(f"d_model {self.d_model} % n_heads {self.n_heads} != 0")
         return self.d_model // self.n_heads
 
     def __post_init__(self):
-        if self.pos_embed not in ("learned", "rope"):
+        if self.pos_embed not in ("learned", "rope", "none"):
             raise ValueError(
-                f"pos_embed {self.pos_embed!r} not in ('learned', 'rope')"
+                f"pos_embed {self.pos_embed!r} not in "
+                "('learned', 'rope', 'none')"
             )
+        pat = self.layer_pattern
+        if pat:
+            if len(pat) != self.n_layers or set(pat) - set("*ME"):
+                raise ValueError(
+                    f"layer_pattern {pat!r}: one of '*ME' for each of "
+                    f"the {self.n_layers} layers")
+            if "M" in pat and not (
+                    self.ssm_heads > 0
+                    and self.ssm_heads % self.ssm_groups == 0):
+                raise ValueError(
+                    "an 'M' layer needs ssm_heads > 0, a multiple of "
+                    f"ssm_groups (got {self.ssm_heads}, {self.ssm_groups})")
+            if "E" in pat and not (
+                    0 < self.moe_top_k <= self.moe_experts
+                    and self.moe_held_start + self.experts_held
+                    <= self.moe_experts
+                    and min(self.moe_latent, self.moe_d_ff,
+                            self.moe_shared_d_ff) > 0):
+                raise ValueError(
+                    "an 'E' layer needs moe_experts >= moe_top_k > 0, a "
+                    "held range inside the experts, and moe_latent, "
+                    "moe_d_ff, moe_shared_d_ff > 0")
         if self.pos_embed == "rope" and self.head_dim % 2:
             raise ValueError("rope needs an even head_dim")
         if self.attention not in ATTENTION_IMPLS:
@@ -257,6 +327,8 @@ def init_params(key, cfg: TransformerConfig):
     """f32 master params; layer weights stacked on a leading n_layers
     axis for ``lax.scan``."""
     D, F, L, V = cfg.d_model, cfg.d_ff, cfg.n_layers, cfg.vocab
+    if cfg.layer_pattern:
+        return _init_patterned(key, cfg)
     k = iter(jax.random.split(key, 8))
 
     def initn(shape, scale):
@@ -272,8 +344,8 @@ def init_params(key, cfg: TransformerConfig):
         "wo": initn((L, D, D), (2 * D * L) ** -0.5),
     }
     pos = (
-        {} if cfg.pos_embed == "rope"
-        else {"pos_embed": initn((cfg.max_seq, D), 0.02)}
+        {"pos_embed": initn((cfg.max_seq, D), 0.02)}
+        if cfg.pos_embed == "learned" else {}
     )
     if cfg.n_experts:
         E = cfg.n_experts
@@ -290,6 +362,73 @@ def init_params(key, cfg: TransformerConfig):
         "ln_f_scale": jnp.ones((D,), jnp.float32),
         "lm_head": initn((D, V), D ** -0.5),
     }
+
+
+def _init_patterned(key, cfg: TransformerConfig):
+    """f32 master params of a patterned model: ``layers`` is a tuple of
+    per-layer dicts, each with the leaves of its own mixer."""
+    from hpc_patterns_tpu.models.ssm import ssm_dims
+
+    D, L, V = cfg.d_model, cfg.n_layers, cfg.vocab
+    top_key, *layer_keys = jax.random.split(key, L + 1)
+
+    def one(kind, lkey):
+        k = iter(jax.random.split(lkey, 12))
+        n = lambda shape, scale: jax.random.normal(
+            next(k), shape, jnp.float32) * scale
+        lp = {"ln1_scale": jnp.ones((D,), jnp.float32)}
+        if kind == "*":
+            lp["wqkv"] = n((D, D + 2 * cfg.kv_heads * cfg.head_dim),
+                           D ** -0.5)
+            lp["wo"] = n((D, D), (2 * D * L) ** -0.5)
+        elif kind == "M":
+            d, H = ssm_dims(cfg), cfg.ssm_heads
+            lp["in_proj"] = n((D, d["proj"]), D ** -0.5)
+            lp["conv_w"] = n((cfg.ssm_conv, d["conv_dim"]),
+                             cfg.ssm_conv ** -0.5)
+            lp["conv_b"] = jnp.zeros((d["conv_dim"],), jnp.float32)
+            # steps log-uniform in [1e-3, 1e-1] through the softplus
+            step = jnp.exp(jax.random.uniform(
+                next(k), (H,), jnp.float32, math.log(1e-3), math.log(1e-1)))
+            lp["dt_bias"] = step + jnp.log(-jnp.expm1(-step))
+            lp["A_log"] = jnp.log(jax.random.uniform(
+                next(k), (H,), jnp.float32, 1.0, 16.0))
+            lp["D"] = jnp.ones((H,), jnp.float32)
+            lp["norm_scale"] = jnp.ones((d["d_inner"],), jnp.float32)
+            lp["out_proj"] = n((d["d_inner"], D),
+                               (2 * d["d_inner"] * L) ** -0.5)
+        else:   # "E"
+            E, held = cfg.moe_experts, cfg.experts_held
+            R, F, Fs = cfg.moe_latent, cfg.moe_d_ff, cfg.moe_shared_d_ff
+            lp["router"] = n((D, E), D ** -0.5)
+            lp["router_bias"] = jnp.zeros((E,), jnp.float32)
+            lp["w_down"] = n((D, R), D ** -0.5)
+            lp["w_up"] = n((R, D), (2 * R * L) ** -0.5)
+            lp["w1"] = n((held, R, F), R ** -0.5)
+            lp["w2"] = n((held, F, R), (2 * F) ** -0.5)
+            lp["ws1"] = n((D, Fs), D ** -0.5)
+            lp["ws2"] = n((Fs, D), (2 * Fs * L) ** -0.5)
+        return lp
+
+    kt = iter(jax.random.split(top_key, 3))
+    top = {"embed": jax.random.normal(next(kt), (V, D), jnp.float32) * 0.02,
+           "ln_f_scale": jnp.ones((D,), jnp.float32),
+           "lm_head": jax.random.normal(next(kt), (D, V), jnp.float32)
+           * D ** -0.5}
+    if cfg.pos_embed == "learned":
+        top["pos_embed"] = jax.random.normal(
+            next(kt), (cfg.max_seq, D), jnp.float32) * 0.02
+    return {**top, "layers": tuple(one(c, lk) for c, lk
+                                   in zip(cfg.layer_pattern, layer_keys))}
+
+
+def layer_params(params, l: int):
+    """Layer ``l``'s leaves: a static slice of the stacked tree, or the
+    patterned tree's own dict."""
+    layers = params["layers"]
+    if isinstance(layers, (tuple, list)):
+        return layers[l]
+    return jax.tree.map(lambda a: a[l], layers)
 
 
 #: sibling-key suffix carrying a quantized weight's per-output-channel
@@ -379,9 +518,10 @@ def matmul_weight(tree, name, dt):
 
 
 #: leaves whose use sites compute in float32 whatever ``cfg.dtype`` is:
-#: the MoE router (parallel/moe._route); the ``*_qscale`` siblings
-#: (:func:`matmul_weight`) are matched by suffix
-_FLOAT32_AT_USE = ("router",)
+#: the MoE router (parallel/moe._route, sigmoid_route) and its selection
+#: bias, the Mamba-2 mixer's decay, skip and step bias (models/ssm.py);
+#: the ``*_qscale`` siblings (:func:`matmul_weight`) are matched by suffix
+_FLOAT32_AT_USE = ("router", "router_bias", "A_log", "D", "dt_bias")
 
 
 def serving_cast_leaves(params, cfg: TransformerConfig) -> dict:
@@ -450,9 +590,9 @@ def scoped(name: str):
     return decorate
 
 
-def _rmsnorm(x, scale):
+def _rmsnorm(x, scale, eps: float = 1e-6):
     var = jnp.mean(jnp.square(x.astype(jnp.float32)), axis=-1, keepdims=True)
-    return (x * lax.rsqrt(var + 1e-6).astype(x.dtype)) * scale.astype(x.dtype)
+    return (x * lax.rsqrt(var + eps).astype(x.dtype)) * scale.astype(x.dtype)
 
 
 def apply_rope(x, positions, cfg: TransformerConfig):
@@ -643,7 +783,7 @@ def _qkv_block(x, lp, cfg: TransformerConfig, mesh):
     checkpoint it independently of the attention kernel."""
     B, T, D = x.shape
     H = cfg.n_heads
-    h = _rmsnorm(x, lp["ln1_scale"])
+    h = _rmsnorm(x, lp["ln1_scale"], cfg.norm_eps)
     q, k, v = project_qkv(h, lp, cfg)
     if cfg.pos_embed == "rope":
         # global positions: the layer always sees the full sequence (the
@@ -684,7 +824,7 @@ def _post_attn(x, o, lp, cfg: TransformerConfig, mesh, act_spec):
         if mesh is not None:
             x = lax.with_sharding_constraint(x, act_spec)
     with jax.named_scope("mlp"):
-        return x, _rmsnorm(x, lp["ln2_scale"])
+        return x, _rmsnorm(x, lp["ln2_scale"], cfg.norm_eps)
 
 
 @scoped("mlp")
@@ -749,6 +889,60 @@ def _post_block(x, o, lp, cfg: TransformerConfig, mesh, act_spec,
             aux = jnp.zeros((), jnp.float32)
             st = [jnp.ones((), jnp.float32)] if with_stats else []
         return (c(x + h, act_spec), aux, *st)
+
+
+@scoped("attn")
+def attn_out(x, o, lp):
+    """An attention-only layer's close: output projection + residual.
+    o (..., H, Dh) or (..., D)."""
+    dt = x.dtype
+    o = jnp.dot(o.reshape(x.shape).astype(dt), matmul_weight(lp, "wo", dt))
+    return x + o
+
+
+@scoped("ssm")
+def ssm_mixer(x, lp, cfg: TransformerConfig, last_pos=None):
+    """An "M" layer over a whole sequence x (B, T, D): the Mamba-2 mixer
+    in its chunked form under the pre-norm residual. Returns (x, (conv
+    tail, S)): the state at ``last_pos`` (B,), default the last
+    position."""
+    from hpc_patterns_tpu.models import ssm
+
+    h = _rmsnorm(x, lp["ln1_scale"], cfg.norm_eps)
+    out, state = ssm.mamba_prefill(h, lp, cfg, last_pos)
+    return x + out, state
+
+
+@scoped("ssm")
+def ssm_mixer_step(x, lp, cfg: TransformerConfig, state, active=None):
+    """An "M" layer on one token a row, x (B, D), against the carried
+    ``state``; rows where ``active`` is false keep theirs."""
+    from hpc_patterns_tpu.models import ssm
+
+    h = _rmsnorm(x, lp["ln1_scale"], cfg.norm_eps)
+    out, state = ssm.mamba_step(h, lp, cfg, state, active)
+    return x + out, state
+
+
+@scoped("moe")
+def moe_mixer(x, lp, cfg: TransformerConfig, valid=None):
+    """An "E" layer, x (..., D): the LatentMoE layer on this chip's share
+    of the experts under the pre-norm residual. ``valid`` (...,) bool:
+    tokens that count (the others pick no expert). Returns (x, the
+    route's stats, parallel/moe.ROUTE_STATS)."""
+    from hpc_patterns_tpu.parallel import moe
+
+    dt = x.dtype
+    D = x.shape[-1]
+    h = _rmsnorm(x, lp["ln1_scale"], cfg.norm_eps).reshape(-1, D)
+    w = lambda name: matmul_weight(lp, name, dt)
+    out, stats = moe.latent_moe(
+        h, lp["router"], lp["router_bias"], w("w_down"), w("w_up"),
+        w("w1"), w("w2"), w("ws1"), w("ws2"),
+        held_start=cfg.moe_held_start, top_k=cfg.moe_top_k,
+        scale=cfg.moe_scale,
+        valid=None if valid is None else valid.reshape(-1))
+    return x + out.reshape(x.shape).astype(dt), stats
 
 
 def _layer(x, lp, cfg: TransformerConfig, mesh, act_spec,
@@ -878,7 +1072,21 @@ def forward_hidden(params, tokens, cfg: TransformerConfig, mesh=None):
             }[cfg.remat_policy]
             layer = jax.checkpoint(layer, policy=policy)
 
-    if cfg.scan_layers:
+    if cfg.layer_pattern:
+        if mesh is not None:
+            raise ValueError(
+                "a patterned model runs unsharded (mesh=None): the state "
+                "and expert layers carry no sharding rules yet")
+        for kind, lp in zip(cfg.layer_pattern, params["layers"]):
+            if kind == "*":
+                q, k, v = _qkv_block(x, lp, cfg, None)
+                x = attn_out(x, _attention(q, k, v, cfg, None), lp)
+            elif kind == "M":
+                x, _ = ssm_mixer(x, lp, cfg)
+            else:
+                x, _ = moe_mixer(x, lp, cfg)
+        auxes = jnp.zeros((), jnp.float32)
+    elif cfg.scan_layers:
         x, auxes = lax.scan(lambda h, lp: layer(h, lp), x, params["layers"])
     else:
         aux_list = []
@@ -888,7 +1096,8 @@ def forward_hidden(params, tokens, cfg: TransformerConfig, mesh=None):
             aux_list.append(aux_i)
         auxes = jnp.stack(aux_list)
     with jax.named_scope("head"):
-        return _rmsnorm(x, params["ln_f_scale"]), jnp.sum(auxes)
+        return (_rmsnorm(x, params["ln_f_scale"], cfg.norm_eps),
+                jnp.sum(auxes))
 
 
 def moe_drop_rates(params, tokens, cfg: TransformerConfig, mesh=None):

@@ -237,3 +237,103 @@ def moe_ep(x, router_w, w1_local, w2_local, *, axis: str, capacity: int,
         return (y.astype(x.dtype), aux,
                 collectives.allreduce(kept, axis, "mean"))
     return y.astype(x.dtype), aux
+
+
+# ---------------------------------------------------------------------------
+# The drop-free route of an expert layer that holds a share of its experts
+# ---------------------------------------------------------------------------
+
+def relu2(x):
+    """relu(x)^2, the non-gated activation of the latent experts."""
+    return jnp.square(jax.nn.relu(x))
+
+
+def sigmoid_route(h, router_w, router_b, *, top_k: int, scale: float):
+    """Sigmoid scores over ALL experts, float32: the ``top_k`` chosen are
+    the top of ``score + router_b`` (a selection bias: it picks, it does
+    not weigh), the gates the chosen scores normalised to one and scaled.
+    h (N, D) -> (idx (N, k) int32, gates (N, k) float32)."""
+    s = jax.nn.sigmoid(jnp.dot(h.astype(jnp.float32),
+                               router_w.astype(jnp.float32),
+                               precision=jax.lax.Precision.HIGHEST))
+    _, idx = jax.lax.top_k(s + router_b.astype(jnp.float32), top_k)
+    g = jnp.take_along_axis(s, idx, axis=-1)
+    return idx.astype(jnp.int32), scale * g / jnp.sum(g, -1, keepdims=True)
+
+
+#: what :func:`held_experts` reports beside its result, one int32 each:
+#: picks computed here, tokens routed, the fullest held expert's picks,
+#: held experts with at least one pick, and 1 (a call). Whole numbers, so
+#: that running sums over layers and steps stay exact (they wrap, they do
+#: not stall: read them as differences) and give means:
+#: ``max_load * held / picks`` is the fullest expert over the mean load
+ROUTE_STATS = ("picks", "tokens", "max_load", "touched", "calls")
+
+
+def held_experts(x, idx, gates, w1, w2, *, held_start: int = 0,
+                 activation=relu2, valid=None):
+    """The part of a routed layer that the experts held here give.
+
+    x (N, d) tokens, idx / gates (N, k) from a route over all experts, w1
+    (held, d, f) and w2 (held, f, d): experts ``held_start ..
+    held_start + held`` of the layer. Only the picks whose expert lies in
+    that range are computed: the picks sort by held expert (a stable
+    sort; the others sort behind every group) and two grouped products
+    run over the groups, so the cost follows the picks and no pick is
+    ever dropped, whatever the imbalance. What the absent experts would
+    add is left out. ``valid`` (N,) bool: tokens that do not count (a
+    bucket's padding, an idle row) pick nothing. Returns (y (N, d)
+    float32, stats (len(ROUTE_STATS),) int32)."""
+    N, k = idx.shape
+    held = w1.shape[0]
+    local = idx - held_start
+    here = (local >= 0) & (local < held)
+    if valid is not None:
+        here = here & valid[:, None]
+    e_flat = jnp.where(here, local, held).reshape(N * k)
+    order = jnp.argsort(e_flat, stable=True)
+    sizes = jnp.zeros((held + 1,), jnp.int32).at[e_flat].add(1)[:held]
+    rows = x[order // k]                                  # (N k, d)
+    mid = activation(jax.lax.ragged_dot(rows, w1.astype(x.dtype), sizes))
+    out = jax.lax.ragged_dot(mid, w2.astype(x.dtype), sizes,
+                             preferred_element_type=jnp.float32)
+    # back to (token, choice) order: a gather, then the gated sum
+    back = jnp.zeros((N * k,), jnp.int32).at[order].set(
+        jnp.arange(N * k, dtype=jnp.int32))
+    out = out[back].reshape(N, k, -1)
+    y = jnp.sum(jnp.where(here[..., None], out * gates[..., None], 0.0),
+                axis=1)
+    tokens = (jnp.int32(N) if valid is None
+              else jnp.sum(valid, dtype=jnp.int32))
+    stats = jnp.stack([
+        jnp.sum(sizes), tokens, jnp.max(sizes),
+        jnp.sum(sizes > 0, dtype=jnp.int32), jnp.int32(1)])
+    return y, stats
+
+
+def latent_moe(h, router_w, router_b, w_down, w_up, w1, w2, ws1, ws2, *,
+               held_start: int, top_k: int, scale: float, valid=None):
+    """A LatentMoE layer on its share of the experts: h (N, D) ->
+    (out (N, D) in h's dtype, stats).
+
+        idx, g = route(h)                       over all experts
+        r   = sum_k g_k W2_k relu2(W1_k (h W_down))    the held picks
+        out = r W_up + Ws2 relu2(Ws1 h)         the shared expert, whole
+
+    The router and the shared expert read the hidden state; only the
+    routed experts live in the latent width. On one chip the layer runs
+    without its exchange: the other shares' parts are not here."""
+    dt = h.dtype
+    with jax.named_scope("route"):
+        idx, gates = sigmoid_route(h, router_w, router_b, top_k=top_k,
+                                   scale=scale)
+    with jax.named_scope("latent"):
+        u = jnp.dot(h, w_down.astype(dt))
+    with jax.named_scope("experts"):
+        r, stats = held_experts(u, idx, gates, w1, w2,
+                                held_start=held_start, valid=valid)
+    with jax.named_scope("latent"):
+        routed = jnp.dot(r.astype(dt), w_up.astype(dt))
+    with jax.named_scope("shared"):
+        shared = jnp.dot(relu2(jnp.dot(h, ws1.astype(dt))), ws2.astype(dt))
+    return routed + shared, stats
