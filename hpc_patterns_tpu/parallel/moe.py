@@ -23,6 +23,7 @@ import jax
 import jax.numpy as jnp
 
 from hpc_patterns_tpu.comm import collectives, ring
+from hpc_patterns_tpu.ops.grouped_matmul import grouped_matmul
 
 
 def _dispatch_combine(x, router_w, n_experts: int, capacity: int,
@@ -279,11 +280,11 @@ def held_experts(x, idx, gates, w1, w2, *, held_start: int = 0,
     held_start + held`` of the layer. Only the picks whose expert lies in
     that range are computed: the picks sort by held expert (a stable
     sort; the others sort behind every group) and two grouped products
-    run over the groups, so the cost follows the picks and no pick is
-    ever dropped, whatever the imbalance. What the absent experts would
-    add is left out. ``valid`` (N,) bool: tokens that do not count (a
-    bucket's padding, an idle row) pick nothing. Returns (y (N, d)
-    float32, stats (len(ROUTE_STATS),) int32)."""
+    (ops/grouped_matmul) run over the groups, so the cost follows the
+    picks and no pick is ever dropped, whatever the imbalance. What the
+    absent experts would add is left out. ``valid`` (N,) bool: tokens that
+    do not count (a bucket's padding, an idle row) pick nothing. Returns
+    (y (N, d) float32, stats (len(ROUTE_STATS),) int32)."""
     N, k = idx.shape
     held = w1.shape[0]
     local = idx - held_start
@@ -294,15 +295,17 @@ def held_experts(x, idx, gates, w1, w2, *, held_start: int = 0,
     order = jnp.argsort(e_flat, stable=True)
     sizes = jnp.zeros((held + 1,), jnp.int32).at[e_flat].add(1)[:held]
     rows = x[order // k]                                  # (N k, d)
-    mid = activation(jax.lax.ragged_dot(rows, w1.astype(x.dtype), sizes))
-    out = jax.lax.ragged_dot(mid, w2.astype(x.dtype), sizes,
-                             preferred_element_type=jnp.float32)
-    # back to (token, choice) order: a gather, then the gated sum
+    mid = grouped_matmul(rows, w1.astype(x.dtype), sizes,
+                         activation=activation)
+    out = grouped_matmul(mid, w2.astype(x.dtype), sizes,
+                         preferred_element_type=jnp.float32)
+    # back to (token, choice) order: a gather, then the gated sum. The
+    # products leave the rows behind every group unwritten: those picks
+    # are masked before anything multiplies them
     back = jnp.zeros((N * k,), jnp.int32).at[order].set(
         jnp.arange(N * k, dtype=jnp.int32))
-    out = out[back].reshape(N, k, -1)
-    y = jnp.sum(jnp.where(here[..., None], out * gates[..., None], 0.0),
-                axis=1)
+    out = jnp.where(here[..., None], out[back].reshape(N, k, -1), 0.0)
+    y = jnp.sum(out * gates[..., None], axis=1)
     tokens = (jnp.int32(N) if valid is None
               else jnp.sum(valid, dtype=jnp.int32))
     stats = jnp.stack([

@@ -1,0 +1,80 @@
+"""Kernels of the serving path compiled at their real widths for a TPU v5e
+that is described, not attached: what the chip's compiler refuses (a tile
+it cannot lay out, more VMEM than a kernel may take) fails here, at no
+chip time. Nothing runs, so nothing here is a time or a result.
+
+Keep every such test in THIS file: the process that describes the
+topology holds the TPU's library until it exits, so a second file on
+another worker could not (and would skip in silence).
+"""
+
+import re
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from hpc_patterns_tpu.ops.grouped_matmul import grouped_matmul
+from hpc_patterns_tpu.parallel.moe import relu2
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler here: nothing to guard
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture()
+def no_compile_cache():
+    """A compile for a described chip is written to the persistent cache
+    but cannot be read back without one: the next run would warn."""
+    from jax.experimental.compilation_cache import compilation_cache
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+# nemotron3-super-ep4: 128 held experts, latent 1024, expert width 2688,
+# 22 picks a token; a decode step of 64 slots, prefills of 512 and 4096
+@pytest.mark.parametrize("rows", [64 * 22, 512 * 22, 4096 * 22])
+@pytest.mark.parametrize("product", ["first", "second"])
+def test_grouped_matmul_compiles_at_the_held_experts_widths(
+        one_chip, no_compile_cache, rows, product):
+    k, n = (1024, 2688) if product == "first" else (2688, 1024)
+    kw = ({"activation": relu2} if product == "first"
+          else {"preferred_element_type": jnp.float32})
+    shape = lambda s, dt: jax.ShapeDtypeStruct(s, dt, sharding=one_chip)
+    compiled = jax.jit(
+        lambda a, b, s: grouped_matmul(a, b, s, interpret=False, **kw)
+    ).lower(shape((rows, k), jnp.bfloat16), shape((128, k, n), jnp.bfloat16),
+            shape((128,), jnp.int32)).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "grouped_matmul" in text
+
+
+def test_the_kernel_carries_its_callers_scope(one_chip, no_compile_cache):
+    """``moe_prefill_ms`` / ``moe_decode_ms_chunk`` read device time by
+    ``jax.named_scope`` path: the kernel's wrapper is jitted (one Mosaic
+    lowering a program, not one a layer), and the compiled call must
+    still say under which scope it ran."""
+    def layer(a, b, s):
+        with jax.named_scope("moe"), jax.named_scope("experts"):
+            return grouped_matmul(a, b, s, interpret=False)
+
+    shape = lambda s, dt: jax.ShapeDtypeStruct(s, dt, sharding=one_chip)
+    text = jax.jit(layer).lower(
+        shape((256, 128), jnp.bfloat16), shape((4, 128, 256), jnp.bfloat16),
+        shape((4,), jnp.int32)).compile().as_text()
+    calls = [line for line in text.splitlines() if "tpu_custom_call" in line]
+    assert calls and all(
+        re.search(r'op_name="[^"]*moe/experts/[^"]*grouped_matmul', line)
+        and re.search(r"%grouped_matmul[.\d]* = ", line) for line in calls)

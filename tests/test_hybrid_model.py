@@ -593,3 +593,16 @@ def test_scope_shows_in_the_programs_metadata(lowered, program, path):
     assert any(parts[i:i + len(want)] == want
                for parts in (loc.split("/") for loc in locs)
                for i in range(len(parts))), (program, path)
+
+
+@pytest.mark.parametrize("program", ["prefill", "chunk"])
+def test_the_grouped_products_are_the_repos_kernel(lowered, program):
+    """The held experts' products are calls of the jitted kernel wrapper
+    under ``moe/experts`` whose body is the call named ``grouped_matmul``
+    (XLA joins the two into the path a device trace finds it by:
+    tests/test_chip_compile.py), and the plain formulation they replaced
+    is in neither program."""
+    locs = set(re.findall(r'loc\("([^"]*)"', lowered[program]))
+    assert any(loc.endswith("moe/experts/jit(_call)") for loc in locs)
+    assert any(loc.startswith("grouped_matmul/") for loc in locs)
+    assert "ragged_dot" not in lowered[program]
