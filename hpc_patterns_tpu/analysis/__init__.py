@@ -9,8 +9,8 @@ scratch-slot reuse across live DMAs, collective-id collisions, dtype
 holes, and VMEM budget overflows — the chip-only bug class interpret
 mode cannot see (``pallas_rules.py`` / ``vmem.py``; runtime half:
 ``runtime.strict_semaphores``); and (the contractlint family) the
-stringly-typed producer/consumer seams: orphaned regression-gate
-keys, RunLog record-kind drift, wire-codec field incompatibility,
+stringly-typed producer/consumer seams: metric names read by string
+with no producer, RunLog record-kind drift, wire-codec field incompatibility,
 Perfetto track-band collisions, and chaos site/kind typos — checked
 whole-tree against merged extraction tables (``contracts.py`` /
 ``contract_rules.py``; ``--contract-report`` prints the tables).

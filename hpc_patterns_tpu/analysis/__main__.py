@@ -62,7 +62,7 @@ def build_parser() -> argparse.ArgumentParser:
     p.add_argument(
         "--contract-report", action="store_true",
         help="print the whole-tree producer/consumer tables the "
-             "contractlint rules judge (gate keys, metric names, "
+             "contractlint rules judge (metric and span names, "
              "record kinds, track bands, chaos names; "
              "analysis/contracts.py)")
     return p

@@ -4,17 +4,17 @@ Second pass over :mod:`hpc_patterns_tpu.analysis.contracts`'s tables.
 Every rule here anchors its findings INSIDE the module currently
 under analysis (output stays stable per-file, like every other rule
 family), but judges that module's sites against the tables merged
-over the tree the module belongs to — so deleting a gated key's
-emitter in ``bench.py`` surfaces at the surviving ``SPECS`` row in
-``harness/regress.py``, at review time, instead of as the PR 5
-runtime coverage-loss warning after a bench run already happened.
+over the tree the module belongs to — so deleting a gauge's producer
+surfaces at the surviving ``gauges.get("...")`` line in
+report/explain/autofit, at review time, instead of as a reading that
+silently went missing after a run already happened.
 
 The five rules and the seams they pin (each drifted at least once in
 review before this existed):
 
-- ``gate-key-orphan`` — ``harness/regress.py`` gate keys vs. bench
-  ``detail`` emitters; metric/span names consumed by string in
-  report/explain/autofit vs. ``metrics.gauge(...)`` producers.
+- ``gate-key-orphan`` — metric/span names consumed by string in
+  report/explain/autofit vs. ``metrics.gauge(...)`` /
+  ``mark_dispatch`` producers.
 - ``record-kind-drift`` — RunLog ``kind=`` literals written vs. the
   kinds report/collect/autofit/explain dispatch on, both directions;
   ``FORENSIC_KINDS`` in ``harness/runlog.py`` declares the kinds
@@ -49,36 +49,25 @@ def _at(site: Site) -> SimpleNamespace:
 
 @register
 class GateKeyOrphanRule(Rule):
-    """Every consumer-by-string of a bench/telemetry name must have a
-    live producer. Three contracts share the shape: (a) a
-    ``MetricSpec("detail.<key>", ...)`` row in the regression gate
-    with no ``<key>`` emitted by any bench-tree dict; (b) a metric
-    name read by string (``gauges.get("mem.hbm_pages")``) with no
-    ``.gauge/.counter/.histogram`` producer; (c) a device-window span
+    """Every consumer-by-string of a telemetry name must have a live
+    producer. Two contracts share the shape: a metric name read by
+    string (``gauges.get("mem.hbm_pages")``) with no
+    ``.gauge/.counter/.histogram`` producer; a device-window span
     name (``_windows(records, "serve.chunk")``) nothing
-    ``mark_dispatch``\\ es. All three are the "emitter deleted, gate
-    silently stops gating" failure the PR 5 runtime coverage-loss
-    warning patches over — this is the review-time version."""
+    ``mark_dispatch``\\ es. Both are the "emitter deleted, consumer
+    silently reads nothing" failure, caught at review time."""
 
     name = "gate-key-orphan"
     family = "contractlint"
-    summary = ("gate key / string-consumed metric name has no live "
+    summary = ("string-consumed metric or span name has no live "
                "emitter anywhere in the tree")
-    hint = ("restore the emitter (bench detail dict key, "
-            "metrics.gauge(...) call, or mark_dispatch span), or "
-            "delete the consumer row if the metric is gone for good")
+    hint = ("restore the emitter (metrics.gauge(...) call or "
+            "mark_dispatch span), or delete the consumer if the "
+            "metric is gone for good")
 
     def check(self, mod: ModuleInfo, config: AnalysisConfig
               ) -> Iterable[Finding]:
         t = contracts.tables_for(mod)
-        for s in t.gate_specs:
-            if s.path != mod.path or not s.name.startswith("detail."):
-                continue
-            key = s.name.split(".", 1)[1]
-            if key not in t.detail_keys:
-                yield self.finding(mod, _at(s), (
-                    f"{s.detail} gate key {s.name!r} has no emitter: "
-                    f"no bench-tree dict ever writes {key!r}"))
         for s in t.gauges_consumed:
             if s.path != mod.path:
                 continue

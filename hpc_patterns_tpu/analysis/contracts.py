@@ -2,9 +2,8 @@
 
 The repo's cross-module seams are stringly typed by design — metric
 names (``harness/metrics.py``), RunLog record kinds
-(``harness/runlog.py``), bench gate keys (``harness/regress.py``
-``SPECS`` vs. the ``detail`` dicts ``bench.py`` emits), the migration
-wire codec's field names (``serving_plane/migration.py``), Perfetto
+(``harness/runlog.py``), the migration wire codec's field names
+(``serving_plane/migration.py``), Perfetto
 device-subtrack bands (``harness/trace.py`` ``TRACK_BANDS``), and
 chaos site/kind names (``harness/chaos.py``). Every one of them is a
 producer/consumer contract that Python cannot check, and the review
@@ -18,10 +17,10 @@ tables, so a deleted emitter becomes a finding at the surviving
 consumer's line — review-time, not a runtime coverage-loss warning.
 
 Tree resolution (``tables_for``): a module under the live repo (an
-ancestor directory holding both ``bench.py`` and the
+ancestor directory holding ``pyproject.toml`` beside the
 ``hpc_patterns_tpu`` package) is judged against tables merged over
-the whole repo — package + ``bench.py`` + ``benchmarks/`` +
-``chip_smoke.py`` + ``tests/`` (fixture corpora excluded). A module under a ``fixtures``
+the whole repo — package + ``benchmarks/`` + ``chip_smoke.py`` +
+``tests/`` (fixture corpora excluded). A module under a ``fixtures``
 directory — or outside any repo root — is judged SELF-CONTAINED: its
 own file is the whole tree, which is what makes the bad/clean fixture
 twins reproducible without dragging the live tables in.
@@ -99,11 +98,6 @@ class ContractTables:
     gauges_consumed: list[Site] = field(default_factory=list)
     spans_produced: dict[str, list[Site]] = field(default_factory=dict)
     spans_consumed: list[Site] = field(default_factory=list)
-    # -- bench gate keys --------------------------------------------
-    #: every string key a bench-tree dict literal/store emits
-    detail_keys: dict[str, list[Site]] = field(default_factory=dict)
-    #: MetricSpec(...) paths consumed by the regression gate
-    gate_specs: list[Site] = field(default_factory=list)
     # -- RunLog record kinds ----------------------------------------
     kinds_produced: dict[str, list[Site]] = field(default_factory=dict)
     kinds_consumed: dict[str, list[Site]] = field(default_factory=dict)
@@ -133,9 +127,6 @@ class ContractTables:
         for name, sites in other.spans_produced.items():
             self.spans_produced.setdefault(name, []).extend(sites)
         self.spans_consumed.extend(other.spans_consumed)
-        for name, sites in other.detail_keys.items():
-            self.detail_keys.setdefault(name, []).extend(sites)
-        self.gate_specs.extend(other.gate_specs)
         for name, sites in other.kinds_produced.items():
             self.kinds_produced.setdefault(name, []).extend(sites)
         for name, sites in other.kinds_consumed.items():
@@ -253,13 +244,8 @@ def _str_tuple_elems(node: ast.AST) -> list[ast.Constant] | None:
     return None
 
 
-def extract_module(mod: ModuleInfo,
-                   bench_producer: bool = True) -> ContractTables:
-    """One module's contract sites. ``bench_producer`` gates the
-    detail-key harvest: in a live tree only ``bench.py`` /
-    ``benchmarks/`` dict keys count as gate-key emitters (a test
-    fabricating a round must not satisfy the gate table); a
-    self-contained fixture is its own bench."""
+def extract_module(mod: ModuleInfo) -> ContractTables:
+    """One module's contract sites."""
     t = ContractTables()
     path = mod.path
     consts = _module_str_constants(mod)
@@ -330,34 +316,23 @@ def extract_module(mod: ModuleInfo,
 
     # ---- whole-tree walk ------------------------------------------
     for node in ast.walk(mod.tree):
-        # dict literals: bench detail keys + "kind": producers
+        # dict literals: "kind": producers
         if isinstance(node, ast.Dict):
             for k, v in zip(node.keys, node.values):
-                key = _str_const(k) if k is not None else None
-                if key is None:
-                    continue
-                if bench_producer:
-                    t.detail_keys.setdefault(key, []).append(
-                        _site(path, k, key))
-                if key == "kind":
+                if _str_const(k) == "kind":  # k is None for a ** entry
                     kind = const_or_name(v)
                     if kind is not None:
                         t.kinds_produced.setdefault(kind, []).append(
                             _site(path, v, kind))
             continue
-        # subscript stores: x["k"] = ... (bench keys + kind)
+        # subscript stores: x["kind"] = ...
         if isinstance(node, ast.Assign) and len(node.targets) == 1 \
                 and isinstance(node.targets[0], ast.Subscript):
-            key = _str_const(node.targets[0].slice)
-            if key is not None:
-                if bench_producer:
-                    t.detail_keys.setdefault(key, []).append(
-                        _site(path, node.targets[0], key))
-                if key == "kind":
-                    kind = const_or_name(node.value)
-                    if kind is not None:
-                        t.kinds_produced.setdefault(kind, []).append(
-                            _site(path, node.value, kind))
+            if _str_const(node.targets[0].slice) == "kind":
+                kind = const_or_name(node.value)
+                if kind is not None:
+                    t.kinds_produced.setdefault(kind, []).append(
+                        _site(path, node.value, kind))
             continue
         # comparisons: kind dispatch (==/!=/in/not in)
         if isinstance(node, ast.Compare) and len(node.comparators) == 1:
@@ -435,24 +410,6 @@ def extract_module(mod: ModuleInfo,
             if name is not None:
                 t.spans_consumed.append(
                     _site(path, node.args[1], name))
-        # gate-key consumers: MetricSpec("detail.x", ...)
-        elif fname == "MetricSpec":
-            spec_path = None
-            if node.args:
-                spec_path = _str_const(node.args[0])
-            for kw in node.keywords:
-                if kw.arg == "path":
-                    spec_path = _str_const(kw.value)
-            if spec_path is not None:
-                anchor = node.args[0] if node.args else node
-                gated = True
-                for kw in node.keywords:
-                    if kw.arg == "gated" and isinstance(
-                            kw.value, ast.Constant):
-                        gated = bool(kw.value.value)
-                t.gate_specs.append(_site(
-                    path, anchor, spec_path,
-                    "gated" if gated else "informational"))
         # band references: track_band("migration")
         elif fname == "track_band" and node.args:
             name = _str_const(node.args[0])
@@ -512,23 +469,22 @@ _MODULE_CACHE: dict[tuple[str, int], ContractTables] = {}
 _TREE_CACHE: dict[str, ContractTables] = {}
 
 
-def _cached_extract(mod: ModuleInfo,
-                    bench_producer: bool) -> ContractTables:
-    key = (mod.path, hash((mod.source, bench_producer)))
+def _cached_extract(mod: ModuleInfo) -> ContractTables:
+    key = (mod.path, hash(mod.source))
     if key not in _MODULE_CACHE:
-        _MODULE_CACHE[key] = extract_module(mod, bench_producer)
+        _MODULE_CACHE[key] = extract_module(mod)
         if len(_MODULE_CACHE) > 512:
             _MODULE_CACHE.pop(next(iter(_MODULE_CACHE)))
     return _MODULE_CACHE[key]
 
 
 def find_repo_root(path: str | Path) -> Path | None:
-    """Nearest ancestor holding both ``bench.py`` and the
+    """Nearest ancestor holding ``pyproject.toml`` beside the
     ``hpc_patterns_tpu`` package — the live tree the tables merge
     over. None for a module outside any repo checkout."""
     p = Path(path).resolve()
     for parent in [p] + list(p.parents):
-        if (parent / "bench.py").is_file() \
+        if (parent / "pyproject.toml").is_file() \
                 and (parent / "hpc_patterns_tpu").is_dir():
             return parent
     return None
@@ -538,23 +494,20 @@ def _is_fixture(path: str | Path) -> bool:
     return "fixtures" in Path(path).parts
 
 
-def tree_files(root: Path) -> list[tuple[Path, bool]]:
-    """(file, is_bench_producer) for every harvested tree file:
-    package + tests as producers/consumers of every contract EXCEPT
-    gate keys, whose producer side is bench.py/benchmarks only."""
-    out: list[tuple[Path, bool]] = []
-    roots = [(root / "hpc_patterns_tpu", False),
-             (root / "tests", False),
-             (root / "chip_smoke.py", False),
-             (root / "bench.py", True),
-             (root / "benchmarks", True)]
-    for base, is_bench in roots:
+def tree_files(root: Path) -> list[Path]:
+    """Every harvested tree file: the package, the tests and the
+    scripts beside them, as producers and consumers of every
+    contract."""
+    out: list[Path] = []
+    for name in ("hpc_patterns_tpu", "tests", "chip_smoke.py",
+                 "benchmarks"):
+        base = root / name
         if not base.exists():
             continue
         for f in iter_python_files([base]):
             if _is_fixture(f):
                 continue  # fixture corpora are their own trees
-            out.append((f, is_bench))
+            out.append(f)
     return out
 
 
@@ -566,12 +519,12 @@ def live_tables(root: Path) -> ContractTables:
         return _TREE_CACHE[key]
     tables = ContractTables(root=key)
     files: list[str] = []
-    for f, is_bench in tree_files(root):
+    for f in tree_files(root):
         try:
             mod = ModuleInfo.parse(f)
         except SyntaxError:
             continue  # parse-error is the engine's finding, not ours
-        tables.merge(_cached_extract(mod, bench_producer=is_bench))
+        tables.merge(_cached_extract(mod))
         files.append(str(f))
     tables.files = tuple(files)
     _TREE_CACHE[key] = tables
@@ -587,7 +540,7 @@ def tables_for(mod: ModuleInfo) -> ContractTables:
         if root is not None:
             return live_tables(root)
     tables = ContractTables()
-    tables.merge(_cached_extract(mod, bench_producer=True))
+    tables.merge(_cached_extract(mod))
     tables.files = (mod.path,)
     return tables
 
@@ -595,8 +548,8 @@ def tables_for(mod: ModuleInfo) -> ContractTables:
 def tables_for_paths(paths) -> ContractTables:
     """The ``--contract-report`` entry point: the live tree's tables
     when the first path sits inside a repo checkout, else the merged
-    tables of exactly the files given (every file a bench producer —
-    the fixture/self-contained convention)."""
+    tables of exactly the files given (the fixture/self-contained
+    convention)."""
     paths = list(paths)
     root = find_repo_root(paths[0]) if paths else None
     if root is not None:
@@ -608,7 +561,7 @@ def tables_for_paths(paths) -> ContractTables:
             mod = ModuleInfo.parse(f)
         except SyntaxError:
             continue
-        tables.merge(_cached_extract(mod, bench_producer=True))
+        tables.merge(_cached_extract(mod))
         files.append(str(f))
     tables.files = tuple(files)
     return tables
@@ -640,16 +593,6 @@ def format_contract_report(tables: ContractTables) -> str:
     lines.append(f"contractlint report over "
                  f"{len(tables.files)} file(s)"
                  + (f" [{root}]" if root else " [self-contained]"))
-
-    lines.append("\ngate keys (harness/regress.py SPECS -> bench "
-                 "detail emitters):")
-    for s in tables.gate_specs:
-        key = s.name.split(".", 1)[1] if s.name.startswith(
-            "detail.") else s.name
-        producers = tables.detail_keys.get(key, [])
-        status = (_fmt_sites(producers, root) if producers
-                  else "MISSING EMITTER")
-        lines.append(f"  {s.name:<40} [{s.detail:<13}] <- {status}")
 
     lines.append("\nmetric names consumed by string "
                  "(report/explain/autofit) -> producers:")

@@ -1,6 +1,6 @@
 """Where the persistent XLA compile cache lives — one rule for every
-entry point (the apps' ``run_instrumented``, ``bench.py``'s measurement
-child, ``benchmarks/*.py``, ``__graft_entry__``, ``tests/conftest.py``).
+entry point (the apps' ``run_instrumented``, ``chipbench/run.py``,
+``benchmarks/*.py``, ``__graft_entry__``, ``tests/conftest.py``).
 
 - ``JAX_COMPILATION_CACHE_DIR`` set: jax reads it itself, and this
   module sets no directory in code — whoever placed the cache from

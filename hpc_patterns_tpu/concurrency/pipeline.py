@@ -358,8 +358,8 @@ def per_pass_seconds(
     measurement to ~``target_s`` of device time, then
     harness.timing.amortized_seconds differences two device-dominated
     pass counts so dispatch-latency jitter divides by tens of thousands
-    of passes. Shared by bench.py and the concurrency app's on-chip
-    engine."""
+    of passes. The concurrency app's on-chip engine calls it
+    (``apps/concurrency_app.py``)."""
     from hpc_patterns_tpu.harness.timing import amortized_seconds, measure_forced
 
     run = lambda p: overlap_run(hbm_array, mode=mode, tripcount=tripcount,
@@ -382,8 +382,8 @@ def balance_tripcount(per_pass, copy_time_s, compute_mode, trips, *,
     ``copy_time_s`` (the C12 balance step, sycl_con.cpp:257-268 — linear
     T(trips), iterated because one probe's noise would leave the commands
     unbalanced). Returns ``(trips, t_compute)``, measured with
-    ``per_pass(mode, trips)``. Shared by bench.py and the concurrency
-    app's on-chip engine so the clamp and convergence rules can't drift."""
+    ``per_pass(mode, trips)``. The concurrency app's on-chip engine
+    calls it; ``tests/test_concurrency.py`` pins the clamp."""
     t_comp = per_pass(compute_mode, trips)
     for _ in range(rounds):
         if t_comp <= 0 or copy_time_s <= 0:

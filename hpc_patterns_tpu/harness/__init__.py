@@ -19,8 +19,8 @@ from hpc_patterns_tpu.harness.metrics import (  # noqa: F401
     get_metrics,
     span,
 )
-# harness.trace (the flight recorder) and harness.regress (the bench
-# gate) are deliberately NOT re-exported here: both are `python -m`
-# CLIs, and importing them in the package __init__ would make runpy
-# warn about double import. Use `from hpc_patterns_tpu.harness import
-# trace` directly, as report.py and the apps do.
+# harness.trace (the flight recorder) is deliberately NOT re-exported
+# here: it is a `python -m` CLI, and importing it in the package
+# __init__ would make runpy warn about double import. Use `from
+# hpc_patterns_tpu.harness import trace` directly, as report.py and
+# the apps do.

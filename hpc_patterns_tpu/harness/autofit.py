@@ -1,7 +1,7 @@
 """autofit — profile-driven configuration: observability becomes control.
 
 The observability ladder (metrics registry → flight recorder →
-regression gate → distributed merge + rollups) stops at diagnosis: a
+distributed merge + rollups) stops at diagnosis: a
 human reads the Perfetto fan and hand-tunes the prompt ladder, the
 residency knobs, the placement policy, and the autoscaler thresholds.
 This module closes the loop: it consumes the RunLog records a prior run

@@ -75,10 +75,9 @@ def base_parser(description: str) -> argparse.ArgumentParser:
 
 
 def add_serving_args(p: argparse.ArgumentParser) -> None:
-    """The serving-engine knobs shared by the serving surfaces
-    (serve_app; benchmarks/bench_serving.py mirrors them through its
-    own flag parser): the prompt-length bucket ladder, the sampling
-    mode, and the admission-overlap toggle."""
+    """The serving-engine knobs of the serving surface (serve_app):
+    the prompt-length bucket ladder, the sampling mode, and the
+    admission-overlap toggle."""
     p.add_argument(
         "--prompt-buckets",
         default="auto",
@@ -109,9 +108,8 @@ def add_serving_args(p: argparse.ArgumentParser) -> None:
     )
 
 
-#: the serving precision knob's legal values — ONE definition shared
-#: by serve_app and benchmarks/bench_serving.py (the two surfaces must
-#: not drift on what "--kv-dtype fp8" means)
+#: the serving precision knob's legal values — ONE definition of what
+#: "--kv-dtype fp8" means (tests/test_quantization.py pins it)
 KV_DTYPE_CHOICES = ("f32", "bf16", "int8", "fp8")
 
 #: --kv-dtype value -> (compute dtype override or None, kv_cache_dtype)
@@ -125,9 +123,8 @@ _KV_DTYPE_MAP = {
 
 def add_kv_dtype_arg(p: argparse.ArgumentParser,
                      default: str = "f32") -> None:
-    """The shared ``--kv-dtype`` serving-precision flag (serve_app;
-    bench_serving mirrors it through its own flag parser but resolves
-    through the SAME :func:`resolve_kv_cache_dtype`)."""
+    """The ``--kv-dtype`` serving-precision flag (serve_app), resolved
+    by :func:`resolve_kv_cache_dtype`."""
     p.add_argument(
         "--kv-dtype",
         default=default,
@@ -188,8 +185,7 @@ def parse_buckets(spec: str, max_prompt_len: int):
 
 def add_autofit_arg(p: argparse.ArgumentParser) -> None:
     """The shared ``--autofit`` flag: every serving surface that can
-    consume a FittedConfig (serve_app, plane_app; bench_serving mirrors
-    it through its own flag parser) ingests through the SAME
+    consume a FittedConfig (serve_app, plane_app) ingests through the SAME
     :func:`load_autofit`, so a config fitted once applies identically
     everywhere."""
     p.add_argument(
@@ -206,8 +202,7 @@ def add_autofit_arg(p: argparse.ArgumentParser) -> None:
 
 def add_explain_args(p: argparse.ArgumentParser) -> None:
     """The shared ``--explain``/``--explain-out`` pair: every serving
-    surface (serve_app, plane_app; bench_serving mirrors them through
-    its own flag parser) enables request-scoped lifecycle tracing
+    surface (serve_app, plane_app) enables request-scoped lifecycle tracing
     (harness/reqtrace.py) the same way and renders the SAME
     per-class tail-attribution table (harness/explain.py) after its
     goodput row — where every p99 went, by lifecycle segment."""

@@ -1,7 +1,7 @@
 """Cross-rank trace collection: clock-aligned merge + skew rollups.
 
-Rung 4 of the observability ladder. Rungs 1–3 (metrics histograms,
-flight recorder + Chrome-trace export, regression gate) see exactly one
+Rung 4 of the observability ladder. Rungs 1–3 (run log, metrics
+histograms, flight recorder + Chrome-trace export) see exactly one
 process — but the reference's miniapps only ever run under ``mpirun
 -np 4``, and for communication patterns the interesting signal IS
 cross-rank: collective skew, stragglers, and the rank-MAX timing rule

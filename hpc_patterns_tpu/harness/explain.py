@@ -28,13 +28,13 @@ preemption, a migration) is blamed on the mechanism that caused it
 instead of vanishing into a fat TPOT mean. The gap band is the gaps
 at/above the exact pooled p99 of gap width.
 
-Three numbers feed the bench gate (harness/regress.py):
+Three scalars summarize a digest (tests/test_reqtrace.py pins them):
 
 - ``coverage_frac`` — 1 - untracked share over all finished requests
-  (gated HIGHER with tight slack: attribution that quietly loses
-  coverage is worse than no attribution);
+  (attribution that quietly loses coverage is worse than no
+  attribution);
 - ``ttft_p99_queue_share`` — queued share of the pooled p99 band's
-  TTFT windows (captured per round; the single scalar that says
+  TTFT windows (the single scalar that says
   whether the tail is a scheduling problem or a compute problem);
 - ``tpot_p99_stall_share`` — the :data:`TPOT_STALL_KINDS` share of
   the pooled p99 inter-token gap band (the single scalar that says
@@ -47,7 +47,7 @@ Usage::
 
 Exit 0 when at least one reqtrace record was found; 2 otherwise.
 The same :func:`digest`/:func:`format_explain` pair backs the
-``--explain`` flag in serve_app / plane_app / bench_serving
+``--explain`` flag in serve_app / plane_app
 (harness/cli.add_explain_args). docs/observability.md#request-forensics.
 """
 
@@ -343,9 +343,9 @@ def format_explain(dig: Mapping[str, Any]) -> str:
 def digest_from_stats(stats: Mapping[int, Mapping[str, Any]],
                       tracer: reqtrace.ReqTrace,
                       worst_n: int = WORST_N) -> dict[str, Any]:
-    """One-step digest for in-process surfaces (serve_app/plane_app/
-    bench_serving): snapshot the live recorder against the run's
-    stats table and fold it."""
+    """One-step digest for in-process surfaces (serve_app/plane_app):
+    snapshot the live recorder against the run's stats table and fold
+    it."""
     return digest([tracer.snapshot(stats)], worst_n=worst_n)
 
 

@@ -1,6 +1,6 @@
 """Open-loop load generation: seeded arrival processes for serving.
 
-``bench_serving``'s original stream is CLOSED-loop: every request is
+A stream submitted whole (``serve_app``'s) is CLOSED-loop: every request is
 queued up front and a new one only makes progress when the engine frees
 capacity — so the offered load adapts to the server and overload can
 never happen. Real traffic is OPEN-loop: arrivals come on the *users'*

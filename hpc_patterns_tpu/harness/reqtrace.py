@@ -313,8 +313,8 @@ def finalize(segments: Iterable, t_submit: float, t_finish: float
 def coverage_frac(segments: Iterable, t_submit: float,
                   t_finish: float) -> float:
     """1 - untracked share of ``[t_submit, t_finish]`` (1.0 for a
-    zero-length life) — the per-request form of the gated run-level
-    ``attribution_coverage_frac``."""
+    zero-length life) — the per-request form of the digest's
+    run-level ``coverage_frac`` (harness/explain.py)."""
     span = max(0.0, t_finish - t_submit)
     if span <= 0:
         return 1.0

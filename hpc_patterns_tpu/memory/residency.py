@@ -529,9 +529,9 @@ class ResidencyManager:
     @property
     def prefetch_overlap_frac(self) -> float | None:
         """Measured fraction of prefetch-window time spent under the
-        consumer's in-flight compute windows — the proved-overlap
-        number ``bench_serving --offload`` reports and
-        ``harness/regress.py`` gates. None until a pull completed."""
+        consumer's in-flight compute windows (a device reading only
+        on the chip; no benchmark cell holds it yet, ROADMAP Design).
+        None until a pull completed."""
         if self._prefetch_total_s <= 0:
             return None
         return self._prefetch_overlap_s / self._prefetch_total_s

@@ -17,10 +17,10 @@ for draft-assisted sampling, applied to precision:
   per teacher-forced step — the distributional distance sampling
   inherits, reported as mean and max over the walk.
 
-``bench_serving --kv-dtype`` runs this oracle BEFORE reporting any
-quantized number (bounds in :data:`DEFAULT_BOUNDS`), and the tier-1
-tests pin the same bounds per precision (int8/fp8 KV, int8 weights,
-and the composed forms). docs/quantization.md has the full matrix.
+The tier-1 tests (tests/test_quantization.py) run this oracle at the
+bounds of :data:`DEFAULT_BOUNDS`, one case per precision (int8/fp8 KV,
+int8 weights, and the composed forms), and show that a broken dequant
+fails it. docs/quantization.md has the full matrix.
 
 The same law judges a token stream an engine ALREADY emitted
 (:func:`emitted_stream_law`): on the TPU, bf16 matmuls round

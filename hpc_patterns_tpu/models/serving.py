@@ -158,9 +158,9 @@ shared prefix pages (tests/test_prefix_cache.py — greedy AND
 sampled, under preemption and migration).
 
 Reference lineage: the benchmark-IS-the-test discipline
-(aurora.mpich.miniapps/src/CMakeLists.txt:39-50) — the engine's
-throughput benchmark (benchmarks/bench_serving.py) validates the
-oracle on every run.
+(aurora.mpich.miniapps/src/CMakeLists.txt:39-50) — the benchmark's
+serving cells (chipbench/drivers/serve.py) hold every run's served
+tokens against a plain reference before a number counts.
 """
 
 from __future__ import annotations
@@ -300,7 +300,7 @@ def pad_to_bucket(buckets, prompt_len: int) -> int:
     """The padded prefill length: the smallest ladder rung that fits
     (the exact length when ``buckets`` is None). THE single pad rule —
     the engine pads admissions with it and pool-sizing callers
-    (serve_app, bench_serving) must size with the same function, or
+    (serve_app) must size with the same function, or
     ``pages_needed`` desynchronizes from what admission writes."""
     if buckets is None:
         return prompt_len
@@ -583,8 +583,8 @@ def _held_weights(params, cfg: TransformerConfig):
 def prefill_cache_size() -> int:
     """Compiled admission-prefill variants in this process (the jit
     cache of :func:`_prefill_one`) — THE compile-count observable the
-    bucket-ladder claim is asserted against (tests) and reported by
-    (benchmarks/bench_serving.py). One entry per distinct (padded
+    bucket-ladder claim is asserted against
+    (tests/test_serving.py). One entry per distinct (padded
     length, config) pair across every engine in the process. A
     consumer of the flight recorder's shared probe
     (harness.trace.jit_cache_size), which compile_watch diffs to stamp
@@ -1161,8 +1161,8 @@ class EngineCore:
     def prefill_skip_frac(self) -> float:
         """Fraction of submitted prompt tokens whose prefill was
         SKIPPED via a prefix match — the headline capacity/TTFT
-        observable (``serve.prefill_skip_frac``; measured and gated by
-        ``bench_serving --shared`` / ``harness/regress.py``)."""
+        observable (``serve.prefill_skip_frac``;
+        tests/test_prefix_cache.py pins it on a template mix)."""
         if not self._prefill_total_tokens:
             return 0.0
         return self._prefill_skip_tokens / self._prefill_total_tokens
@@ -2563,7 +2563,7 @@ class EngineCore:
         # migration-overlap accounting prunes and reads this same
         # deque — popping here would delete windows its still-open
         # migrations intersect (and vice versa would understate the
-        # gated overlap fractions). The deque's maxlen bounds memory.
+        # overlap fractions). The deque's maxlen bounds memory.
         floor = min(h[3] for _, h in self._installed_prefetch)
         windows = [w for w in self.chunk_windows if w[1] >= floor]
         for _bundle, handle in self._installed_prefetch:
@@ -2692,7 +2692,7 @@ class ContinuousBatcher(EngineCore):
             sid = self.submit(**kw)
             # the request entered on the SCHEDULE's clock, not when
             # the loop got around to draining it: TTFT, deadlines, and
-            # the gated goodput must charge the queueing delay the
+            # the goodput must charge the queueing delay the
             # user actually experienced (the drain can lag a whole
             # chunk round or an injected stall behind the arrival
             # instant)
@@ -2725,7 +2725,7 @@ class ContinuousBatcher(EngineCore):
         ``arrivals``: OPEN-loop traffic — ``(t_rel_s, submit_kwargs)``
         pairs; each is submitted once the run clock passes its arrival
         instant (``harness/loadgen.py`` schedules replay this way —
-        see ``benchmarks/bench_serving.run_scenario``). The loop idles
+        see ``chipbench/drivers/serve.py``). The loop idles
         in bounded sleeps when nothing is servable but arrivals remain:
         open-loop means traffic comes on the USERS' clock, so overload
         builds queues (and sheds / preempts) instead of slowing the

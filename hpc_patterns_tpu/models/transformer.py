@@ -476,8 +476,8 @@ def quantize_weights_int8(params):
 
     Token identity CANNOT hold across precision — the law is pinned
     TV-distance-style by the sampling oracles instead (greedy top-1
-    agreement rate + total-variation bounds, tests/test_quantization.py
-    and ``bench_serving --kv-dtype``; docs/quantization.md)."""
+    agreement rate + total-variation bounds, tests/test_quantization.py;
+    docs/quantization.md)."""
     if "router" in params["layers"]:
         raise ValueError(
             "quantize_weights_int8 covers dense decode layers "

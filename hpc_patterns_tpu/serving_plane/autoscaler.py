@@ -45,11 +45,12 @@ Three pieces:
   AND sampled (the same contract preemption and migration already
   carry).
 
-The robustness verdict lives in ``bench_serving --elastic``: a
-diurnal ramp under replica-death chaos where this plane holds
-per-class SLO attainment while the fixed plane demonstrably sheds,
-with ``goodput_per_replica_round`` gated so the trajectory rewards
-efficiency, not just peak (docs/serving_plane.md "Elastic plane").
+The robustness verdict lives in tests/test_autoscaler.py and
+tests/test_serving_plane.py (TestReplicaDeathStaticPlane): under
+replica-death chaos this plane serves every request while the fixed
+plane demonstrably sheds; ``goodput_per_replica_round`` is the
+efficiency reading beside it (docs/serving_plane.md "Elastic plane")
+and no benchmark cell holds either yet (ROADMAP Design).
 """
 
 from __future__ import annotations

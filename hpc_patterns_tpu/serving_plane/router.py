@@ -53,12 +53,12 @@ ends share one chain; the launched plane records one chain per side).
 ``kv_migration_overlap_frac``: Σ over migrations of the window time
 spent under an in-flight decode chunk on the DESTINATION replica,
 over Σ window time — the measured proof that the handoff hid behind
-compute (gated via ``detail.kv_migration_overlap_frac``).
+compute.
 ``dma_migration_overlap_frac`` is the same ratio restricted to
 bundles that actually rode the DMA tier (None when none did — a
 fallback can't impersonate the kernel path), and
 ``migration_bytes_per_round`` pins the dataplane pressure the tier
-carries; both are regress-gated (``harness/regress.py``).
+carries (tests/test_serving_plane.py pins all three).
 """
 
 from __future__ import annotations
@@ -309,7 +309,7 @@ class ServingPlane:
         #: exists to drive to zero)
         self.shed_on_death = 0
         #: Σ over plane rounds of live (serving) replica count — the
-        #: denominator of ``goodput_per_replica_round``: the gated
+        #: denominator of ``goodput_per_replica_round``: the
         #: efficiency metric that rewards holding the SLO with FEWER
         #: replica-rounds, not just holding it
         self.replica_rounds = 0
@@ -1007,8 +1007,7 @@ class ServingPlane:
         """SLO-attained tokens per (live replica × plane round) — the
         EFFICIENCY headline of the elastic trajectory: a plane that
         holds attainment by over-provisioning pays for it here, one
-        that sheds pays in the numerator. Gated via
-        ``detail.goodput_per_replica_round`` (harness/regress.py).
+        that sheds pays in the numerator.
         None until a run with ``slo=`` completed."""
         if self.last_slo is None or not self.replica_rounds:
             return None
@@ -1021,7 +1020,6 @@ class ServingPlane:
         """Σ dispatched KV-payload bytes per plane round — the
         dataplane-pressure headline the transport tier exists to hide:
         the SAME bytes cross whichever transport resolved, so this
-        number is transport-invariant and regress-gated
-        (``detail.migration_bytes_per_round``) as a workload-shape
+        number is transport-invariant: a workload-shape
         pin rather than a speed score. 0.0 before any round ran."""
         return self.migration_bytes / max(1, self.rounds_total)

@@ -1,34 +1,8 @@
-"""Known-bad: orphaned gate keys and string-consumed metric names —
-the minimized replica of "a gated key whose emitter was deleted". The
-gate table still lists ``detail.engine_bubble_frac``, but the bench
-detail dict below stopped emitting it (the PR 5 runtime coverage-loss
-warning fired one bench run too late; contractlint flags the
-surviving consumer row at review time). Same shape for a metric name
-read by string with no gauge producer, and a device-window span name
-nothing dispatches."""
-
-
-class MetricSpec:
-    def __init__(self, path, direction, gated=True, abs_slack=0.0):
-        self.path, self.direction = path, direction
-        self.gated, self.abs_slack = gated, abs_slack
-
-
-SPECS = (
-    MetricSpec("value", "higher"),
-    MetricSpec("detail.engine_tok_s", "higher"),
-    # the emitter below used to write this key; it was deleted in a
-    # "cleanup" and the gate row survived
-    MetricSpec("detail.engine_bubble_frac", "lower"),  # EXPECT: gate-key-orphan
-)
-
-
-def bench_detail(engine_result):
-    """The bench child's detail dict — engine_bubble_frac is gone."""
-    return {
-        "value": engine_result["speedup"],
-        "engine_tok_s": round(engine_result["tok_s"], 1),
-    }
+"""Known-bad: string-consumed metric names with no live producer —
+the minimized replica of "a consumer whose emitter was deleted". The
+gauge was renamed and the read kept the old name; the device-window
+span is consumed and nothing dispatches it. contractlint flags the
+surviving consumer at review time."""
 
 
 def fit_engine(gauges, records):

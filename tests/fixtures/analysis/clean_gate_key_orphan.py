@@ -1,27 +1,5 @@
-"""Known-clean: every gate key, metric name, and span name consumed
-here has a live producer in the same tree. Zero findings expected."""
-
-
-class MetricSpec:
-    def __init__(self, path, direction, gated=True, abs_slack=0.0):
-        self.path, self.direction = path, direction
-        self.gated, self.abs_slack = gated, abs_slack
-
-
-SPECS = (
-    MetricSpec("value", "higher"),
-    MetricSpec("detail.engine_tok_s", "higher"),
-    MetricSpec("detail.engine_bubble_frac", "lower", abs_slack=0.05),
-)
-
-
-def bench_detail(engine_result):
-    """The bench child's detail dict — emits every gated key."""
-    return {
-        "value": engine_result["speedup"],
-        "engine_tok_s": round(engine_result["tok_s"], 1),
-        "engine_bubble_frac": round(engine_result["bubble_frac"], 4),
-    }
+"""Known-clean: every metric name and span name consumed here has a
+live producer in the same tree. Zero findings expected."""
 
 
 def fit_engine(gauges, records):

@@ -1087,40 +1087,21 @@ class TestStrictSemaphores:
 class TestContractlint:
     """Whole-tree producer/consumer verification (contractlint): the
     static tables agree with the live tree, and the motivating
-    deleted-emitter shape is caught at the surviving gate row."""
-
-    def test_static_gate_key_table_covers_every_gate_spec(self):
-        # the static twin of regress.py's runtime coverage-loss
-        # warning: every detail.* key the gate table consumes must
-        # have an emitter in bench.py/benchmarks/ BEFORE any bench
-        # run happens — a deleted emitter fails here, not one silent
-        # bench run later
-        from hpc_patterns_tpu.analysis import contracts
-        from hpc_patterns_tpu.harness import regress
-
-        root = contracts.find_repo_root(Path(__file__).resolve())
-        assert root is not None
-        tables = contracts.live_tables(root)
-        for spec in regress.SPECS:
-            if not spec.path.startswith("detail."):
-                continue
-            key = spec.path.split(".", 1)[1]
-            assert key in tables.detail_keys, (
-                f"gate key {spec.path} has no static emitter in "
-                f"bench.py/benchmarks/")
+    deleted-emitter shape is caught at the surviving consumer."""
 
     def test_deleted_emitter_replica_flagged_at_the_gate_row(self):
-        # the minimized "gated key whose emitter was deleted" replica:
-        # the finding anchors at the surviving MetricSpec row, exactly
-        # where its EXPECT marker sits
+        # the minimized "consumer whose emitter was renamed away"
+        # replica: the finding anchors at the surviving string read,
+        # exactly where its EXPECT marker sits
         path = FIXTURES / "bad_gate_key_orphan.py"
         live, _ = core.analyze_file(path)
         orphans = [f for f in live if f.rule == "gate-key-orphan"]
         assert orphans, "the deleted-emitter replica must be flagged"
         lines = path.read_text().splitlines()
         gate_rows = [f for f in orphans
-                     if "detail.engine_bubble_frac" in lines[f.line - 1]]
-        assert gate_rows, "finding must anchor at the gate-table row"
+                     if 'gauges.get("engine.tokens_per_s")'
+                     in lines[f.line - 1]]
+        assert gate_rows, "finding must anchor at the consumer's line"
         assert "EXPECT: gate-key-orphan" in lines[gate_rows[0].line - 1]
 
     def test_fixture_worlds_are_self_contained(self):
@@ -1166,15 +1147,14 @@ class TestContractlint:
         assert cli.main(["--contract-report"]) == 0
         out = capsys.readouterr().out
         assert "contractlint report over" in out
-        for section in ("gate keys (harness/regress.py SPECS",
-                        "metric names consumed by string",
+        for section in ("metric names consumed by string",
                         "RunLog record kinds",
                         "device-subtrack bands",
                         "chaos contract"):
             assert section in out
-        # the live tree is burned down: every gate key has an
-        # emitter and every string-consumed metric a producer. (The
-        # record-kind section may show residue from deliberate test
-        # fabrications — those carry rule-layer suppressions.)
-        assert "MISSING EMITTER" not in out
+        # the live tree is burned down: every string-consumed metric
+        # has a producer. (The record-kind section may show residue
+        # from deliberate test fabrications — those carry rule-layer
+        # suppressions.)
+        assert "gate keys" not in out
         assert "MISSING PRODUCER" not in out

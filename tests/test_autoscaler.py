@@ -192,6 +192,9 @@ class TestElasticPlane:
         # the spin-up span was measured
         assert len(plane.spinup_s) >= 1
         assert all(s > 0 for s in plane.spinup_s)
+        # every request attains: the fixed plane's death sheds and
+        # reads below 1 (test_serving_plane.py, TestReplicaDeath)
+        assert plane.last_slo["total"]["attained_frac"] == 1.0
         for rid, (p, m) in zip(ids, reqs):
             assert plane.stats[rid]["outcome"] == "ok"
             np.testing.assert_array_equal(

@@ -6,7 +6,8 @@ tilings with known spends; the publish half is pinned against a
 captured emit stream and the metrics registry; the end-to-end claim
 — seeded chaos breaches the budget bucket it was injected into and
 NO other — is pinned through the real engine in
-tests/test_bench_serving.py (run_slo_budget asserts it in-run).
+tests/test_reqtrace.py (TestChaosAttribution, on the tiered stream
+that file already compiles).
 """
 
 import pytest

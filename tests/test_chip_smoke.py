@@ -1,7 +1,7 @@
 """Tier-1 for the bring-up plumbing: ``chip_smoke.py`` itself (run the
 way an operator runs it, as a subprocess), the compile-cache helper,
 the apps' device header / ``--backend`` refusal / kernel-mode record,
-``bench.py``'s exit status, and the native loader's source stamp.
+and the native loader's source stamp.
 
 The chip is not here: what these pin is that the script's legs and
 checks work at toy sizes when the operator TYPES ``--platform cpu
@@ -141,35 +141,6 @@ class TestBackendRefusal:
         out = capsys.readouterr().out
         assert "ERROR: no devices for platform prefix 'tpu'" in out
         assert "FAILURE" in out
-
-
-class TestBenchExitStatus:
-    @pytest.fixture()
-    def bench(self):
-        sys.path.insert(0, str(REPO))
-        import bench
-
-        return bench
-
-    def test_status_of_a_verdict_line(self, bench):
-        ok = {"metric": "m", "value": 1.8,
-              "detail": {"degenerate": False, "error": None}}
-        assert bench._capture_status(json.dumps(ok)) == 0
-        degenerate = {"detail": {"degenerate": True}}
-        assert bench._capture_status(json.dumps(degenerate)) == 1
-        failed_row = {"detail": {"degenerate": False,
-                                 "serving_error": "ValueError: x"}}
-        assert bench._capture_status(json.dumps(failed_row)) == 1
-        assert bench._capture_status("not json") == 1
-
-    def test_non_tpu_backend_prints_its_line_and_exits_nonzero(self):
-        r = subprocess.run([sys.executable, str(REPO / "bench.py")],
-                           cwd=REPO, capture_output=True, text=True,
-                           timeout=120)
-        assert r.returncode != 0
-        line = json.loads(r.stdout.strip().splitlines()[-1])
-        assert line["detail"]["degenerate"] is True
-        assert "not 'tpu'" in line["detail"]["error"]
 
 
 class TestNativeSourceStamp:

@@ -4,7 +4,8 @@ The reference's own test is performance-property-based (overlap speedup,
 SURVEY.md §4.3) — inherently timing-dependent, so on the CPU test mesh we
 assert *mechanics and correctness* (kernel math, command lifecycle, mode
 dispatch, autotuner behavior, verdict wiring) and leave the overlap PASS
-claim to real-TPU runs (bench.py / the driver).
+claim to real-TPU runs (``chip_smoke.py``'s concurrency leg; no benchmark
+cell holds the number yet: PERF.md section 7, ``overlap-1chip``).
 """
 
 import json

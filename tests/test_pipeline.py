@@ -1,9 +1,11 @@
 """On-chip DMA/compute pipeline tests (the Pallas side of C1).
 
-Timing claims are TPU-only (bench.py); here the interpreter validates the
-kernel *semantics*: all computing variants produce the identical checksum
-(the reference's self-validation idea, SURVEY.md §4.2), scalars are
-runtime (no recompiles), and the amortized-timing protocol is sane.
+Timing claims are TPU-only (``concurrency_app`` on the chip; no benchmark
+cell holds them yet: PERF.md section 7, ``overlap-1chip``); here the
+interpreter validates the kernel *semantics*: all computing variants
+produce the identical checksum (the reference's self-validation idea,
+SURVEY.md §4.2), scalars are runtime (no recompiles), and the
+amortized-timing protocol is sane.
 """
 
 import numpy as np

@@ -531,6 +531,21 @@ class TestQuantizedRoundTrips:
             assert (mgr_q.prefetch_bytes
                     <= 0.55 * mgr_b.prefetch_bytes)
 
+    def test_pool_bytes_at_equal_residents_from_real_allocations(self):
+        # the capacity claim on what is allocated, not on shapes: the
+        # int8 pools with their float32 scale pools, against bfloat16
+        # pools of the same slots and pages (the table left out on
+        # both sides), at the head_dim of the test above
+        def pool_bytes(**over):
+            cfg, _ = _setup(d_model=64, n_heads=1, **over)
+            cache = init_paged_cache(cfg, 2, 4, 8, pool_pages=9)
+            return sum(int(arr.nbytes) for name, pools in cache.items()
+                       if name != "table" for arr in pools)
+
+        q = pool_bytes(kv_cache_dtype="int8")
+        b = pool_bytes(dtype="bfloat16")
+        assert 0.5 < q / b <= 0.55, (q, b)
+
 
 class TestRefusalsAndProbe:
     @pytest.mark.parametrize("kv_dtype", ["int8", "fp8"])

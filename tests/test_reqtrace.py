@@ -290,10 +290,11 @@ class TestChaosAttribution:
         finally:
             chaoslib.reset()
 
-    def test_slow_host_transfer_lands_in_prefetch_wait(self, setup):
-        # the tiered path: a seeded host_transfer delay must widen the
-        # prefetch_wait segment it sits inside (the residency window
-        # discipline of test_residency_serving, per-request form)
+    @staticmethod
+    def _tiered_leg(delay_s):
+        """Five requests through a tier that holds two of them, every
+        host->HBM pull eating ``delay_s`` (0: no fault): ``(eng, mgr,
+        ids, injections at host_transfer, the tracer)``."""
         from hpc_patterns_tpu.memory import (
             ColdAfterNPolicy,
             ResidencyManager,
@@ -304,10 +305,10 @@ class TestChaosAttribution:
                                    "n_heads": 2})
         params = init_params(jax.random.PRNGKey(0), cfg)
         pps = ContinuousBatcher.pages_needed(8, 24, 8)
-        delay_s = 0.06
         reqtrace.configure(enabled=True)
-        chaoslib.configure(
-            f"slow_host_transfer:delay_ms={int(delay_s * 1e3)}")
+        if delay_s:
+            chaoslib.configure(
+                f"slow_host_transfer:delay_ms={int(delay_s * 1e3)}")
         try:
             mgr = ResidencyManager(host_blocks=5 * pps,
                                    policy=ColdAfterNPolicy(2))
@@ -319,22 +320,60 @@ class TestChaosAttribution:
             ids = [eng.submit(rng.randint(0, cfg.vocab, size=8)
                               .astype(np.int32), 24) for _ in range(5)]
             eng.run()
-            assert mgr.swap_outs > 0
             fired = [e for e in chaoslib.injections()
                      if e["site"] == "host_transfer"]
-            assert fired
-            rtr = reqtrace.active()
-            waits = [t1 - t0 for sid in ids
-                     for k, t0, t1, _ in rtr.segments(sid)
-                     if k == "prefetch_wait" and t1 is not None]
-            assert waits and max(waits) >= delay_s
-            swapped = [sid for sid in ids
-                       if "swapped_out" in _kinds(rtr, sid)]
-            assert swapped
-            for sid in ids:
-                assert _coverage(rtr, eng.stats, sid) >= 0.999
+            return eng, mgr, ids, fired, reqtrace.active()
         finally:
             chaoslib.reset()
+
+    def test_slow_host_transfer_lands_in_prefetch_wait(self, setup):
+        # the tiered path: a seeded host_transfer delay must widen the
+        # prefetch_wait segment it sits inside (the residency window
+        # discipline of test_residency_serving, per-request form)
+        delay_s = 0.06
+        eng, mgr, ids, fired, rtr = self._tiered_leg(delay_s)
+        assert mgr.swap_outs > 0
+        assert fired
+        waits = [t1 - t0 for sid in ids
+                 for k, t0, t1, _ in rtr.segments(sid)
+                 if k == "prefetch_wait" and t1 is not None]
+        assert waits and max(waits) >= delay_s
+        swapped = [sid for sid in ids
+                   if "swapped_out" in _kinds(rtr, sid)]
+        assert swapped
+        for sid in ids:
+            assert _coverage(rtr, eng.stats, sid) >= 0.999
+
+    def test_slow_host_transfer_breaches_its_own_budget_line_only(
+            self, setup):
+        # the segment budget end to end (harness/budget.py): the same
+        # seeded stream judged against a budget whose prefetch_wait
+        # line (0.02 x 80 ms x 23 tokens = 37 ms) is under ONE injected
+        # 60 ms pull and whose every other line is most of a generous
+        # target: the breach set is that line and no other, and the
+        # inter-token digest gives the stall a share
+        from hpc_patterns_tpu.harness import budget as budgetlib
+        from hpc_patterns_tpu.harness import slo
+
+        self._tiered_leg(0)  # compiles outside the judged leg
+        reqtrace.reset()
+        eng, mgr, ids, fired, rtr = self._tiered_leg(0.06)
+        assert mgr.swap_outs > 0 and len(fired) >= mgr.swap_outs
+        snap = rtr.snapshot(eng.stats)
+        budget = budgetlib.SLOBudget(
+            ttft_shares={"queued": 0.9, "admit_wait": 0.9,
+                         "untracked": 0.5},
+            tpot_shares={"prefetch_wait": 0.02, "swapped_out": 0.9,
+                         "preempted": 0.9, "migrating": 0.9,
+                         "untracked": 0.5})
+        targets = {0: slo.SLOTarget(ttft_s=5.0, tpot_s=0.08)}
+        breaches = budgetlib.evaluate(snap, targets, budget)
+        assert budgetlib.breached_segments(breaches) \
+            == {"prefetch_wait"}
+        assert len(breaches) == 1
+        dig = explainlib.digest([snap])
+        assert 0.0 < dig["tpot_p99_stall_share"] <= 1.0
+        assert dig["coverage_frac"] >= 0.95
 
 
 class TestHistoryTransport:

@@ -363,6 +363,25 @@ class TestBucketedAdmission:
         assert ContinuousBatcher.pages_needed(1, 1, 8, padded_len=16) == 2
         assert ContinuousBatcher.pages_needed(9, 8, 8, padded_len=16) == 3
 
+    def test_from_fitted_applies_the_ladder_and_an_explicit_one_wins(self):
+        # the fitted ladder becomes prompt_buckets, clamped to this
+        # model's max_seq (64); the caller's own ladder outranks it
+        from hpc_patterns_tpu.harness import autofit
+
+        cfg, params = _setup()
+        fitted = autofit.fit([
+            {"kind": "serve_admit", "seq_id": i, "slot": 0,
+             "prompt_len": t, "padded_len": t, "priority": 0}
+            for i, t in enumerate([16] * 4 + [40] * 12 + [100] * 4)])
+        kw = dict(slots=1, pool_pages=8, pages_per_seq=8, page_size=8)
+        eng = ContinuousBatcher.from_fitted(params, cfg, fitted, **kw)
+        assert eng.prompt_buckets == autofit.ladder_from(fitted,
+                                                         max_seq=64)
+        assert 40 in eng.prompt_buckets and max(eng.prompt_buckets) == 64
+        eng = ContinuousBatcher.from_fitted(params, cfg, fitted,
+                                            prompt_buckets=(8, 32), **kw)
+        assert eng.prompt_buckets == (8, 32)
+
 
 class TestSampledServing:
     def test_sampled_token_exact_vs_standalone(self):
@@ -524,6 +543,11 @@ class TestPreemptionAndResume:
         resumed = [e for e in events
                    if e["kind"] == "serve_admit" and e["resumed"]]
         assert [e["seq_id"] for e in resumed] == [a]
+        # the resume's prompt is the original plus what was emitted,
+        # and it pads to a rung: a resume never leaves the ladder (so
+        # never compiles a prefill of its own length)
+        assert resumed[0]["prompt_len"] > len(pA)
+        assert resumed[0]["padded_len"] in eng.prompt_buckets
 
     def test_preempted_and_resumed_sampled_key_stream_exact(self):
         # the sharper half of the oracle: the victim's PER-ROW KEY

@@ -73,7 +73,13 @@ class TestDisaggregationOracle:
         ids = [plane.submit(p, m) for p, m in reqs]
         got = plane.run()
         assert sorted(got) == sorted(ids)
-        assert plane.migrations >= 1
+        assert plane.migrations >= len(reqs)
+        # unplaced replicas: every bundle counted as a local handoff,
+        # none under the DMA tier, and no DMA overlap number claimed
+        assert dict(plane.migration_transports) \
+            == {"local": plane.migrations}
+        assert plane.last_dma_migration_overlap_frac is None
+        assert plane.migration_bytes_per_round > 0
         for rid, (p, m) in zip(ids, reqs):
             np.testing.assert_array_equal(
                 got[rid], _standalone(params, cfg, p, m),
