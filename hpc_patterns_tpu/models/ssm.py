@@ -14,8 +14,9 @@ A head uses its group's ``B`` and ``C``. Two forms of the one recurrence:
   short scan). Under bucket padding ``dt`` is zero past ``last_pos``, so
   the state it returns is the one at the prompt's true last position, and
   the convolution tail is gathered there;
-- :func:`mamba_step`: one token against the carried state, written back
-  only where a row is active.
+- :func:`mamba_step`: one token against the carried state. The pass over
+  ``S`` is the Pallas kernel ``ssm_step`` (ops/ssm_step.py): it visits the
+  rows that are active, in place, and no others.
 
 The state ``S`` is held in float32 and every decay is computed in
 float32; the matmul operands are the compute dtype.
@@ -28,6 +29,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from hpc_patterns_tpu.models.transformer import matmul_weight
+from hpc_patterns_tpu.ops.ssm_step import ssm_step
 
 
 def ssm_dims(cfg) -> dict:
@@ -185,11 +187,11 @@ def mamba_prefill(h, lp, cfg, last_pos=None):
 def mamba_step(h, lp, cfg, state, active=None):
     """One token: h (b, D) normed input against ``state`` = (conv tail,
     S) -> (out (b, D), new state). Where ``active`` (b,) is false the
-    row's state is handed back as it came."""
+    row's state is handed back as it came (its ``S`` is not even read) and
+    its ``out`` means nothing."""
     tail, S = state
     dt_c = h.dtype
     f32 = jnp.float32
-    hpg = cfg.ssm_heads // cfg.ssm_groups
     z, xbc, dt = _project(h, lp, cfg)
     with jax.named_scope("conv"):
         window = jnp.concatenate([tail, xbc[:, None].astype(tail.dtype)], 1)
@@ -200,18 +202,10 @@ def mamba_step(h, lp, cfg, state, active=None):
     with jax.named_scope("step"):
         x, B, C = _split_xbc(act, cfg)                       # float32
         dt, A = _step_sizes(dt, lp)
-        Bh = jnp.repeat(B, hpg, axis=1)                      # (b, H, N)
-        Ch = jnp.repeat(C, hpg, axis=1)
-        S_new = (jnp.exp(dt * A)[..., None, None] * S.astype(f32)
-                 + (dt[..., None] * x)[..., None] * Bh[:, :, None, :])
-        y = (jnp.sum(S_new * Ch[:, :, None, :], axis=-1)
-             + lp["D"].astype(f32)[:, None] * x)
-        # inside ``step``: the compiler fuses the update, the read-out
-        # and this select into one pass over S and names it by its root
-        with jax.named_scope("state_write"):
-            S_new = S_new.astype(S.dtype)
-            if active is not None:
-                S_new = jnp.where(active[:, None, None, None], S_new, S)
+        y, S_new = ssm_step(S, x, dt, A, B, C, active)
+        y = y + lp["D"].astype(f32)[:, None] * x
+        if active is not None:
+            with jax.named_scope("state_write"):
                 new_tail = jnp.where(active[:, None, None], new_tail, tail)
     y = _gated_norm(y.reshape(y.shape[0], -1).astype(dt_c), z, lp, cfg)
     out = jnp.dot(y, matmul_weight(lp, "out_proj", dt_c))

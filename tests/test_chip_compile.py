@@ -16,6 +16,7 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from hpc_patterns_tpu.ops.grouped_matmul import grouped_matmul
+from hpc_patterns_tpu.ops.ssm_step import ssm_step
 from hpc_patterns_tpu.parallel.moe import relu2
 
 
@@ -78,3 +79,32 @@ def test_the_kernel_carries_its_callers_scope(one_chip, no_compile_cache):
     assert calls and all(
         re.search(r'op_name="[^"]*moe/experts/[^"]*grouped_matmul', line)
         and re.search(r"%grouped_matmul[.\d]* = ", line) for line in calls)
+
+
+# nemotron3-super-ep4: 64 slots of 128 heads x 64 x 128 float32, 8 groups
+@pytest.mark.parametrize("active", ["given", "none"])
+def test_ssm_step_compiles_in_place_under_its_callers_scope(
+        one_chip, no_compile_cache, active):
+    """``ssm_step_decode_roofline`` reads device time under ``ssm/step``:
+    the compiled call must say it ran there, and the donated state must
+    come back aliased, with no copy or select of it beside the kernel."""
+    def layer(S, x, dt, A, B, C, a=None):
+        with jax.named_scope("ssm"), jax.named_scope("step"):
+            return ssm_step(S, x, dt, A, B, C, a, interpret=False)
+
+    shape = lambda *s, dt=jnp.float32: jax.ShapeDtypeStruct(
+        s, dt, sharding=one_chip)
+    args = [shape(64, 128, 64, 128), shape(64, 128, 64), shape(64, 128),
+            shape(128), shape(64, 8, 128), shape(64, 8, 128)]
+    if active == "given":
+        args.append(shape(64, dt=jnp.bool_))
+    compiled = jax.jit(layer, donate_argnums=(0,)).lower(*args).compile()
+    text = compiled.as_text()
+    calls = [line for line in text.splitlines() if "tpu_custom_call" in line]
+    assert len(calls) == 1
+    assert re.search(r'op_name="[^"]*ssm/step/[^"]*ssm_step', calls[0])
+    assert re.search(r"%ssm_step[.\d]* = ", calls[0])
+    state = 64 * 128 * 64 * 128 * 4
+    assert compiled.memory_analysis().alias_size_in_bytes == state
+    whole = r"= f32\[64,128,64,128\]\S* (copy|select|fusion)\("
+    assert not [line for line in text.splitlines() if re.search(whole, line)]

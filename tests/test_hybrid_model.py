@@ -582,7 +582,8 @@ def lowered(params):
 COMMON = ["ssm/conv", "moe/route", "moe/latent", "moe/experts", "moe/shared",
           "attn", "head", "kv_write", "embed"]
 SCOPES = {"prefill": COMMON + ["ssm/scan", "ssm/state_write"],
-          "chunk": COMMON + ["ssm/step", "ssm/step/state_write", "sample"]}
+          "chunk": COMMON + ["ssm/step", "ssm/step/state_write",
+                             "ssm/step/jit(_call)", "sample"]}
 
 
 @pytest.mark.parametrize("program,path", [
@@ -606,3 +607,19 @@ def test_the_grouped_products_are_the_repos_kernel(lowered, program):
     assert any(loc.endswith("moe/experts/jit(_call)") for loc in locs)
     assert any(loc.startswith("grouped_matmul/") for loc in locs)
     assert "ragged_dot" not in lowered[program]
+
+
+def test_the_pass_over_the_state_is_the_repos_kernel(lowered):
+    """A decode step's pass over S is the jitted wrapper of the call named
+    ``ssm_step`` under ``ssm/step`` (the scope ``ssm_step_decode_roofline``
+    reads), and nothing in the chunk selects over a whole state: idle rows
+    keep theirs because the kernel never visits them."""
+    chunk = lowered["chunk"]
+    locs = set(re.findall(r'loc\("([^"]*)"', chunk))
+    assert any(loc.endswith("ssm/step/jit(_call)") for loc in locs)
+    assert any(loc.startswith("ssm_step/") for loc in locs)
+    state = "x".join(map(str, (2, CFG.ssm_heads, CFG.ssm_head_dim,
+                               CFG.ssm_state)))
+    assert f"tensor<{state}xf32>" in chunk      # the state is there
+    assert not [line for line in chunk.splitlines()
+                if "stablehlo.select" in line and f"<{state}x" in line]
