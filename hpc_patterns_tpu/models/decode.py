@@ -52,15 +52,22 @@ from hpc_patterns_tpu.harness import trace as tracelib
 from hpc_patterns_tpu.models.sharding_util import mesh_axis_size, resolve_spec
 from jax import shard_map
 from hpc_patterns_tpu.models.transformer import (
+    LAYER_KINDS,
     TransformerConfig,
     _rmsnorm,
     apply_rope,
-    attn_out,
+    attn_proj,
+    gated_mlp,
+    head_logits,
+    attn_norm,
     layer_params,
     matmul_weight,
     moe_mixer,
     project_qkv,
+    scaled,
     scoped,
+    ssm_branch,
+    ssm_branch_step,
     ssm_mixer,
     ssm_mixer_step,
 )
@@ -221,24 +228,25 @@ def init_cache(cfg: TransformerConfig, batch: int, max_len: int):
     return cache
 
 
-#: cache entries that are per-ROW state of a patterned model's "M"
-#: layers (one row a sequence, beside the K/V of its attention layers)
+#: cache entries that are per-ROW state of a patterned model's "M" and
+#: "H" layers (one row a sequence, beside the K/V of its attention
+#: layers, which for "H" are the same layers)
 STATE_KEYS = ("conv", "ssm")
 
 
 def init_layer_state(cfg: TransformerConfig, batch: int) -> dict:
     """What a patterned model's cache holds beside K/V: ``conv`` / ``ssm``,
-    one (batch, ...) array an "M" layer (the convolution's tail and the
-    recurrent state S, models/ssm.py), and ``moe_stats``, the route's
-    running sums (parallel/moe.ROUTE_STATS; row 0 prefills, row 1 decode
-    steps). Empty for the default pattern."""
+    one (batch, ...) array a layer that holds state (the convolution's
+    tail and the recurrent state S, models/ssm.py), and ``moe_stats``,
+    the route's running sums (parallel/moe.ROUTE_STATS; row 0 prefills,
+    row 1 decode steps). Empty for the default pattern."""
     out = {}
-    n_m = cfg.layer_pattern.count("M")
-    if n_m:
+    if cfg.n_state_layers:
         from hpc_patterns_tpu.models import ssm
 
         out.update(_state_entries(
-            [ssm.init_state(cfg, batch) for _ in range(n_m)]))
+            [ssm.init_state(cfg, batch)
+             for _ in range(cfg.n_state_layers)]))
     if "E" in cfg.layer_pattern:
         from hpc_patterns_tpu.parallel.moe import ROUTE_STATS
 
@@ -247,7 +255,7 @@ def init_layer_state(cfg: TransformerConfig, batch: int) -> dict:
 
 
 def _state_entries(pairs) -> dict:
-    """The "M" layers' (conv tail, S) pairs, in layer order, as the
+    """The state layers' (conv tail, S) pairs, in layer order, as the
     cache's ``STATE_KEYS`` entries; none gives none."""
     return dict(zip(STATE_KEYS, map(tuple, zip(*pairs)))) if pairs else {}
 
@@ -316,13 +324,15 @@ def prefill(params, prompt, cfg: TransformerConfig, max_len: int,
         )
     dt = jnp.dtype(cfg.dtype)
     with jax.named_scope("embed"):
-        x = params["embed"].astype(dt)[prompt]
+        x = scaled(params["embed"].astype(dt)[prompt],
+                   cfg.embedding_multiplier)
         if cfg.pos_embed == "learned":
             x = x + params["pos_embed"].astype(dt)[:T]
 
     @scoped("attn")
-    def attend(h, lp):
-        hn = _rmsnorm(h, lp["ln1_scale"], cfg.norm_eps)
+    def attend(hn, lp):
+        """The normed input's attention before its output projection:
+        (o (B, T, H, Dh), K, V)."""
         q, k, v = project_qkv(hn, lp, cfg)
         if cfg.pos_embed == "rope":
             # the cache stores POST-rope K: a key's rotation depends
@@ -351,30 +361,31 @@ def prefill(params, prompt, cfg: TransformerConfig, max_len: int,
                 o = flash_attention(q, k, v, causal=True)
         else:
             o = full_attention(q, k, v, causal=True)
-        o = jnp.dot(o.reshape(B, T, cfg.d_model),
-                    matmul_weight(lp, "wo", dt))
-        return h + o.astype(dt), k, v
+        return o, k, v
+
+    @scoped("kv_write")
+    def capture(k, v):
+        # in kernel layout (B, Hkv, T, D), padded to the static cache
+        # length — one transpose at prefill, zero per decode step
+        kc = jnp.einsum("bthd->bhtd", k)
+        vc = jnp.einsum("bthd->bhtd", v)
+        pad = [(0, 0), (0, 0), (0, max_len - T), (0, 0)]
+        return jnp.pad(kc, pad).astype(dt), jnp.pad(vc, pad).astype(dt)
 
     def body(h, lp, mlp=True):
-        h, k, v = attend(h, lp)
+        o, k, v = attend(attn_norm(h, lp, cfg), lp)
+        h = h + attn_proj(o, lp, cfg, dt)
         if mlp:
             h = _mlp(h, lp, cfg)
-        # capture in kernel layout (B, Hkv, T, D), padded to the static
-        # cache length — one transpose at prefill, zero per decode step
-        with jax.named_scope("kv_write"):
-            kc = jnp.einsum("bthd->bhtd", k)
-            vc = jnp.einsum("bthd->bhtd", v)
-            pad = [(0, 0), (0, 0), (0, max_len - T), (0, 0)]
-            return h, (jnp.pad(kc, pad).astype(dt),
-                       jnp.pad(vc, pad).astype(dt))
+        return h, capture(k, v)
 
     rows_last = lambda: jnp.broadcast_to(
         jnp.asarray(last_pos, jnp.int32), (B,))
     extra = {}
     if cfg.layer_pattern:
         last = None if last_pos is None else rows_last()
-        # one mixer a layer, by its type: "M" layers hand back the state
-        # at the prompt's TRUE last position, "E" layers route the true
+        # by the layer's kind: "M" and "H" layers hand back the state at
+        # the prompt's TRUE last position, "E" layers route the true
         # tokens alone (a bucket's padding picks no expert)
         valid = (None if last is None else
                  jnp.arange(T, dtype=jnp.int32)[None, :] <= last[:, None])
@@ -382,14 +393,22 @@ def prefill(params, prompt, cfg: TransformerConfig, max_len: int,
         for kind, lp in zip(cfg.layer_pattern, params["layers"]):
             if kind == "*":
                 x, (kc, vc) = body(x, lp, mlp=False)
-                ks.append(kc)
-                vs.append(vc)
             elif kind == "M":
                 x, st = ssm_mixer(x, lp, cfg, last)
-                states.append(st)
-            else:
+            elif kind == "E":
                 x, st = moe_mixer(x, lp, cfg, valid)
                 stats.append(st)
+            else:   # "H": attention and the recurrence off one norm
+                hn = attn_norm(x, lp, cfg)
+                o, k, v = attend(hn, lp)
+                m, st = ssm_branch(hn, lp, cfg, last)
+                x = gated_mlp(x + attn_proj(o, lp, cfg, dt) + m, lp, cfg)
+                kc, vc = capture(k, v)
+            if LAYER_KINDS[kind].kv:
+                ks.append(kc)
+                vs.append(vc)
+            if LAYER_KINDS[kind].state:
+                states.append(st)
         extra = _state_entries(states)
         if stats:
             extra["moe_stats"] = jnp.stack(
@@ -403,7 +422,7 @@ def prefill(params, prompt, cfg: TransformerConfig, max_len: int,
         else:
             x_last = jnp.take_along_axis(x, rows_last()[:, None, None],
                                          axis=1)[:, 0]
-        logits = jnp.dot(x_last, matmul_weight(params, "lm_head", dt))
+        logits = head_logits(x_last, params, cfg)
     L = cfg.n_attn_layers
     if _kv_quantized(cfg):
         kvd = cfg.kv_cache_dtype
@@ -424,7 +443,7 @@ def prefill(params, prompt, cfg: TransformerConfig, max_len: int,
         # sharded decode step's shard_map consumes exactly this layout)
         cache = _tp_pin_cache(cache, mesh, cfg)
     cache.update(extra)
-    return logits.astype(jnp.float32), cache
+    return logits, cache
 
 
 def _token_step(params, pos, tokens, cfg: TransformerConfig,
@@ -444,12 +463,14 @@ def _token_step(params, pos, tokens, cfg: TransformerConfig,
     that block, "*" its attention half alone, "M" one step of the
     recurrence against ``row_states`` (the (conv tail, S) pairs, written
     back only where ``active``), "E" the expert layer (idle rows pick
-    nothing). Returns (logits, the attention layers' new states, the
-    other layers' cache entries)."""
+    nothing), "H" the attention and one step of the recurrence off one
+    norm, summed, then the gated MLP. Returns (logits, the K/V layers'
+    new states, the other cache entries)."""
     dt = jnp.dtype(cfg.dtype)
     B = tokens.shape[0]
     with jax.named_scope("embed"):
-        x = params["embed"].astype(dt)[tokens]  # (B, D)
+        x = scaled(params["embed"].astype(dt)[tokens],   # (B, D)
+                   cfg.embedding_multiplier)
         if cfg.pos_embed == "learned":
             pe = params["pos_embed"].astype(dt)
             # scalar pos: one shared row (DUS slice); ragged (B,) pos:
@@ -469,8 +490,8 @@ def _token_step(params, pos, tokens, cfg: TransformerConfig,
             x, st = moe_mixer(x, lp, cfg, active)
             stats.append(st)
             continue
+        hn = attn_norm(x, lp, cfg)
         with jax.named_scope("attn"):
-            hn = _rmsnorm(x, lp["ln1_scale"], cfg.norm_eps)
             q, k_new, v_new = project_qkv(hn, lp, cfg)  # (B, H/Hkv, Dh)
             if cfg.pos_embed == "rope":
                 q = apply_rope(q, pos, cfg)
@@ -484,28 +505,31 @@ def _token_step(params, pos, tokens, cfg: TransformerConfig,
         # ``attn``), so the two stay disjoint
         o, st = attend_update(q, k_new, v_new,
                               layer_states[len(new_states)])
-        with jax.named_scope("attn"):
-            o = jnp.dot(o.reshape(B, cfg.d_model).astype(dt),
-                        matmul_weight(lp, "wo", dt))
-            x = x + o
-        if kind == "B":
-            x = _mlp(x, lp, cfg)
+        x = x + attn_proj(o.reshape(B, cfg.n_heads, cfg.head_dim), lp,
+                          cfg, dt)
         new_states.append(st)
+        holds = LAYER_KINDS[kind]
+        if holds.state:   # "H": the recurrence, off the same normed input
+            m, rs = ssm_branch_step(hn, lp, cfg, row_states[len(new_rows)],
+                                    active)
+            new_rows.append(rs)
+            x = x + m
+        if holds.mlp:
+            x = (gated_mlp if holds.mlp == "gated" else _mlp)(x, lp, cfg)
     with jax.named_scope("head"):
         x = _rmsnorm(x, params["ln_f_scale"], cfg.norm_eps)
-        logits = jnp.dot(x, matmul_weight(params, "lm_head", dt))
-    return (logits.astype(jnp.float32), new_states,
-            _step_extra(new_rows, stats))
+        logits = head_logits(x, params, cfg)
+    return logits, new_states, _step_extra(new_rows, stats)
 
 
 def _row_states(cache):
-    """The "M" layers' (conv tail, S) pairs of a cache, in layer order."""
+    """The state layers' (conv tail, S) pairs of a cache, in layer order."""
     return list(zip(*(cache.get(k, ()) for k in STATE_KEYS)))
 
 
 def _step_extra(new_rows, stats) -> dict:
     """What one token step adds to a patterned model's cache entries:
-    the "M" layers' new state and the route's sums of this step."""
+    the state layers' new rows and the route's sums of this step."""
     extra = _state_entries(new_rows)
     if stats:
         extra["moe_stats"] = sum(stats)
@@ -703,7 +727,7 @@ def extend_step(params, cache, pos, tokens, cfg: TransformerConfig):
         o = jnp.einsum("bkgcs,bksd->bckgd", p,
                        v_cache.astype(jnp.float32),
                        precision=lax.Precision.HIGHEST)
-        o = jnp.dot(o.reshape(B, c, cfg.d_model).astype(dt),
+        o = jnp.dot(o.reshape(B, c, cfg.attn_width).astype(dt),
                     matmul_weight(lp, "wo", dt))
         h = _mlp(h + o, lp, cfg)
         return h, (k_cache, v_cache)
@@ -1078,7 +1102,7 @@ def paged_tail_prefill(params, tail, cfg: TransformerConfig, cache,
         p = jax.nn.softmax(s, axis=-1)
         o = jnp.einsum("bhtd->bthd", _grouped_pv(p, v_ctx)).astype(
             q.dtype)
-        o = jnp.dot(o.reshape(B, c, cfg.d_model),
+        o = jnp.dot(o.reshape(B, c, cfg.attn_width),
                     matmul_weight(lp, "wo", dt))
         h = _mlp(h + o.astype(dt), lp, cfg)
         kc = jnp.einsum("bthd->bhtd", k)
@@ -1512,7 +1536,7 @@ def paged_extend_step(params, cache, pos, tokens, cfg: TransformerConfig):
         p = jax.nn.softmax(s, axis=-1)
         o = jnp.einsum("bkgcs,bksd->bckgd", p, vd,
                        precision=lax.Precision.HIGHEST)
-        o = jnp.dot(o.reshape(B, c, cfg.d_model).astype(dt),
+        o = jnp.dot(o.reshape(B, c, cfg.attn_width).astype(dt),
                     matmul_weight(lp, "wo", dt))
         h = _mlp(h + o, lp, cfg)
         return h, (k_pool, v_pool, ks_pool, vs_pool)

@@ -175,7 +175,7 @@ def tp_permute_wqkv(wqkv, cfg: TransformerConfig, tp: int):
     sections). Pure column gather — applied once per step on the way
     into the pipeline shard_map; the public param layout stays
     standard."""
-    D = cfg.d_model
+    D = cfg.attn_width
     S = cfg.kv_heads * cfg.head_dim
     q, k, v = jnp.split(wqkv, [D, D + S], axis=-1)
     qs = jnp.split(q, tp, axis=-1)
@@ -192,7 +192,7 @@ def tp_unpermute_wqkv(wqkv_p, cfg: TransformerConfig, tp: int):
     """Inverse of :func:`tp_permute_wqkv` (applied to the wqkv gradient
     on the way out, so optimizer/checkpoint/oracle all see the standard
     packed layout)."""
-    Dl = cfg.d_model // tp
+    Dl = cfg.attn_width // tp
     Sl = cfg.kv_heads * cfg.head_dim // tp
     qs, ks, vs = [], [], []
     for blk in jnp.split(wqkv_p, tp, axis=-1):
@@ -212,7 +212,7 @@ def _tp_layer(x, lp, cfg: TransformerConfig, axis_tp: str, tp: int):
     B, T, D = x.shape
     dt = x.dtype
     Hl, Hkvl, Dh = cfg.n_heads // tp, cfg.kv_heads // tp, cfg.head_dim
-    Dl = D // tp
+    Dl = cfg.attn_width // tp
 
     a = _tp_f(x, axis_tp)
     h = _rmsnorm(a, lp["ln1_scale"])

@@ -886,6 +886,10 @@ class EngineCore:
         #: bytes of per-row recurrent state held beside the pools
         self.state_bytes = sum(
             int(a.nbytes) for k in STATE_KEYS for a in self.cache.get(k, ()))
+        #: bytes of K/V (scales included) one token holds, all layers
+        self.kv_bytes_per_token = sum(
+            int(a.nbytes) for k in ("k", "v", "k_scale", "v_scale")
+            for a in self.cache.get(k, ())) // ((pool_pages + 1) * page_size)
         #: what ``_count_route`` last saw of the expert route's sums
         self._route_seen = None
         if draft_params is not None:
@@ -1447,6 +1451,10 @@ class EngineCore:
                           overlapped=overlapped)
         if self.state_bytes:
             span_attrs["state_bytes"] = self.state_bytes // self.slots
+        # whole pages from the matched prefix on, every layer's K and V
+        span_attrs["kv_bytes"] = lambda: (
+            -(-(padded - M) // self.page_size) * self.page_size
+            * self.kv_bytes_per_token)
         if m:
             # tail-only prefill: positions [M, padded) computed against
             # the mapped prefix pages; the matched span's compute AND
@@ -1554,6 +1562,8 @@ class EngineCore:
             mx.gauge("serve.free_pages").set(len(self.free_pages))
             if self.state_bytes:
                 mx.gauge("engine.state_bytes").set(self.state_bytes)
+            mx.gauge("engine.kv_bytes_per_token").set(
+                self.kv_bytes_per_token)
             mx.counter("serve.admitted").inc()
             if overlapped:
                 mx.counter("serve.admit_overlapped").inc()
@@ -1863,7 +1873,11 @@ class EngineCore:
         pos_start = np.array(self.pos)
         parts = [i for i, s in enumerate(self._slots) if s.active]
         with metricslib.span("serve.decode_dispatch", chunk=self.chunk,
-                             rows=len(parts), round=self._round), \
+                             rows=len(parts), round=self._round,
+                             # the live rows' positions, summed: what the
+                             # chunk's first step attends over
+                             ctx_tokens=lambda: pos_start[parts].sum()
+                             ), \
                 tracelib.compile_watch("serving._chunk_step",
                                        _chunk_step, chunk=self.chunk):
             (self.cache, self.pos, self.limit, self.tokens, self.keys,

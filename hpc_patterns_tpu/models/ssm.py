@@ -1,4 +1,8 @@
-"""The Mamba-2 mixer of a patterned model's ``M`` layers.
+"""The Mamba-2 mixer of a patterned model's ``M`` layers and of the
+Mamba half of its ``H`` layers (which brings the config's multipliers:
+``h`` times ``ssm_in_multiplier``, the in-projection's five segments
+``[z | x | B | C | dt]`` times ``ssm_multipliers``, ``out`` times
+``ssm_out_multiplier``).
 
     [z | xBC | dt] = h W_in
     xBC  <- silu(causal depthwise conv1d_K(xBC) + b_conv);  xBC = [x | B | C]
@@ -28,7 +32,9 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from hpc_patterns_tpu.models.transformer import matmul_weight
+import numpy as np
+
+from hpc_patterns_tpu.models.transformer import matmul_weight, scaled
 from hpc_patterns_tpu.ops.ssm_step import ssm_step
 
 
@@ -53,7 +59,13 @@ def init_state(cfg, batch: int) -> tuple:
 
 def _project(h, lp, cfg):
     d = ssm_dims(cfg)
+    h = scaled(h, cfg.ssm_in_multiplier)
     zxbcdt = jnp.dot(h, matmul_weight(lp, "in_proj", h.dtype))
+    if set(cfg.ssm_multipliers) != {1.0}:   # one each of [z | x | B | C | dt]
+        zxbcdt = zxbcdt * jnp.asarray(np.repeat(
+            cfg.ssm_multipliers,
+            [d["d_inner"], d["d_inner"], d["bc"], d["bc"], cfg.ssm_heads]),
+            zxbcdt.dtype)
     z, xbc, dt = jnp.split(
         zxbcdt, [d["d_inner"], d["d_inner"] + d["conv_dim"]], axis=-1)
     return z, xbc, dt
@@ -181,7 +193,7 @@ def mamba_prefill(h, lp, cfg, last_pos=None):
         y = y + lp["D"].astype(jnp.float32)[:, None] * x.astype(jnp.float32)
     y = _gated_norm(y.reshape(b, T, -1), z, lp, cfg)
     out = jnp.dot(y, matmul_weight(lp, "out_proj", dt_c))
-    return out, (tail.astype(dt_c), S)
+    return scaled(out, cfg.ssm_out_multiplier), (tail.astype(dt_c), S)
 
 
 def mamba_step(h, lp, cfg, state, active=None):
@@ -209,4 +221,4 @@ def mamba_step(h, lp, cfg, state, active=None):
                 new_tail = jnp.where(active[:, None, None], new_tail, tail)
     y = _gated_norm(y.reshape(y.shape[0], -1).astype(dt_c), z, lp, cfg)
     out = jnp.dot(y, matmul_weight(lp, "out_proj", dt_c))
-    return out, (new_tail, S_new)
+    return scaled(out, cfg.ssm_out_multiplier), (new_tail, S_new)

@@ -36,6 +36,7 @@ from __future__ import annotations
 import dataclasses
 import math
 from functools import partial, wraps
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -50,6 +51,29 @@ from hpc_patterns_tpu.parallel.ulysses import ulysses_attention
 
 ATTENTION_IMPLS = ("full", "flash", "ring", "ring_flash", "ulysses",
                    "ulysses_flash")
+
+
+class LayerKind(NamedTuple):
+    """What a layer of one kind holds: K/V (a pool of pages in the
+    cache), a row of recurrent state (convolution tail, S), and which
+    MLP closes it ("" none, "gelu" the default block's, "gated" the
+    SiLU-gated one)."""
+    kv: bool
+    state: bool
+    mlp: str
+
+
+#: THE table of layer kinds, by the pattern's character: "B" spells the
+#: default block (no pattern names it). Read by the config's counts and
+#: by every loop over a pattern (forward_hidden, decode.prefill,
+#: decode._token_step, decode.init_layer_state)
+LAYER_KINDS = {
+    "B": LayerKind(kv=True, state=False, mlp="gelu"),
+    "*": LayerKind(kv=True, state=False, mlp=""),
+    "M": LayerKind(kv=False, state=True, mlp=""),
+    "E": LayerKind(kv=False, state=False, mlp=""),
+    "H": LayerKind(kv=True, state=True, mlp="gated"),
+}
 
 
 @dataclasses.dataclass(frozen=True)
@@ -166,14 +190,36 @@ class TransformerConfig:
     kv_cache_dtype: str = "compute"
     # the layer pattern: "" = ``n_layers`` of the one block (attention +
     # MLP/MoE, the default and everything above). Otherwise one character
-    # a layer, each layer ONE mixer under a pre-norm residual: "*"
-    # attention alone (GQA, no MLP), "M" a Mamba-2 mixer
+    # a layer (LAYER_KINDS). Three are ONE mixer under a pre-norm
+    # residual: "*" attention alone (GQA, no MLP), "M" a Mamba-2 mixer
     # (models/ssm.py), "E" a LatentMoE layer on this chip's share of the
-    # experts (parallel/moe.latent_moe). A patterned model's
-    # ``params["layers"]`` is a tuple of per-layer dicts, its layer loop
-    # is unrolled, and it runs unsharded (mesh=None)
+    # experts (parallel/moe.latent_moe). "H" is the parallel hybrid
+    # block: attention AND the Mamba-2 mixer off one norm, summed into
+    # the residual, then a second norm and a SiLU-gated MLP of width
+    # ``d_ff``. A patterned model's ``params["layers"]`` is a tuple of
+    # per-layer dicts, its layer loop is unrolled, and it runs unsharded
+    # (mesh=None)
     layer_pattern: str = ""
     norm_eps: float = 1e-6
+    # the attention head size where heads x size is not d_model (0 =
+    # d_model // n_heads): wqkv is (d_model, (n_heads + 2 kv_heads) x
+    # size), wo (n_heads x size, d_model)
+    attn_head_dim: int = 0
+    # an all-"H" model's scalar multipliers, under their published names,
+    # applied at use on activations (1 = no operation is emitted): on the
+    # embedding; on the attention's input, its keys, its output; on the
+    # Mamba-2 mixer's input, on the five segments [z | x | B | C | dt] of
+    # its in-projection, on its output; on the MLP's gate and its output;
+    # on the logits
+    embedding_multiplier: float = 1.0
+    attention_in_multiplier: float = 1.0
+    key_multiplier: float = 1.0
+    attention_out_multiplier: float = 1.0
+    ssm_in_multiplier: float = 1.0
+    ssm_multipliers: tuple = (1.0, 1.0, 1.0, 1.0, 1.0)
+    ssm_out_multiplier: float = 1.0
+    mlp_multipliers: tuple = (1.0, 1.0)
+    lm_head_multiplier: float = 1.0
     # "M": heads x head_dim = d_inner; B and C are shared by the heads of
     # a group; the convolution's width; positions a chunk of the prefill's
     # chunked form (the recurrent state is held in float32)
@@ -230,7 +276,12 @@ class TransformerConfig:
     @property
     def n_attn_layers(self) -> int:
         """Layers that hold K/V: the cache has one pool for each."""
-        return sum(c in "B*" for c in self.pattern)
+        return sum(LAYER_KINDS[c].kv for c in self.pattern)
+
+    @property
+    def n_state_layers(self) -> int:
+        """Layers that hold a row of recurrent state a sequence."""
+        return sum(LAYER_KINDS[c].state for c in self.pattern)
 
     @property
     def experts_held(self) -> int:
@@ -238,9 +289,26 @@ class TransformerConfig:
 
     @property
     def head_dim(self) -> int:
+        if self.attn_head_dim:
+            return self.attn_head_dim
         if self.d_model % self.n_heads:
             raise ValueError(f"d_model {self.d_model} % n_heads {self.n_heads} != 0")
         return self.d_model // self.n_heads
+
+    @property
+    def attn_width(self) -> int:
+        """Width of the attention's output before ``wo``: d_model unless
+        the head size is explicit."""
+        return self.n_heads * self.head_dim
+
+    @property
+    def multipliers(self) -> tuple:
+        """The fourteen multiplier values, flat."""
+        return (self.embedding_multiplier, self.attention_in_multiplier,
+                self.key_multiplier, self.attention_out_multiplier,
+                self.ssm_in_multiplier, *self.ssm_multipliers,
+                self.ssm_out_multiplier, *self.mlp_multipliers,
+                self.lm_head_multiplier)
 
     def __post_init__(self):
         if self.pos_embed not in ("learned", "rope", "none"):
@@ -250,16 +318,17 @@ class TransformerConfig:
             )
         pat = self.layer_pattern
         if pat:
-            if len(pat) != self.n_layers or set(pat) - set("*ME"):
+            if len(pat) != self.n_layers or set(pat) - set("*MEH"):
                 raise ValueError(
-                    f"layer_pattern {pat!r}: one of '*ME' for each of "
+                    f"layer_pattern {pat!r}: one of '*MEH' for each of "
                     f"the {self.n_layers} layers")
-            if "M" in pat and not (
+            if self.n_state_layers and not (
                     self.ssm_heads > 0
                     and self.ssm_heads % self.ssm_groups == 0):
                 raise ValueError(
-                    "an 'M' layer needs ssm_heads > 0, a multiple of "
-                    f"ssm_groups (got {self.ssm_heads}, {self.ssm_groups})")
+                    "an 'M' or 'H' layer needs ssm_heads > 0, a multiple "
+                    f"of ssm_groups (got {self.ssm_heads}, "
+                    f"{self.ssm_groups})")
             if "E" in pat and not (
                     0 < self.moe_top_k <= self.moe_experts
                     and self.moe_held_start + self.experts_held
@@ -270,6 +339,16 @@ class TransformerConfig:
                     "an 'E' layer needs moe_experts >= moe_top_k > 0, a "
                     "held range inside the experts, and moe_latent, "
                     "moe_d_ff, moe_shared_d_ff > 0")
+        if (len(self.ssm_multipliers), len(self.mlp_multipliers)) != (5, 2):
+            raise ValueError(
+                "ssm_multipliers has five values ([z | x | B | C | dt]) "
+                "and mlp_multipliers two (gate, output)")
+        if set(self.multipliers) != {1.0} and set(pat) != {"H"}:
+            # the other kinds' and the default block's paths (the MLPs,
+            # extend/tail prefill, the pipeline stages) apply none
+            raise ValueError(
+                "multipliers other than 1 are read by 'H' layers: they "
+                f"need a layer_pattern of 'H' alone, not {pat!r}")
         if self.pos_embed == "rope" and self.head_dim % 2:
             raise ValueError("rope needs an even head_dim")
         if self.attention not in ATTENTION_IMPLS:
@@ -339,9 +418,9 @@ def init_params(key, cfg: TransformerConfig):
         "ln2_scale": jnp.ones((L, D), jnp.float32),
         # fused q + k + v projection; with GQA the kv widths shrink to
         # kv_heads * head_dim
-        "wqkv": initn((L, D, D + 2 * cfg.kv_heads * cfg.head_dim),
-                      D ** -0.5),
-        "wo": initn((L, D, D), (2 * D * L) ** -0.5),
+        "wqkv": initn((L, D, cfg.attn_width
+                       + 2 * cfg.kv_heads * cfg.head_dim), D ** -0.5),
+        "wo": initn((L, cfg.attn_width, D), (2 * D * L) ** -0.5),
     }
     pos = (
         {"pos_embed": initn((cfg.max_seq, D), 0.02)}
@@ -377,11 +456,12 @@ def _init_patterned(key, cfg: TransformerConfig):
         n = lambda shape, scale: jax.random.normal(
             next(k), shape, jnp.float32) * scale
         lp = {"ln1_scale": jnp.ones((D,), jnp.float32)}
-        if kind == "*":
-            lp["wqkv"] = n((D, D + 2 * cfg.kv_heads * cfg.head_dim),
-                           D ** -0.5)
-            lp["wo"] = n((D, D), (2 * D * L) ** -0.5)
-        elif kind == "M":
+        holds = LAYER_KINDS[kind]
+        if holds.kv:
+            lp["wqkv"] = n((D, cfg.attn_width
+                            + 2 * cfg.kv_heads * cfg.head_dim), D ** -0.5)
+            lp["wo"] = n((cfg.attn_width, D), (2 * D * L) ** -0.5)
+        if holds.state:
             d, H = ssm_dims(cfg), cfg.ssm_heads
             lp["in_proj"] = n((D, d["proj"]), D ** -0.5)
             lp["conv_w"] = n((cfg.ssm_conv, d["conv_dim"]),
@@ -397,7 +477,13 @@ def _init_patterned(key, cfg: TransformerConfig):
             lp["norm_scale"] = jnp.ones((d["d_inner"],), jnp.float32)
             lp["out_proj"] = n((d["d_inner"], D),
                                (2 * d["d_inner"] * L) ** -0.5)
-        else:   # "E"
+        if holds.mlp == "gated":
+            F = cfg.d_ff
+            lp["ln2_scale"] = jnp.ones((D,), jnp.float32)
+            lp["w_gate"] = n((D, F), D ** -0.5)
+            lp["w_up"] = n((D, F), D ** -0.5)
+            lp["w_down"] = n((F, D), (2 * F * L) ** -0.5)
+        if kind == "E":
             E, held = cfg.moe_experts, cfg.experts_held
             R, F, Fs = cfg.moe_latent, cfg.moe_d_ff, cfg.moe_shared_d_ff
             lp["router"] = n((D, E), D ** -0.5)
@@ -618,20 +704,28 @@ def apply_rope(x, positions, cfg: TransformerConfig):
     ).astype(x.dtype)
 
 
+def scaled(x, multiplier: float):
+    """``x`` times one of the config's multipliers, in ``x``'s dtype; a
+    multiplier of 1 emits nothing, so a model without them lowers to the
+    program it lowered to before they existed."""
+    return x if multiplier == 1.0 else x * jnp.asarray(multiplier, x.dtype)
+
+
 def project_qkv(h, lp, cfg: TransformerConfig):
     """Fused qkv projection + head split, GQA-narrow K/V (kv_heads, not
     yet expanded). THE qkv layout definition — shared by the training
     layer (_layer) and the decode path (models/decode.py) so the two can
     never disagree on the split or head order. ``h``: (..., d_model);
     returns q (..., n_heads, Dh), k/v (..., kv_heads, Dh)."""
-    *lead, D = h.shape
+    lead = h.shape[:-1]
     dt = h.dtype
     H, Hkv, Dh = cfg.n_heads, cfg.kv_heads, cfg.head_dim
+    h = scaled(h, cfg.attention_in_multiplier)
     qkv = jnp.dot(h, matmul_weight(lp, "wqkv", dt))  # column-parallel
-    q, k, v = jnp.split(qkv, [D, D + Hkv * Dh], axis=-1)
+    q, k, v = jnp.split(qkv, [H * Dh, (H + Hkv) * Dh], axis=-1)
     return (
         q.reshape(*lead, H, Dh),
-        k.reshape(*lead, Hkv, Dh),
+        scaled(k, cfg.key_multiplier).reshape(*lead, Hkv, Dh),
         v.reshape(*lead, Hkv, Dh),
     )
 
@@ -776,14 +870,19 @@ def _moe_block(h, lp, cfg: TransformerConfig, mesh, with_stats=False):
     return out
 
 
-@scoped("attn")
 def _qkv_block(x, lp, cfg: TransformerConfig, mesh):
-    """Pre-attention: norm + fused qkv projection + rope + the GQA
-    narrow-vs-expand decision. Split out so remat_policy="split" can
-    checkpoint it independently of the attention kernel."""
-    B, T, D = x.shape
+    """Pre-attention: norm + :func:`_qkv_heads`. Split out so
+    remat_policy="split" can checkpoint it independently of the
+    attention kernel."""
+    return _qkv_heads(attn_norm(x, lp, cfg), lp, cfg, mesh)
+
+
+@scoped("attn")
+def _qkv_heads(h, lp, cfg: TransformerConfig, mesh):
+    """The normed input's fused qkv projection + rope + the GQA
+    narrow-vs-expand decision."""
+    B, T, D = h.shape
     H = cfg.n_heads
-    h = _rmsnorm(x, lp["ln1_scale"], cfg.norm_eps)
     q, k, v = project_qkv(h, lp, cfg)
     if cfg.pos_embed == "rope":
         # global positions: the layer always sees the full sequence (the
@@ -818,7 +917,7 @@ def _post_attn(x, o, lp, cfg: TransformerConfig, mesh, act_spec):
     B, T, D = x.shape
     dt = x.dtype
     with jax.named_scope("attn"):
-        o = jnp.dot(o.reshape(B, T, D),
+        o = jnp.dot(o.reshape(B, T, cfg.attn_width),
                     matmul_weight(lp, "wo", dt))  # row-parallel
         x = x + o
         if mesh is not None:
@@ -892,12 +991,26 @@ def _post_block(x, o, lp, cfg: TransformerConfig, mesh, act_spec,
 
 
 @scoped("attn")
-def attn_out(x, o, lp):
-    """An attention-only layer's close: output projection + residual.
-    o (..., H, Dh) or (..., D)."""
+def attn_proj(o, lp, cfg: TransformerConfig, dt):
+    """The attention's output projection: o (..., H, Dh) -> (..., D)."""
+    o = jnp.dot(o.reshape(*o.shape[:-2], cfg.attn_width).astype(dt),
+                matmul_weight(lp, "wo", dt))
+    return scaled(o, cfg.attention_out_multiplier)
+
+
+@scoped("mlp")
+def gated_mlp(x, lp, cfg: TransformerConfig):
+    """An "H" layer's close, x (..., D): the SiLU-gated MLP under its
+    own pre-norm residual,
+    ``x + (silu(h W_gate) * (h W_up)) W_down``."""
     dt = x.dtype
-    o = jnp.dot(o.reshape(x.shape).astype(dt), matmul_weight(lp, "wo", dt))
-    return x + o
+    m_gate, m_out = cfg.mlp_multipliers
+    h = _rmsnorm(x, lp["ln2_scale"], cfg.norm_eps)
+    with jax.named_scope("gate_up"):
+        gate = scaled(jnp.dot(h, matmul_weight(lp, "w_gate", dt)), m_gate)
+        h = jax.nn.silu(gate) * jnp.dot(h, matmul_weight(lp, "w_up", dt))
+    with jax.named_scope("down"):
+        return x + scaled(jnp.dot(h, matmul_weight(lp, "w_down", dt)), m_out)
 
 
 @scoped("ssm")
@@ -922,6 +1035,32 @@ def ssm_mixer_step(x, lp, cfg: TransformerConfig, state, active=None):
     h = _rmsnorm(x, lp["ln1_scale"], cfg.norm_eps)
     out, state = ssm.mamba_step(h, lp, cfg, state, active)
     return x + out, state
+
+
+@scoped("attn")
+def attn_norm(x, lp, cfg: TransformerConfig):
+    """The pre-norm of a layer that has attention; an "H" layer's Mamba
+    half reads the same normed input."""
+    return _rmsnorm(x, lp["ln1_scale"], cfg.norm_eps)
+
+
+@scoped("ssm")
+def ssm_branch(h, lp, cfg: TransformerConfig, last_pos=None):
+    """An "H" layer's Mamba-2 half over a whole sequence, from the
+    layer's normed input h (B, T, D): (what it adds to the residual,
+    (conv tail, S) at ``last_pos``)."""
+    from hpc_patterns_tpu.models import ssm
+
+    return ssm.mamba_prefill(h, lp, cfg, last_pos)
+
+
+@scoped("ssm")
+def ssm_branch_step(h, lp, cfg: TransformerConfig, state, active=None):
+    """An "H" layer's Mamba-2 half on one token a row, from the layer's
+    normed input h (B, D), against the carried ``state``."""
+    from hpc_patterns_tpu.models import ssm
+
+    return ssm.mamba_step(h, lp, cfg, state, active)
 
 
 @scoped("moe")
@@ -1000,11 +1139,16 @@ def forward(params, tokens, cfg: TransformerConfig, mesh=None, *,
     (zeros for dense models)."""
     x, aux = forward_hidden(params, tokens, cfg, mesh)
     with jax.named_scope("head"):
-        logits = jnp.dot(x, matmul_weight(params, "lm_head", x.dtype))
-        logits = logits.astype(jnp.float32)
+        logits = head_logits(x, params, cfg)
     if return_aux:
         return logits, aux
     return logits
+
+
+def head_logits(x, params, cfg: TransformerConfig):
+    """float32 logits of final-norm hidden states x (..., D)."""
+    logits = jnp.dot(x, matmul_weight(params, "lm_head", x.dtype))
+    return scaled(logits.astype(jnp.float32), cfg.lm_head_multiplier)
 
 
 @scoped("embed")
@@ -1026,7 +1170,7 @@ def _embed_tokens(params, tokens, cfg: TransformerConfig, mesh, dt):
         emb = lax.with_sharding_constraint(
             emb, jax.sharding.NamedSharding(mesh, P())
         )
-    x = emb[tokens]
+    x = scaled(emb[tokens], cfg.embedding_multiplier)
     if cfg.pos_embed == "learned":
         pos = params["pos_embed"].astype(dt)
         if replicate:
@@ -1080,11 +1224,18 @@ def forward_hidden(params, tokens, cfg: TransformerConfig, mesh=None):
         for kind, lp in zip(cfg.layer_pattern, params["layers"]):
             if kind == "*":
                 q, k, v = _qkv_block(x, lp, cfg, None)
-                x = attn_out(x, _attention(q, k, v, cfg, None), lp)
+                x = x + attn_proj(_attention(q, k, v, cfg, None), lp, cfg,
+                                  dt)
             elif kind == "M":
                 x, _ = ssm_mixer(x, lp, cfg)
-            else:
+            elif kind == "E":
                 x, _ = moe_mixer(x, lp, cfg)
+            else:   # "H": both mixers read the one normed input
+                h = attn_norm(x, lp, cfg)
+                q, k, v = _qkv_heads(h, lp, cfg, None)
+                a = attn_proj(_attention(q, k, v, cfg, None), lp, cfg, dt)
+                m, _ = ssm_branch(h, lp, cfg)
+                x = gated_mlp(x + a + m, lp, cfg)
         auxes = jnp.zeros((), jnp.float32)
     elif cfg.scan_layers:
         x, auxes = lax.scan(lambda h, lp: layer(h, lp), x, params["layers"])
@@ -1212,6 +1363,7 @@ def loss_fn(params, tokens, cfg: TransformerConfig, mesh=None):
     """
     if cfg.loss_chunk:
         x, aux = forward_hidden(params, tokens, cfg, mesh)
+        x = scaled(x, cfg.lm_head_multiplier)   # (x m) W = m (x W)
         # the head's matmul lives inside the chunked loss: one scope
         with jax.named_scope("loss"):
             loss = chunked_masked_causal_nll(

@@ -15,6 +15,8 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from hpc_patterns_tpu.ops.flash_attention import flash_attention
+from hpc_patterns_tpu.ops.flash_decode import flash_decode_paged
 from hpc_patterns_tpu.ops.grouped_matmul import grouped_matmul
 from hpc_patterns_tpu.ops.ssm_step import ssm_step
 from hpc_patterns_tpu.parallel.moe import relu2
@@ -108,3 +110,50 @@ def test_ssm_step_compiles_in_place_under_its_callers_scope(
     assert compiled.memory_analysis().alias_size_in_bytes == state
     whole = r"= f32\[64,128,64,128\]\S* (copy|select|fusion)\("
     assert not [line for line in text.splitlines() if re.search(whole, line)]
+
+
+# falcon-h1-34b-stage: 32 slots of 32 heads x 128 x 256 float32, 2 groups.
+# A head's slab of S is 128 KiB, so 8 heads make the kernel's 1 MiB tile
+# (serve-chat's: 32 heads of 32 KiB)
+def test_ssm_step_compiles_at_state_256_head_128_in_place(
+        one_chip, no_compile_cache):
+    def layer(S, x, dt, A, B, C, a):
+        with jax.named_scope("ssm"), jax.named_scope("step"):
+            return ssm_step(S, x, dt, A, B, C, a, interpret=False)
+
+    shape = lambda *s, dt=jnp.float32: jax.ShapeDtypeStruct(
+        s, dt, sharding=one_chip)
+    args = [shape(32, 32, 128, 256), shape(32, 32, 128), shape(32, 32),
+            shape(32), shape(32, 2, 256), shape(32, 2, 256),
+            shape(32, dt=jnp.bool_)]
+    compiled = jax.jit(layer, donate_argnums=(0,)).lower(*args).compile()
+    calls = [line for line in compiled.as_text().splitlines()
+             if "tpu_custom_call" in line]
+    assert len(calls) == 1
+    assert re.search(r'op_name="[^"]*ssm/step/[^"]*ssm_step', calls[0])
+    assert compiled.memory_analysis().alias_size_in_bytes \
+        == 32 * 32 * 128 * 256 * 4
+
+
+# falcon-h1-34b-stage: 20 query heads on 4 K/V heads of 128, a group of 5
+# (the accepted cells have 12 and 16): a prefill rung, and a decode step
+# of 32 slots against 513 pages of 256 positions
+@pytest.mark.parametrize("kernel", ["flash_fwd", "flash_decode_paged"])
+def test_attention_kernels_compile_at_a_group_of_five(
+        one_chip, no_compile_cache, kernel):
+    shape = lambda *s, dt=jnp.bfloat16: jax.ShapeDtypeStruct(
+        s, dt, sharding=one_chip)
+    if kernel == "flash_fwd":
+        fn = lambda q, k, v: flash_attention(q, k, v, causal=True,
+                                             interpret=False)
+        args = [shape(1, 2048, 20, 128), shape(1, 2048, 4, 128),
+                shape(1, 2048, 4, 128)]
+    else:
+        fn = lambda q, kp, vp, table, pos: flash_decode_paged(
+            q, kp, vp, table, pos, scale=128 ** -0.5, interpret=False)
+        args = [shape(32, 20, 128), shape(513, 4, 256, 128),
+                shape(513, 4, 256, 128), shape(32, 16, dt=jnp.int32),
+                shape(32, dt=jnp.int32)]
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    calls = [line for line in text.splitlines() if "tpu_custom_call" in line]
+    assert len(calls) == 1 and re.search(rf"%{kernel}[.\d]* = ", calls[0])

@@ -79,7 +79,8 @@ def _program_mixer(kind, x, lp):
     if kind == "E":
         return jax.jit(lambda x, lp: T.moe_mixer(x, lp, CFG)[0])(x, lp)
     q, k, v = T._qkv_block(x, lp, CFG, None)
-    return T.attn_out(x, T._attention(q, k, v, CFG, None), lp)
+    return x + T.attn_proj(T._attention(q, k, v, CFG, None), lp, CFG,
+                           x.dtype)
 
 
 @pytest.mark.parametrize("kind", ["M", "*", "E"])
