@@ -1153,17 +1153,35 @@ _identity_verified: "weakref.WeakValueDictionary[int, object]" = (
 
 
 def _pool_write(pool, page_ids, page, offset, rows, pages: int,
-                identity: bool):
-    """Write one (B, Hkv, D) K/V row into its page slot. The general
-    form is a scatter (pages anywhere in the pool) — correct for ANY
-    table but XLA materializes a pool copy per step. With the default
-    identity layout (page j of sequence b at pool row b·pages + j,
-    ``pages`` = the TABLE's pages_per_seq) AND an exact-size pool, the
-    write is a pure ``dynamic_update_slice`` on a (B, pages, ...) view
-    — aliased in place through the generation scan, the same
-    no-rematerialization property the linear cache's DUS has. An
-    OVERSIZED pool makes the view layout disagree with the table's row
-    numbering, so it falls through to the scatter."""
+                identity: bool, tp: int = 1):
+    """Write K/V rows ``rows`` (n, Hkv, D) into their page slots: row
+    ``i`` lands in page ``page_ids[i]`` at position ``offset[i]`` (n is
+    the batch for a decode step, batch x chunk for an extension).
+
+    The general form is a scatter (pages anywhere in the pool), correct
+    for ANY table. It indexes the K/V-head axis too — ``page_ids``,
+    ``arange(Hkv)`` and ``offset`` broadcast to (n, Hkv), the window is
+    one row of ``head_dim`` — because XLA then scatters in place in the
+    layout ``flash_decode_paged`` reads (``{3,2,1,0:T(8,128)}``). With
+    the head axis a window of the scatter (``pool.at[ids, :, offset,
+    :]``) the TPU compiler gave the scatter a layout of its own (a
+    position's heads one tile) and copied the whole pool back to the
+    kernel's layout after every write: 0.41 ms a write of a 134 MB pool
+    against 0.012 ms (PERF.md, PR 31); the values are the same.
+
+    Under ``tp`` > 1 the pools are sharded on the K/V-head axis; an
+    index into a sharded axis makes GSPMD gather the pool, so there the
+    head axis stays a window (no collective, as before). That form is
+    NOT measured on a chip: a rank's local head count is another layout
+    question and no benchmark cell shards a pool.
+
+    With the default identity layout (page j of sequence b at pool row
+    b·pages + j, ``pages`` = the TABLE's pages_per_seq) AND an
+    exact-size pool, the write is a pure ``dynamic_update_slice`` on a
+    (B, pages, ...) view — aliased in place through the generation
+    scan, the same no-rematerialization property the linear cache's DUS
+    has. An OVERSIZED pool makes the view layout disagree with the
+    table's row numbering, so it falls through to the scatter."""
     B = rows.shape[0]
     if identity and pool.shape[0] == B * pages:
         n_pool, Hkv, P, D = pool.shape
@@ -1173,14 +1191,24 @@ def _pool_write(pool, page_ids, page, offset, rows, pages: int,
             (0, page, 0, offset, 0),
         )
         return v.reshape(pool.shape)
-    return pool.at[page_ids, :, offset, :].set(rows.astype(pool.dtype))
+    rows = rows.astype(pool.dtype)
+    if tp > 1:
+        return pool.at[page_ids, :, offset, :].set(rows)
+    heads = jnp.arange(pool.shape[1], dtype=jnp.int32)
+    # offset: (n,) ragged, or the scalar cursor all rows share
+    return pool.at[page_ids[:, None], heads[None, :],
+                   jnp.reshape(offset, (-1, 1)), :].set(rows)
 
 
 def _scale_write(pool, page_ids, page, offset, rows, pages: int,
                  identity: bool):
     """int8 companion of :func:`_pool_write` for the (pool_pages,
-    kv_heads, 1, page_size) lane-major scale pools: one (B, kv_heads)
-    scale row lands at lane ``offset`` of its page."""
+    kv_heads, 1, page_size) lane-major scale pools: one (n, kv_heads)
+    scale row lands at lane ``offset`` of its page. Its scatter keeps
+    the head axis as a window: indexed like :func:`_pool_write`'s it
+    still compiles with copies of the (2 MB) scale pools around it
+    (described v5e, PR 31), so there was nothing to gain, and no
+    benchmark cell runs quantized pools."""
     B = rows.shape[0]
     if identity and pool.shape[0] == B * pages:
         v = pool.reshape(B, pages, *pool.shape[1:])
@@ -1371,9 +1399,9 @@ def paged_decode_step(params, cache, pos, tokens, cfg: TransformerConfig,
             vs_pool = _scale_write(vs_pool, page_ids, page, offset, v_s,
                                    pages, ident)
         k_pool = _pool_write(k_pool, page_ids, page, offset, k_new,
-                             pages, ident)
+                             pages, ident, tp)
         v_pool = _pool_write(v_pool, page_ids, page, offset, v_new,
-                             pages, ident)
+                             pages, ident, tp)
         return k_pool, v_pool, ks_pool, vs_pool
 
     def attend_update(q, k_new, v_new, state):
@@ -1438,7 +1466,8 @@ def paged_decode_step(params, cache, pos, tokens, cfg: TransformerConfig,
     return logits, out
 
 
-def paged_extend_step(params, cache, pos, tokens, cfg: TransformerConfig):
+def paged_extend_step(params, cache, pos, tokens, cfg: TransformerConfig,
+                      mesh=None):
     """RAGGED multi-token cache extension against the paged cache: row
     ``b``'s chunk ``tokens[b]`` occupies positions ``pos[b] ..
     pos[b]+c-1`` — every row at its own length, the verification
@@ -1455,6 +1484,10 @@ def paged_extend_step(params, cache, pos, tokens, cfg: TransformerConfig):
     dequantizes the linearized view (unlike linear
     :func:`extend_step`, which stays compute-only).
     Returns (logits (B, c, vocab) f32, updated cache).
+
+    ``mesh``: only tells :func:`_pool_write` whether the pools are
+    tp-sharded (its scatter then keeps the head axis whole); the math
+    partitions via GSPMD from the sharded params/pools alone.
 
     CONTRACT (same as :func:`paged_decode_step`): every touched
     position < pages_per_seq * page_size; concrete ``pos`` is checked,
@@ -1478,6 +1511,7 @@ def paged_extend_step(params, cache, pos, tokens, cfg: TransformerConfig):
                 f"capacity {pages * Pg} tokens")
     scale = 1.0 / (cfg.head_dim ** 0.5)
     Hkv, g, Dh = cfg.kv_heads, cfg.n_heads // cfg.kv_heads, cfg.head_dim
+    tp = _tp_size(mesh, cfg)
 
     positions = pos[:, None] + jnp.arange(c, dtype=jnp.int32)  # (B, c)
     x = params["embed"].astype(dt)[tokens]
@@ -1511,12 +1545,14 @@ def paged_extend_step(params, cache, pos, tokens, cfg: TransformerConfig):
         if quant:
             rows_k, k_s = _quantize_rows(rows_k, cfg.kv_cache_dtype)
             rows_v, v_s = _quantize_rows(rows_v, cfg.kv_cache_dtype)
-            ks_pool = ks_pool.at[pids, :, 0, off].set(k_s)
-            vs_pool = vs_pool.at[pids, :, 0, off].set(v_s)
-        k_pool = k_pool.at[pids, :, off, :].set(
-            rows_k.astype(k_pool.dtype))
-        v_pool = v_pool.at[pids, :, off, :].set(
-            rows_v.astype(v_pool.dtype))
+            ks_pool = _scale_write(ks_pool, pids, None, off, k_s, pages,
+                                   False)
+            vs_pool = _scale_write(vs_pool, pids, None, off, v_s, pages,
+                                   False)
+        k_pool = _pool_write(k_pool, pids, None, off, rows_k, pages, False,
+                             tp)
+        v_pool = _pool_write(v_pool, pids, None, off, rows_v, pages, False,
+                             tp)
         if quant:
             kd = (lin_view(k_pool).astype(jnp.float32)
                   * lin_scales(ks_pool)[..., None])

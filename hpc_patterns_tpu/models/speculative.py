@@ -294,7 +294,7 @@ def paged_round(params, cfg, draft_params, draft_cfg, cache, dcache,
 
     chunk = jnp.concatenate([cur[:, None], props], axis=1)
     vlogits, cache = paged_extend_step(params, cache, pos_eff, chunk,
-                                       cfg)
+                                       cfg, mesh=mesh)
     if greedy:
         t_all = jnp.argmax(vlogits, axis=-1).astype(jnp.int32)
         matches = (props == t_all[:, :gamma]).astype(jnp.int32)

@@ -13,8 +13,10 @@ import re
 import jax
 import jax.numpy as jnp
 import pytest
+from jax import lax
 from jax.sharding import SingleDeviceSharding
 
+from hpc_patterns_tpu.models.decode import _pool_write
 from hpc_patterns_tpu.ops.flash_attention import flash_attention
 from hpc_patterns_tpu.ops.flash_decode import flash_decode_paged
 from hpc_patterns_tpu.ops.grouped_matmul import grouped_matmul
@@ -157,3 +159,54 @@ def test_attention_kernels_compile_at_a_group_of_five(
     text = jax.jit(fn).lower(*args).compile().as_text()
     calls = [line for line in text.splitlines() if "tpu_custom_call" in line]
     assert len(calls) == 1 and re.search(rf"%{kernel}[.\d]* = ", calls[0])
+
+
+# the serving cells' K/V pools (pages + the trash page, K/V heads, page
+# 256, head 128) with their slots and query heads: serve-assist, serve-gen
+# (serve-code's pool is the same with 161 pages), serve-chat
+@pytest.mark.parametrize("pool,slots,q_heads", [
+    ((513, 4, 256, 128), 32, 20),
+    ((385, 2, 256, 128), 32, 24),
+    ((1025, 2, 256, 128), 64, 32),
+])
+def test_a_decode_steps_kv_row_lands_in_its_pool_in_place(
+        one_chip, no_compile_cache, pool, slots, q_heads):
+    """A chunk's loop of write-then-attend over donated pools: the scatter
+    of ``decode._pool_write`` must come out in the layout
+    ``flash_decode_paged`` reads, so that no step copies a whole pool
+    (12 copies of 134 MB a step in serve-assist before PR 31)."""
+    def chunk(pools, q, new, table, pos):
+        def step(carry, _):
+            pools, pos, acc = carry
+            page_ids = jnp.take_along_axis(
+                table, (pos // 256)[:, None], axis=1)[:, 0]
+            out = []
+            for k_pool, v_pool in pools:
+                k_pool = _pool_write(k_pool, page_ids, None, pos % 256, new,
+                                     16, False)
+                v_pool = _pool_write(v_pool, page_ids, None, pos % 256, new,
+                                     16, False)
+                acc = acc + flash_decode_paged(
+                    q, k_pool, v_pool, table, pos, scale=128 ** -0.5,
+                    interpret=False)
+                out.append((k_pool, v_pool))
+            return (tuple(out), pos + 1, acc), None
+
+        acc = jnp.zeros(q.shape, jnp.float32)
+        (pools, _, acc), _ = lax.scan(step, (pools, pos, acc), None,
+                                      length=8)
+        return pools, acc
+
+    shape = lambda *s, dt=jnp.bfloat16: jax.ShapeDtypeStruct(
+        s, dt, sharding=one_chip)
+    pools = tuple((shape(*pool), shape(*pool)) for _ in range(2))
+    compiled = jax.jit(chunk, donate_argnums=(0,)).lower(
+        pools, shape(slots, q_heads, 128), shape(slots, pool[1], 128),
+        shape(slots, 16, dt=jnp.int32), shape(slots, dt=jnp.int32)).compile()
+    whole = r"= bf16\[{},{},{},{}\]\S* copy\(".format(*pool)
+    text = compiled.as_text()
+    assert len(re.findall(r"%flash_decode_paged[.\d]* = ", text)) == 2
+    assert not [line for line in text.splitlines() if re.search(whole, line)]
+    memory = compiled.memory_analysis()
+    assert memory.temp_size_in_bytes < 64 << 20
+    assert memory.alias_size_in_bytes >= 4 * 2 * pool[0] * pool[1] * 256 * 128

@@ -1017,3 +1017,132 @@ class TestRaggedPaged:
         for b in range(2):
             np.testing.assert_allclose(np.asarray(got[b]), want[b],
                                        atol=1e-5, err_msg=f"row {b}")
+
+
+def _window_pool_write(pool, page_ids, offset, rows):
+    """The scatter as the parent of PR 31 wrote it, the K/V-head axis a
+    window of the update: the oracle of :func:`_pool_write`'s values."""
+    return pool.at[page_ids, :, offset, :].set(rows.astype(pool.dtype))
+
+
+def _window_scale_write(pool, page_ids, offset, rows):
+    return pool.at[page_ids, :, 0, offset].set(rows.astype(pool.dtype))
+
+
+class TestPoolWrite:
+    """``decode._pool_write`` indexes the K/V-head axis so that the TPU
+    compiler scatters in the kernel's layout; the values are the window
+    form's, to the last bit."""
+
+    POOL, HKV, P, D = 9, 4, 8, 16     # the last page is the trash page
+
+    @staticmethod
+    def _draw(seed, shape, dtype):
+        key = jax.random.PRNGKey(seed)
+        if dtype == "int8":
+            return jax.random.randint(key, shape, -127, 128, jnp.int8)
+        return jax.random.normal(key, shape, jnp.float32).astype(dtype)
+
+    def _pool(self, dtype):
+        return self._draw(0, (self.POOL, self.HKV, self.P, self.D), dtype)
+
+    def _rows(self, n, dtype):
+        # float32 rows whatever the floating pool: the write rounds them
+        return self._draw(1, (n, self.HKV, self.D),
+                          "int8" if dtype == "int8" else "float32")
+
+    @pytest.mark.parametrize("dtype", ["float32", "bfloat16", "int8"])
+    @pytest.mark.parametrize("form", ["ragged", "one_cursor", "sharded"])
+    def test_rows_equal_the_window_form_to_the_last_bit(self, form, dtype):
+        """Ragged offsets; the scalar cursor all rows share (off the
+        identity layout it scatters too); and the form kept for pools
+        sharded on the head axis."""
+        from hpc_patterns_tpu.models.decode import _pool_write
+
+        pool = self._pool(dtype)
+        ids = jnp.array([3, 0, 7, 5, 1], jnp.int32)
+        off = (jnp.int32(5) if form == "one_cursor"
+               else jnp.array([7, 0, 3, 3, 5], jnp.int32))
+        rows = self._rows(5, dtype)
+        got = jax.jit(lambda p: _pool_write(
+            p, ids, None, off, rows, 2, False,
+            tp=2 if form == "sharded" else 1))(pool)
+        want = _window_pool_write(pool, ids, off, rows)
+        assert got.dtype == pool.dtype and got.shape == pool.shape
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+        # and nothing else moved: 5 rows of HKV x D differ at most
+        changed = np.asarray(got != pool).any(axis=-1)
+        assert changed.sum() <= 5 * self.HKV
+
+    @pytest.mark.parametrize("dtype", ["float32", "bfloat16", "int8"])
+    def test_idle_rows_aimed_at_one_trash_page(self, dtype):
+        """The engine points every idle slot at the trash page, position
+        0: a scatter may keep any one of the rows that collide there. The
+        live rows' pages, and the trash page off that position, are the
+        window form's bits."""
+        from hpc_patterns_tpu.models.decode import _pool_write
+
+        trash = self.POOL - 1
+        pool = self._pool(dtype)
+        ids = jnp.array([2, trash, trash, 6, trash], jnp.int32)
+        off = jnp.array([4, 0, 0, 1, 0], jnp.int32)
+        rows = self._rows(5, dtype)
+        got = np.asarray(_pool_write(pool, ids, None, off, rows, 2, False))
+        want = np.asarray(_window_pool_write(pool, ids, off, rows))
+        np.testing.assert_array_equal(got[:trash], want[:trash])
+        np.testing.assert_array_equal(got[trash, :, 1:], want[trash, :, 1:])
+        aimed = np.asarray(rows.astype(pool.dtype))[[1, 2, 4]]
+        assert any(np.array_equal(got[trash, :, 0], r) for r in aimed)
+
+    def test_scale_rows_equal_the_window_form(self):
+        from hpc_patterns_tpu.models.decode import _scale_write
+
+        pool = self._draw(3, (self.POOL, self.HKV, 1, self.P), "float32")
+        ids = jnp.array([3, 0, 7, 5], jnp.int32)
+        off = jnp.array([7, 0, 3, 3], jnp.int32)
+        rows = self._draw(4, (4, self.HKV), "float32")
+        got = _scale_write(pool, ids, None, off, rows, 2, False)
+        np.testing.assert_array_equal(
+            np.asarray(got),
+            np.asarray(_window_scale_write(pool, ids, off, rows)))
+
+    def test_tp_ragged_step_gains_no_collective(self, mesh_dp_sp_tp,
+                                                monkeypatch):
+        """Pools sharded on the K/V-head axis: the compiled ragged step
+        must hold no collective that the window form's did not. (An index
+        into the sharded axis would make GSPMD gather each pool.)"""
+        import re
+
+        from hpc_patterns_tpu.models import decode as D
+        from hpc_patterns_tpu.models.sharding import shard_params
+
+        cfg, params, prompt = _setup(n_heads=4, n_kv_heads=2)
+        p_sh = shard_params(params, mesh_dp_sp_tp, cfg)
+        sc = D.init_paged_cache(cfg, 2, pages_per_seq=3, page_size=8)
+        _, sc = D.paged_prefill(p_sh, prompt, cfg, sc, 8,
+                                mesh=mesh_dp_sp_tp)
+        pos = jnp.array([8, 9], jnp.int32)
+        tok = jnp.array([1, 2], jnp.int32)
+
+        def collectives():
+            text = jax.jit(lambda p, c: D.paged_decode_step(
+                p, c, pos, tok, cfg, mesh=mesh_dp_sp_tp)).lower(
+                    p_sh, sc).compile().as_text()
+            return {kind: len(re.findall(rf" {kind}(-start)?\(", text))
+                    for kind in ("all-gather", "all-reduce",
+                                 "collective-permute", "all-to-all")}
+
+        ours = collectives()
+        real = D._pool_write
+        monkeypatch.setattr(
+            D, "_pool_write",
+            lambda pool, ids, page, off, rows, pages, identity, tp=1:
+            _window_pool_write(pool, ids, off, rows))
+        assert ours == collectives()
+        # what the test guards against is visible to it: the single-chip
+        # form on the sharded pools does gather them
+        monkeypatch.setattr(
+            D, "_pool_write",
+            lambda pool, ids, page, off, rows, pages, identity, tp=1:
+            real(pool, ids, page, off, rows, pages, identity))
+        assert collectives()["all-gather"] > ours["all-gather"]

@@ -414,14 +414,17 @@ DENSE = T.TransformerConfig(
 # engine's three programs at DENSE (the prefill, the admission's device
 # bookkeeping, whose signature and donation this PR changed, and the
 # chunk), and its logits (prefill of 24 tokens, 16 decode steps, 2 rows):
-# tests/fixtures/dense_block_pr25_logits.npy
+# tests/fixtures/dense_block_pr25_logits.npy. PR 31 re-pinned the chunk:
+# its K/V write indexes the head axis (decode._pool_write), another
+# scatter of the same values, which the logits test below still holds to
+# the PR 25 fixture
 PARENT_PROGRAMS = {
     "_admit_row":
     "1bb6625a4fc7306bd9709f4592a8a4b7641654e1da0609877d86508b92c5918c",
     "_prefill_one":
     "31f87a71d09866b850979ced4ab3da6347112ce64df33d0581d1dc2e8dfeb196",
     "_chunk_step":
-    "9a0978e3aa59b3a6a1693415ad50d1b8929458cbd5223f60043a6cc51a989326",
+    "9c13bd1fb61bdca77a5bc66c73026fc5b0eaa948775605edcd01e8e131379c99",
 }
 
 
