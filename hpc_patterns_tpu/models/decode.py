@@ -64,6 +64,7 @@ from hpc_patterns_tpu.models.transformer import (
     matmul_weight,
     moe_mixer,
     project_qkv,
+    routed_mlp,
     scaled,
     scoped,
     ssm_branch,
@@ -238,8 +239,9 @@ def init_layer_state(cfg: TransformerConfig, batch: int) -> dict:
     """What a patterned model's cache holds beside K/V: ``conv`` / ``ssm``,
     one (batch, ...) array a layer that holds state (the convolution's
     tail and the recurrent state S, models/ssm.py), and ``moe_stats``,
-    the route's running sums (parallel/moe.ROUTE_STATS; row 0 prefills,
-    row 1 decode steps). Empty for the default pattern."""
+    the "E" and "R" layers' route's running sums
+    (parallel/moe.ROUTE_STATS; row 0 prefills, row 1 decode steps). Empty
+    for the default pattern."""
     out = {}
     if cfg.n_state_layers:
         from hpc_patterns_tpu.models import ssm
@@ -247,7 +249,7 @@ def init_layer_state(cfg: TransformerConfig, batch: int) -> dict:
         out.update(_state_entries(
             [ssm.init_state(cfg, batch)
              for _ in range(cfg.n_state_layers)]))
-    if "E" in cfg.layer_pattern:
+    if cfg.n_routed_layers:
         from hpc_patterns_tpu.parallel.moe import ROUTE_STATS
 
         out["moe_stats"] = jnp.zeros((2, len(ROUTE_STATS)), jnp.int32)
@@ -263,9 +265,12 @@ def _state_entries(pairs) -> dict:
 def _dense_only(cfg: TransformerConfig, what: str) -> None:
     if cfg.layer_pattern:
         raise ValueError(
-            f"{what} covers the default layer pattern only: a patterned "
-            f"model ({cfg.layer_pattern!r}) has per-row recurrent state "
-            "that this route neither carries nor rewinds")
+            f"{what} covers the default layer pattern only (causal, "
+            f"dense blocks): a patterned model ({cfg.layer_pattern!r}) "
+            "may hold per-row recurrent state, which this route neither "
+            "carries nor rewinds, and its layer kinds are not written "
+            "here. The multi-position step of an all-'R' model under the "
+            "block mask is paged_block_step")
 
 
 @scoped("mlp")
@@ -298,7 +303,11 @@ def prefill(params, prompt, cfg: TransformerConfig, max_len: int,
             mesh=None, last_pos=None):
     """Run the prompt in one batched pass (MXU-shaped, exactly
     transformer.forward's math) while capturing each layer's K/V into a
-    fresh cache. Returns (last_logits (B, V) f32, cache).
+    fresh cache. Returns (last_logits (B, V) f32, cache); the logits are
+    None for a model with ``cfg.block_len`` (generation by diffusion over
+    blocks), whose prompt pass runs under the block mask over the prompt's
+    WHOLE blocks (``last_pos``: the last position of the last whole block;
+    what lies behind it is padding) and feeds no head.
 
     ``max_len`` sizes the static cache (prompt + planned new tokens,
     <= cfg.max_seq). ``mesh``: tp-sharded serving — the flash prefill
@@ -346,6 +355,9 @@ def prefill(params, prompt, cfg: TransformerConfig, max_len: int,
         # a 17 GB allocation at B=8); short/ragged prompts and sharded
         # (gather-mode) serving keep the einsum path, which consumes
         # the narrow GQA K/V directly
+        # a model that generates by diffusion over blocks prefills under
+        # the block mask (unsharded: it is a patterned model)
+        mb = {"mask_block": cfg.block_len} if cfg.block_len else {}
         if use_flash and T % 128 == 0:
             from hpc_patterns_tpu.ops import flash_attention
 
@@ -358,9 +370,9 @@ def prefill(params, prompt, cfg: TransformerConfig, max_len: int,
                     check_vma=False,  # pallas_call can't declare vma
                 )(q, k, v)
             else:
-                o = flash_attention(q, k, v, causal=True)
+                o = flash_attention(q, k, v, causal=True, **mb)
         else:
-            o = full_attention(q, k, v, causal=True)
+            o = full_attention(q, k, v, causal=True, **mb)
         return o, k, v
 
     @scoped("kv_write")
@@ -391,8 +403,11 @@ def prefill(params, prompt, cfg: TransformerConfig, max_len: int,
                  jnp.arange(T, dtype=jnp.int32)[None, :] <= last[:, None])
         ks, vs, states, stats = [], [], [], []
         for kind, lp in zip(cfg.layer_pattern, params["layers"]):
-            if kind == "*":
+            if kind in "*R":   # "R": attention, then the routed experts
                 x, (kc, vc) = body(x, lp, mlp=False)
+                if kind == "R":
+                    x, st = routed_mlp(x, lp, cfg, valid)
+                    stats.append(st)
             elif kind == "M":
                 x, st = ssm_mixer(x, lp, cfg, last)
             elif kind == "E":
@@ -415,14 +430,19 @@ def prefill(params, prompt, cfg: TransformerConfig, max_len: int,
                 [sum(stats), jnp.zeros_like(stats[0])])
     else:
         x, (ks, vs) = lax.scan(body, x, params["layers"])
-    with jax.named_scope("head"):
-        x = _rmsnorm(x, params["ln_f_scale"], cfg.norm_eps)
-        if last_pos is None:
-            x_last = x[:, -1]
-        else:
-            x_last = jnp.take_along_axis(x, rows_last()[:, None, None],
-                                         axis=1)[:, 0]
-        logits = head_logits(x_last, params, cfg)
+    if cfg.block_len:
+        # the first block is denoised from the stored K/V: no position
+        # of the prompt pass predicts a token that anyone reads
+        logits = None
+    else:
+        with jax.named_scope("head"):
+            x = _rmsnorm(x, params["ln_f_scale"], cfg.norm_eps)
+            if last_pos is None:
+                x_last = x[:, -1]
+            else:
+                x_last = jnp.take_along_axis(
+                    x, rows_last()[:, None, None], axis=1)[:, 0]
+            logits = head_logits(x_last, params, cfg)
     L = cfg.n_attn_layers
     if _kv_quantized(cfg):
         kvd = cfg.kv_cache_dtype
@@ -464,8 +484,9 @@ def _token_step(params, pos, tokens, cfg: TransformerConfig,
     recurrence against ``row_states`` (the (conv tail, S) pairs, written
     back only where ``active``), "E" the expert layer (idle rows pick
     nothing), "H" the attention and one step of the recurrence off one
-    norm, summed, then the gated MLP. Returns (logits, the K/V layers'
-    new states, the other cache entries)."""
+    norm, summed, then the gated MLP, "R" the attention and then the
+    routed experts. Returns (logits, the K/V layers' new states, the
+    other cache entries)."""
     dt = jnp.dtype(cfg.dtype)
     B = tokens.shape[0]
     with jax.named_scope("embed"):
@@ -514,7 +535,10 @@ def _token_step(params, pos, tokens, cfg: TransformerConfig,
                                     active)
             new_rows.append(rs)
             x = x + m
-        if holds.mlp:
+        if holds.mlp == "routed":   # idle rows pick no expert
+            x, st = routed_mlp(x, lp, cfg, active)
+            stats.append(st)
+        elif holds.mlp:
             x = (gated_mlp if holds.mlp == "gated" else _mlp)(x, lp, cfg)
     with jax.named_scope("head"):
         x = _rmsnorm(x, params["ln_f_scale"], cfg.norm_eps)
@@ -1237,7 +1261,9 @@ def _paged_attend_gather(q, k_pool, v_pool, ks_pool, vs_pool, table,
     ``pos``: scalar or ragged (B,); int8 pools dequantize in the einsum
     stream like the linear gather."""
     B, pages = table.shape
-    Hkv, g, Dh = cfg.kv_heads, cfg.n_heads // cfg.kv_heads, cfg.head_dim
+    # g from q: a block step folds its positions into the group
+    Hkv, Dh = cfg.kv_heads, cfg.head_dim
+    g = q.shape[1] // Hkv
     P = k_pool.shape[2]
     quant = ks_pool is not None
 
@@ -1264,7 +1290,7 @@ def _paged_attend_gather(q, k_pool, v_pool, ks_pool, vs_pool, table,
     p = jax.nn.softmax(s, axis=-1)
     o = jnp.einsum("bkgs,bksd->bkgd", p, vd,
                    precision=lax.Precision.HIGHEST)
-    return o.reshape(B, cfg.n_heads, Dh)
+    return o.reshape(B, Hkv * g, Dh)
 
 
 def paged_decode_step(params, cache, pos, tokens, cfg: TransformerConfig,
@@ -1597,6 +1623,112 @@ def paged_extend_step(params, cache, pos, tokens, cfg: TransformerConfig,
         out["k_scale"] = tuple(s[2] for s in states)
         out["v_scale"] = tuple(s[3] for s in states)
     return logits.astype(jnp.float32), out
+
+
+def paged_block_step(params, cache, pos, tokens, cfg: TransformerConfig,
+                     active=None):
+    """One forward over a BLOCK a row against the paged cache, for a model
+    that generates by diffusion over blocks (``cfg.block_len``, all "R"
+    layers): row ``b``'s ``tokens[b]`` (B, c = block_len) occupy positions
+    ``pos[b] .. pos[b] + c - 1`` (``pos`` (B,) int32, a multiple of c:
+    rows are ragged). Each layer writes the block's K/V rows into the
+    pool at those positions, over whatever an earlier forward of the same
+    block left there, and every position of the block attends over keys
+    ``0 .. pos[b] + c - 1``: the stored blocks before it and ALL of its
+    own, through one ``flash_decode_paged`` call with the block folded
+    into the group (``cfg.decode_attn == "gather"``: the pure-XLA view).
+    The write is PROVISIONAL while the block still holds masks (the next
+    forward of the block overwrites it) and stands once the caller moves
+    the row's cursor past the block. Returns (logits (B, c, vocab)
+    float32, the logits AT a position predict that position, and the
+    updated cache).
+
+    ``active`` (B,) bool: rows that count. The others run at position 0,
+    write nothing (their page ids point past the pool: the scatter drops
+    them) and pick no expert.
+
+    CONTRACT (as :func:`paged_decode_step`): ``pos[b] + c`` within the
+    row's pages; a concrete ``pos`` is checked."""
+    c = cfg.block_len
+    if not c or tokens.ndim != 2 or tokens.shape[1] != c:
+        raise ValueError(
+            f"paged_block_step takes (batch, block_len = {c}) tokens of a "
+            f"model with block_len > 0, got {tokens.shape}")
+    if cfg.decode_attn not in ("flash", "gather") or _kv_quantized(cfg):
+        raise ValueError(
+            "paged_block_step is written for decode_attn 'flash' or "
+            "'gather' over compute-dtype pools, not "
+            f"{cfg.decode_attn!r} / kv_cache_dtype {cfg.kv_cache_dtype!r}")
+    B = tokens.shape[0]
+    table = cache["table"]
+    n_pool, _, Pg, _ = cache["k"][0].shape
+    pages = table.shape[1]
+    if jnp.ndim(pos) != 1 or jnp.shape(pos)[0] != B:
+        raise ValueError(
+            f"pos must be (batch,)={B} per-row positions, got "
+            f"{jnp.shape(pos)}")
+    if not isinstance(pos, jax.core.Tracer):
+        if np.any(np.asarray(pos) % c) or np.any(
+                np.asarray(pos) + c > pages * Pg):
+            raise ValueError(
+                f"block starts {np.asarray(pos)} must be multiples of {c} "
+                f"and end within the cache's {pages * Pg} tokens")
+    dt = jnp.dtype(cfg.dtype)
+    scale = 1.0 / (cfg.head_dim ** 0.5)
+    if active is not None:
+        pos = jnp.where(active, pos, 0)
+    positions = pos[:, None] + jnp.arange(c, dtype=jnp.int32)   # (B, c)
+    off = (positions % Pg).reshape(-1)
+    pids = jnp.take_along_axis(table, positions // Pg, axis=1).reshape(-1)
+    valid = None
+    if active is not None:
+        valid = jnp.broadcast_to(active[:, None], (B, c))
+        pids = jnp.where(valid.reshape(-1), pids, n_pool)   # dropped
+    with jax.named_scope("embed"):
+        x = scaled(params["embed"].astype(dt)[tokens],
+                   cfg.embedding_multiplier)
+        if cfg.pos_embed == "learned":
+            x = x + params["pos_embed"].astype(dt)[positions]
+
+    @scoped("kv_write")
+    def write(pool, rows):
+        return _pool_write(pool, pids, None, off,
+                           rows.reshape(B * c, cfg.kv_heads, cfg.head_dim),
+                           pages, False)
+
+    @scoped("attn")
+    def attend(q, k_pool, v_pool):
+        from hpc_patterns_tpu.ops import flash_decode
+
+        if cfg.decode_attn == "gather":   # the same fold, the XLA view
+            o = _paged_attend_gather(
+                flash_decode.fold_block(q, cfg.kv_heads), k_pool, v_pool,
+                None, None, table, pos + (c - 1), cfg, scale)
+            return flash_decode.unfold_block(o, c, cfg.kv_heads)
+        return flash_decode.flash_decode_paged_block(
+            q, k_pool, v_pool, table, pos, scale=scale)
+
+    ks, vs, stats = [], [], []
+    for l, lp in enumerate(params["layers"]):
+        hn = attn_norm(x, lp, cfg)
+        with jax.named_scope("attn"):
+            q, k_new, v_new = project_qkv(hn, lp, cfg)   # (B, c, H/Hkv, Dh)
+            if cfg.pos_embed == "rope":
+                q = apply_rope(q, positions, cfg)
+                k_new = apply_rope(k_new, positions, cfg)
+        k_pool = write(cache["k"][l], k_new)
+        v_pool = write(cache["v"][l], v_new)
+        x = x + attn_proj(attend(q, k_pool, v_pool), lp, cfg, dt)
+        x, st = routed_mlp(x, lp, cfg, valid)
+        ks.append(k_pool)
+        vs.append(v_pool)
+        stats.append(st)
+    with jax.named_scope("head"):
+        x = _rmsnorm(x, params["ln_f_scale"], cfg.norm_eps)
+        logits = head_logits(x, params, cfg)
+    out = {"k": tuple(ks), "v": tuple(vs), "table": table}
+    _apply_extra(cache, out, {"moe_stats": sum(stats)})
+    return logits, out
 
 
 @partial(jax.jit, static_argnums=(2, 3, 4, 5, 8, 9, 10))

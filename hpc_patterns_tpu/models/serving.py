@@ -188,12 +188,14 @@ from hpc_patterns_tpu.models.decode import (
     _pick,
     _topk_mask,
     init_paged_cache,
+    paged_block_step,
     paged_decode_step,
     paged_prefill,
     paged_tail_prefill,
 )
 from hpc_patterns_tpu.models.transformer import (
     TransformerConfig,
+    scoped,
     serving_cast_leaves,
     serving_weights,
 )
@@ -420,6 +422,7 @@ class _Slot:
     prefix: list = field(default_factory=list)  # pre-preemption tokens
     padded_len: int = 0      # the admission rung this row prefilled at
     shared_pages: int = 0    # leading table entries mapped SHARED
+    cursor: int = 0          # a diffusion row's block start, host mirror
 
 
 @partial(jax.jit,
@@ -469,6 +472,116 @@ def _chunk_step(params, cache, pos, limit, tokens, keys, temps, *, cfg,
         step, (cache, pos, limit, tokens, keys), None, length=chunk
     )
     return cache, pos, limit, tokens, keys, out
+
+
+#: how a denoising forward chooses the masked positions it settles
+UNMASK_RULES = ("static", "dynamic")
+
+#: the running sums a block engine keeps on the device (int32, whole
+#: numbers that wrap: read differences), :meth:`EngineCore.diffusion_stats`
+DIFFUSION_STATS = ("forwards", "blocks", "tokens")
+
+
+@scoped("unmask")
+def _unmask(logits, msk, *, rule: str, steps: int, threshold: float):
+    """What one denoising forward settles. ``logits`` (rows, B, vocab)
+    float32 at the block's positions, ``msk`` (rows, B) the positions
+    still masked. Every position's candidate is its argmax and its
+    confidence that token's softmax probability; among the masked ones,
+    ``static`` settles the ``ceil(B / steps)`` most confident (ties by
+    index, as ``lax.top_k``), ``dynamic`` every one above ``threshold``
+    and at least the most confident. Returns (candidates (rows, B) int32,
+    settle (rows, B) bool, inside ``msk``)."""
+    B = msk.shape[1]
+    best = jnp.max(logits, axis=-1)
+    cand = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+    conf = jnp.exp(best - jax.nn.logsumexp(logits, axis=-1))
+    masked_conf = jnp.where(msk, conf, -1.0)
+    at = jnp.arange(B, dtype=jnp.int32)
+    if rule == "static":
+        _, first = lax.top_k(masked_conf, -(-B // steps))        # (rows, n)
+        settle = jnp.any(first[:, :, None] == at, axis=1)
+    else:
+        top = jnp.argmax(masked_conf, axis=-1)
+        settle = (conf > threshold) | (at == top[:, None])
+    return cand, settle & msk
+
+
+@partial(jax.jit,
+         static_argnames=("cfg", "forwards", "rule", "steps", "threshold"),
+         donate_argnums=(1, 2, 4, 5, 6, 7, 8))
+def _block_chunk(params, cache, pos, limit, blk, msk, fidx, nfw, dstats, *,
+                 cfg, forwards, rule, steps, threshold):
+    """``forwards`` block forwards in one trace, for a model that
+    generates by diffusion over blocks (``cfg.block_len`` = B). A row
+    carries its block between forwards: ``blk`` (rows, B) the tokens
+    (``cfg.mask_id`` where none is settled), ``msk`` the positions still
+    masked, ``fidx`` the index of the forward that settled each (-1: given
+    by the prompt), ``nfw`` the denoising forwards its block has had. In
+    each forward every live row (``pos < limit``; ``pos`` the block's
+    start) runs :func:`paged_block_step` over its block and then either
+
+    - still holds masks: this was a DENOISING forward, which settles part
+      of them (:func:`_unmask`); its K/V write is provisional; or
+    - holds none: this was its COMMIT forward, whose K/V write stands.
+      The block's tokens and ``fidx`` go to the output, the row moves on
+      by B positions and lays a fresh block of masks.
+
+    Rows are ragged in position and in phase; a row whose block reaches
+    ``limit`` is done after that block's commit (the host cuts the block
+    at the limit). Returns the carried arrays and, a forward, (the
+    block's tokens, its ``fidx``, committed (rows,) bool)."""
+    B = cfg.block_len
+    at = jnp.arange(B, dtype=jnp.int32)
+
+    def step(carry, _):
+        cache, pos, blk, msk, fidx, nfw, dstats = carry
+        active = pos < limit
+        logits, cache = paged_block_step(params, cache, pos, blk, cfg,
+                                         active=active)
+        cand, settle = _unmask(logits, msk, rule=rule, steps=steps,
+                               threshold=threshold)
+        masked = jnp.any(msk, axis=-1)
+        commit = active & ~masked
+        settle = settle & (active & masked)[:, None]
+        out = (blk, fidx, commit)
+        with jax.named_scope("kv_commit"):
+            # the cursor passes the block: its last write is the stored one
+            handed = jnp.sum(commit[:, None] & (fidx >= 0)
+                             & (pos[:, None] + at < limit[:, None]),
+                             dtype=jnp.int32)
+            pos = jnp.where(commit, pos + B, pos)
+            fresh = commit[:, None]
+            blk = jnp.where(fresh, cfg.mask_id, jnp.where(settle, cand, blk))
+            msk = fresh | (msk & ~settle)
+            fidx = jnp.where(fresh, -1,
+                             jnp.where(settle, nfw[:, None], fidx))
+            nfw = jnp.where(commit, 0, nfw + (active & masked))
+        dstats = dstats + jnp.stack([
+            jnp.sum(active, dtype=jnp.int32),
+            jnp.sum(commit, dtype=jnp.int32), handed])
+        return (cache, pos, blk, msk, fidx, nfw, dstats), out
+
+    carry, out = lax.scan(step, (cache, pos, blk, msk, fidx, nfw, dstats),
+                          None, length=forwards)
+    return (*carry, out)
+
+
+@partial(jax.jit, donate_argnums=(0, 1, 2, 3, 4, 5))
+def _admit_block_row(pos, limit, blk, msk, fidx, nfw, slot, start, end,
+                     first, given):
+    """A diffusion row's admission bookkeeping in one dispatch: the cursor
+    at the first block that is not whole in the prompt (``start``), the
+    limit at prompt + budget (``end``), and that block laid: ``first``
+    (B,) the prompt's remainder (``given`` tokens) then masks."""
+    B = blk.shape[1]
+    pos = pos.at[slot].set(start)
+    limit = limit.at[slot].set(end)
+    blk = blk.at[slot].set(first)
+    msk = msk.at[slot].set(jnp.arange(B, dtype=jnp.int32) >= given)
+    fidx = fidx.at[slot].set(-1)
+    nfw = nfw.at[slot].set(0)
+    return pos, limit, blk, msk, fidx, nfw
 
 
 @partial(jax.jit,
@@ -743,6 +856,21 @@ class EngineCore:
     resume), migration (bundles carry prefix refs a warm destination
     resolves — or it materializes), and residency (shared pages are
     pinned while a second reader is resident).
+
+    A model that generates BY DIFFUSION OVER BLOCKS (``cfg.block_len`` =
+    B) goes through the same arena, admission and round loop, with a
+    block a row a step in place of a token: admission prefills the
+    prompt's whole blocks under the block mask and takes no token from
+    it; ``chunk`` counts block FORWARDS a dispatch (:func:`_block_chunk`);
+    a row hands out up to B tokens at each of its commits and its first
+    tokens' readback is its ``serve.first_token``. ``unmask_rule``
+    ("static": the ``ceil(B / unmask_steps)`` most confident masked
+    positions a denoising forward; "dynamic": every one whose confidence
+    passes ``unmask_threshold``, at least one) says what a denoising
+    forward settles. Greedy only; speculation, prefix sharing,
+    preemption, residency, migration, an end token and a mesh are refused
+    with the mechanism that is missing. :meth:`diffusion_stats` hands a
+    caller the forwards, blocks and tokens so far.
     """
 
     def __init__(self, params, cfg: TransformerConfig, *, slots: int,
@@ -755,7 +883,56 @@ class EngineCore:
                  seed: int = 0, preempt: bool = False,
                  admit_highwater: float = 1.0,
                  slo: dict[int, slolib.SLOTarget] | None = None,
-                 residency=None, prefix_cache: bool = False):
+                 residency=None, prefix_cache: bool = False,
+                 unmask_rule: str = "static", unmask_steps: int = 2,
+                 unmask_threshold: float = 0.9):
+        if cfg.block_len:
+            # what a row that carries a block between forwards cannot do
+            # yet, each by the mechanism that is missing
+            for on, what, why in (
+                (draft_params is not None, "draft_params",
+                 "a block step already settles several positions a "
+                 "forward; a draft model's proposals have no place in it"),
+                (prefix_cache, "prefix_cache",
+                 "prefix K/V under the block mask depends on where the "
+                 "prompt's whole blocks end, and the tail prefill is "
+                 "causal and dense-only"),
+                (preempt, "preempt",
+                 "a preempted row resumes by prefilling prompt + output; "
+                 "that needs the resume to land on a block boundary and "
+                 "the block in flight (tokens, masks, forward indices) "
+                 "snapshotted"),
+                (residency is not None, "residency",
+                 "swap-out detaches a row's cursors and current token; a "
+                 "diffusion row's block in flight has no place in the "
+                 "bundle"),
+                (temperature > 0.0, "temperature > 0",
+                 "a sampled unmasking draws each settled token and keeps "
+                 "the confidence of the draw; only the greedy pick "
+                 "(argmax, its softmax probability) is written"),
+                (eos_id is not None, "eos_id",
+                 "an end token inside a block would have to cut the block "
+                 "and the row on the device; a row ends at its budget"),
+                (mesh is not None, "mesh",
+                 "the block step runs unsharded (a patterned model)"),
+            ):
+                if on:
+                    raise ValueError(
+                        f"{what} with a block-diffusion model (block_len "
+                        f"{cfg.block_len}): {why}")
+            if unmask_rule not in UNMASK_RULES:
+                raise ValueError(
+                    f"unmask_rule {unmask_rule!r} not in {UNMASK_RULES}")
+            if not 1 <= unmask_steps <= cfg.block_len:
+                raise ValueError(
+                    f"unmask_steps {unmask_steps} outside [1, block_len "
+                    f"{cfg.block_len}]")
+            if page_size % cfg.block_len or any(
+                    int(r) % cfg.block_len for r in prompt_buckets or ()):
+                raise ValueError(
+                    f"block_len {cfg.block_len} must divide page_size "
+                    f"{page_size} and every rung {prompt_buckets}: a "
+                    "block never straddles a page or a rung's end")
         if cfg.layer_pattern:
             # what a model with per-row recurrent state cannot do yet,
             # each by the mechanism that is missing
@@ -919,6 +1096,18 @@ class EngineCore:
         self.tokens = jnp.zeros((slots,), jnp.int32)
         self.keys = jnp.zeros((slots, 2), jnp.uint32)
         self.temps = jnp.ones((slots,), jnp.float32)
+        if cfg.block_len:
+            # a diffusion row's block between forwards (_block_chunk);
+            # ``pos`` is the block's start, ``chunk`` counts FORWARDS
+            B = cfg.block_len
+            self.unmask = dict(rule=unmask_rule, steps=int(unmask_steps),
+                               threshold=float(unmask_threshold))
+            self.blk = jnp.full((slots, B), cfg.mask_id, jnp.int32)
+            self.msk = jnp.ones((slots, B), bool)
+            self.fidx = jnp.full((slots, B), -1, jnp.int32)
+            self.nfw = jnp.zeros((slots,), jnp.int32)
+            self.dstats = jnp.zeros((len(DIFFUSION_STATS),), jnp.int32)
+            self._dstats_seen = None
         self._slots = [_Slot() for _ in range(slots)]
         self._pending: list[int] = []  # admitted, first token unread
         self._queue: list[Request] = []
@@ -1476,9 +1665,12 @@ class EngineCore:
                     tracelib.compile_watch("serving._prefill_one",
                                            _prefill_one,
                                            padded_len=padded):
+                # a diffusion row's prompt pass covers its WHOLE blocks
+                # (what lies behind them is padding to it)
+                Bk = self.cfg.block_len
                 logits, out = _prefill_one(
                     self.params, jnp.asarray(prompt)[None, :],
-                    jnp.int32(T - 1), one,
+                    jnp.int32((T // Bk * Bk if Bk else T) - 1), one,
                     cfg=self.cfg, page_size=self.page_size,
                     mesh=self.mesh,
                 )
@@ -1506,20 +1698,36 @@ class EngineCore:
             for k, v in dout.items():
                 if k != "table":
                     self.dcache[k] = v
-        key = req.key if req.key is not None else self.request_key(
-            req.seq_id)
-        temp = (req.temperature if req.temperature is not None
-                else self.temperature)
-        state = ({k: self.cache[k] for k in row_state}
-                 if row_state else None)
-        (self.pos, self.limit, self.tokens, self.keys, self.temps,
-         first_dev, state) = _admit_row(
-            self.pos, self.limit, self.tokens, self.keys, self.temps,
-            logits, key, jnp.float32(max(temp, 1e-6)), slot, T,
-            req.max_new, state, row_state or None, eos_id=self.eos_id,
-            greedy=self.greedy, top_k=self.top_k)
-        if state is not None:
-            self.cache.update(state)
+        first_dev = None
+        if self.cfg.block_len:
+            # no token comes out of the prompt pass: the row's first block
+            # is the prompt's remainder, then masks
+            Bk = self.cfg.block_len
+            start = T // Bk * Bk
+            first = np.full((Bk,), self.cfg.mask_id, np.int32)
+            first[:T - start] = req.prompt[start:]
+            (self.pos, self.limit, self.blk, self.msk, self.fidx,
+             self.nfw) = _admit_block_row(
+                self.pos, self.limit, self.blk, self.msk, self.fidx,
+                self.nfw, slot, start, T + req.max_new, jnp.asarray(first),
+                T - start)
+            self._slots[slot].cursor = start
+            self.stats[req.seq_id]["blocks"] = []
+        else:
+            key = req.key if req.key is not None else self.request_key(
+                req.seq_id)
+            temp = (req.temperature if req.temperature is not None
+                    else self.temperature)
+            state = ({k: self.cache[k] for k in row_state}
+                     if row_state else None)
+            (self.pos, self.limit, self.tokens, self.keys, self.temps,
+             first_dev, state) = _admit_row(
+                self.pos, self.limit, self.tokens, self.keys, self.temps,
+                logits, key, jnp.float32(max(temp, 1e-6)), slot, T,
+                req.max_new, state, row_state or None, eos_id=self.eos_id,
+                greedy=self.greedy, top_k=self.top_k)
+            if state is not None:
+                self.cache.update(state)
         st = self._slots[slot]
         st.seq_id, st.pages, st.prompt_len = req.seq_id, pages, T
         st.budget = req.max_new
@@ -1548,7 +1756,8 @@ class EngineCore:
                                 "padded_len": padded,
                                 "overlapped": overlapped},
                 track=slot + 1)
-        self._pending.append(slot)
+        if first_dev is not None:
+            self._pending.append(slot)
         self._emit(kind="serve_admit", seq_id=req.seq_id, slot=slot,
                    pages=need, prompt_len=T, padded_len=padded,
                    budget=req.max_new, overlapped=overlapped,
@@ -1925,6 +2134,125 @@ class EngineCore:
             if pos_start[i] + valid >= limit_new[i]:
                 self._finish(i)
 
+    def _dispatch_block(self):
+        """:meth:`_dispatch_chunk` for a block-diffusion model: ``chunk``
+        block FORWARDS of :func:`_block_chunk` in one dispatch. The live
+        rows' block starts are the host's own mirror (``_Slot.cursor``):
+        nothing is read back here."""
+        parts = [i for i, s in enumerate(self._slots) if s.active]
+        # the stored positions the live rows attend over beside their own
+        # blocks
+        ctx = sum(self._slots[i].cursor for i in parts)
+        with metricslib.span("serve.decode_dispatch", chunk=self.chunk,
+                             forwards=self.chunk, rows=len(parts),
+                             block=self.cfg.block_len, round=self._round,
+                             ctx_tokens=ctx), \
+                tracelib.compile_watch("serving._block_chunk",
+                                       _block_chunk, forwards=self.chunk):
+            (self.cache, self.pos, self.blk, self.msk, self.fidx, self.nfw,
+             self.dstats, out) = _block_chunk(
+                self.params, self.cache, self.pos, self.limit, self.blk,
+                self.msk, self.fidx, self.nfw, self.dstats, cfg=self.cfg,
+                forwards=self.chunk, **self.unmask)
+        rec = tracelib.active()
+        t_disp = (rec.mark_dispatch(
+            "serve.chunk", {"chunk": self.chunk, "rows": len(parts)})
+            if rec is not None else 0.0)
+        return parts, ctx, out, t_disp
+
+    def _collect_block(self, inflight):
+        """:meth:`_collect_chunk` for a block-diffusion model: every block
+        a row committed in the chunk hands out up to B tokens at once (its
+        given positions and those at or past the row's limit are cut), all
+        with this readback's one availability instant; the readback that
+        brings a request's first tokens is its ``serve.first_token``. The
+        whole blocks and the forward that settled each position are kept
+        as ``stats[seq_id]["blocks"]`` ((2, B) arrays: what a replay of
+        the (block, forward) states needs)."""
+        parts, ctx, out, t_disp = inflight
+        with metricslib.span("serve.decode_round", chunk=self.chunk):
+            toks, fidx, commit = (np.asarray(a) for a in out)   # the sync
+        rec = tracelib.active()
+        if rec is not None and t_disp:
+            rec.mark_complete("serve.chunk", t_disp,
+                              {"chunk": self.chunk, "rows": len(parts)})
+        if metricslib.get_metrics().enabled:
+            self._count_route()
+            self._count_diffusion()
+        B = self.cfg.block_len
+        now = time.perf_counter()
+        for i in parts:
+            st = self._slots[i]
+            if not st.active:
+                continue
+            end = st.prompt_len + st.budget
+            rec_s = self.stats.get(st.seq_id)
+            new = 0
+            for f in np.nonzero(commit[:, i])[0]:
+                lo = max(0, st.prompt_len - st.cursor)
+                hi = min(B, end - st.cursor)
+                st.out.extend(int(t) for t in toks[f, i, lo:hi])
+                new += hi - lo
+                st.cursor += B
+                if rec_s is not None:
+                    rec_s["blocks"].append(
+                        np.stack([toks[f, i], fidx[f, i]]))
+            if new and len(st.out) == new:
+                self._first_tokens(i, now)
+            if rec_s is not None and new:
+                rec_s.setdefault("token_ts", []).extend([now] * new)
+            if st.cursor >= end:
+                self._finish(i)
+        self._emit(kind="serve_block_chunk", round=self._round,
+                   rows=len(parts), ctx_tokens=ctx, forwards=self.chunk,
+                   blocks=int(commit[:, parts].sum()))
+
+    def _first_tokens(self, slot: int, now: float) -> None:
+        """A diffusion request's first tokens reached the host (none comes
+        out of its prompt pass): what :meth:`_resolve_pending` records for
+        a first token, at the chunk readback that brought them."""
+        st = self._slots[slot]
+        with metricslib.span("serve.first_token", seq_id=st.seq_id,
+                             slot=slot):
+            pass   # an instant: the chunk's readback was the wait
+        rec = tracelib.active()
+        if rec is not None and st.t_dispatch:
+            rec.mark_complete("serve.admit", st.t_dispatch,
+                              {"seq_id": st.seq_id, "slot": slot},
+                              track=slot + 1)
+            st.t_dispatch = 0.0
+        rec_s = self.stats.get(st.seq_id)
+        if rec_s is not None and rec_s["t_first"] is None:
+            rec_s["t_first"] = now
+        rtr = reqtracelib.active()
+        if rtr is not None:
+            rtr.stamp_transition(st.seq_id, "decode", now)
+        m = metricslib.get_metrics()
+        if m.enabled:
+            m.histogram("serve.ttft_s").observe(now - (st.t_submit or now))
+
+    def diffusion_stats(self):
+        """A block engine's running sums read to the host now
+        (``DIFFUSION_STATS``: forwards run by live rows, blocks committed,
+        tokens handed out), None for a model that decodes a token a step.
+        int32 that wrap: take differences."""
+        return (np.asarray(self.dstats) if self.cfg.block_len else None)
+
+    def _count_diffusion(self) -> None:
+        """The engine's diffusion counters (docs/observability.md), from
+        what the sums grew by since the last look; as
+        :meth:`_count_route`, after a chunk's readback."""
+        new = self.diffusion_stats()
+        old, self._dstats_seen = self._dstats_seen, new
+        forwards, blocks, tokens = (
+            int(v) for v in new - (0 if old is None else old))
+        mx = metricslib.get_metrics()
+        mx.counter("diffusion.forwards").inc(forwards)
+        mx.counter("diffusion.blocks").inc(blocks)
+        mx.counter("diffusion.tokens").inc(tokens)
+        if forwards:
+            mx.gauge("diffusion.tokens_per_forward").set(tokens / forwards)
+
     def route_stats(self):
         """The expert route's running sums read to the host now (rows:
         prefills, decode steps; columns: ``parallel/moe.ROUTE_STATS``),
@@ -2073,6 +2401,8 @@ class EngineCore:
         spec = self.draft_params is not None
         dispatch = self._dispatch_spec if spec else self._dispatch_chunk
         collect = self._collect_spec if spec else self._collect_chunk
+        if self.cfg.block_len:   # a block a row a step, not a token
+            dispatch, collect = self._dispatch_block, self._collect_block
         inflight = None
         t_chunk0 = 0.0
         if decode and self.overlap and any(s.active for s in self._slots):
@@ -2179,6 +2509,12 @@ class EngineCore:
                 if s.active and i not in self._pending]
 
     def _no_state_migration(self) -> None:
+        if self.cfg.block_len:
+            raise ValueError(
+                "migration with a block-diffusion model (block_len "
+                f"{self.cfg.block_len}): a MigrationBundle carries a row's "
+                "pages, cursor and current token; the block in flight "
+                "(tokens, masks, forward indices) has no place in it yet")
         if self.state_bytes:
             raise ValueError(
                 "migration with a patterned model "

@@ -57,7 +57,7 @@ class LayerKind(NamedTuple):
     """What a layer of one kind holds: K/V (a pool of pages in the
     cache), a row of recurrent state (convolution tail, S), and which
     MLP closes it ("" none, "gelu" the default block's, "gated" the
-    SiLU-gated one)."""
+    SiLU-gated one, "routed" a softmax top-k of SiLU-gated experts)."""
     kv: bool
     state: bool
     mlp: str
@@ -73,6 +73,7 @@ LAYER_KINDS = {
     "M": LayerKind(kv=False, state=True, mlp=""),
     "E": LayerKind(kv=False, state=False, mlp=""),
     "H": LayerKind(kv=True, state=True, mlp="gated"),
+    "R": LayerKind(kv=True, state=False, mlp="routed"),
 }
 
 
@@ -196,15 +197,31 @@ class TransformerConfig:
     # experts (parallel/moe.latent_moe). "H" is the parallel hybrid
     # block: attention AND the Mamba-2 mixer off one norm, summed into
     # the residual, then a second norm and a SiLU-gated MLP of width
-    # ``d_ff``. A patterned model's ``params["layers"]`` is a tuple of
-    # per-layer dicts, its layer loop is unrolled, and it runs unsharded
-    # (mesh=None)
+    # ``d_ff``. "R" is attention, then a second norm and ROUTED experts: a
+    # softmax over ``moe_experts``, the ``moe_top_k`` largest (their gates
+    # renormalised to one where ``moe_renorm``), each expert a SiLU-gated
+    # MLP of width ``moe_d_ff`` (parallel/moe.gated_moe), on this chip's
+    # share ``moe_held_start .. + moe_held`` of them. A patterned model's
+    # ``params["layers"]`` is a tuple of per-layer dicts, its layer loop is
+    # unrolled, and it runs unsharded (mesh=None)
     layer_pattern: str = ""
     norm_eps: float = 1e-6
     # the attention head size where heads x size is not d_model (0 =
     # d_model // n_heads): wqkv is (d_model, (n_heads + 2 kv_heads) x
     # size), wo (n_heads x size, d_model)
     attn_head_dim: int = 0
+    # RMSNorm over each head's ``head_dim`` of q and of k, with a learned
+    # scale (``q_norm`` / ``k_norm``), before the rotation
+    qk_norm: bool = False
+    # generation by diffusion over blocks (0 = one token a row a step):
+    # position i sees j iff j // block_len <= i // block_len (the whole
+    # blocks before it and ALL of its own), the logits at a position
+    # predict that position, and a block starts as ``mask_id`` wherever no
+    # token is given (decode.paged_block_step, serving._block_chunk). An
+    # all-"R" pattern; ``block_len`` a power of two up to 128, so that it
+    # divides the attention kernels' tiles
+    block_len: int = 0
+    mask_id: int = -1
     # an all-"H" model's scalar multipliers, under their published names,
     # applied at use on activations (1 = no operation is emitted): on the
     # embedding; on the attention's input, its keys, its output; on the
@@ -242,6 +259,8 @@ class TransformerConfig:
     moe_d_ff: int = 0
     moe_shared_d_ff: int = 0
     moe_scale: float = 1.0
+    # "R": the chosen gates sum to one (True) or stay the softmax's
+    moe_renorm: bool = True
     # mesh axis names (data / sequence(context) / tensor / expert)
     axis_dp: str = "dp"
     axis_sp: str = "sp"
@@ -284,6 +303,11 @@ class TransformerConfig:
         return sum(LAYER_KINDS[c].state for c in self.pattern)
 
     @property
+    def n_routed_layers(self) -> int:
+        """Layers with a route over experts: the cache carries their sums."""
+        return sum(c in "ER" for c in self.pattern)
+
+    @property
     def experts_held(self) -> int:
         return self.moe_held or self.moe_experts
 
@@ -318,9 +342,9 @@ class TransformerConfig:
             )
         pat = self.layer_pattern
         if pat:
-            if len(pat) != self.n_layers or set(pat) - set("*MEH"):
+            if len(pat) != self.n_layers or set(pat) - set("*MEHR"):
                 raise ValueError(
-                    f"layer_pattern {pat!r}: one of '*MEH' for each of "
+                    f"layer_pattern {pat!r}: one of '*MEHR' for each of "
                     f"the {self.n_layers} layers")
             if self.n_state_layers and not (
                     self.ssm_heads > 0
@@ -339,6 +363,36 @@ class TransformerConfig:
                     "an 'E' layer needs moe_experts >= moe_top_k > 0, a "
                     "held range inside the experts, and moe_latent, "
                     "moe_d_ff, moe_shared_d_ff > 0")
+            if "R" in pat and not (
+                    0 < self.moe_top_k <= self.moe_experts
+                    and self.moe_held_start + self.experts_held
+                    <= self.moe_experts and self.moe_d_ff > 0):
+                raise ValueError(
+                    "an 'R' layer needs moe_experts >= moe_top_k > 0, a "
+                    "held range inside the experts and moe_d_ff > 0")
+        if self.block_len:
+            B = self.block_len
+            if B < 2 or B > 128 or B & (B - 1):
+                raise ValueError(
+                    f"block_len {B}: a power of two from 2 to 128 (it has "
+                    "to divide the attention kernels' tiles, every rung "
+                    "and the page size)")
+            if set(pat) != {"R"}:
+                raise ValueError(
+                    "block_len > 0 (generation by diffusion over blocks) "
+                    f"needs a layer_pattern of 'R' alone, not {pat!r}: "
+                    "the other kinds have no multi-position step, and a "
+                    "recurrence cannot see the rest of its own block")
+            if not 0 <= self.mask_id < self.vocab:
+                raise ValueError(
+                    f"mask_id {self.mask_id} outside the vocabulary "
+                    f"[0, {self.vocab})")
+            if self.attention not in ("full", "flash"):
+                raise ValueError(
+                    "the block mask is written for attention 'full' and "
+                    f"'flash', not {self.attention!r}")
+        elif self.mask_id != -1:
+            raise ValueError("mask_id is read with block_len > 0 only")
         if (len(self.ssm_multipliers), len(self.mlp_multipliers)) != (5, 2):
             raise ValueError(
                 "ssm_multipliers has five values ([z | x | B | C | dt]) "
@@ -461,6 +515,9 @@ def _init_patterned(key, cfg: TransformerConfig):
             lp["wqkv"] = n((D, cfg.attn_width
                             + 2 * cfg.kv_heads * cfg.head_dim), D ** -0.5)
             lp["wo"] = n((cfg.attn_width, D), (2 * D * L) ** -0.5)
+            if cfg.qk_norm:
+                lp["q_norm"] = jnp.ones((cfg.head_dim,), jnp.float32)
+                lp["k_norm"] = jnp.ones((cfg.head_dim,), jnp.float32)
         if holds.state:
             d, H = ssm_dims(cfg), cfg.ssm_heads
             lp["in_proj"] = n((D, d["proj"]), D ** -0.5)
@@ -483,6 +540,13 @@ def _init_patterned(key, cfg: TransformerConfig):
             lp["w_gate"] = n((D, F), D ** -0.5)
             lp["w_up"] = n((D, F), D ** -0.5)
             lp["w_down"] = n((F, D), (2 * F * L) ** -0.5)
+        if holds.mlp == "routed":
+            E, held, F = cfg.moe_experts, cfg.experts_held, cfg.moe_d_ff
+            lp["ln2_scale"] = jnp.ones((D,), jnp.float32)
+            lp["router"] = n((D, E), D ** -0.5)
+            lp["w_gate"] = n((held, D, F), D ** -0.5)
+            lp["w_up"] = n((held, D, F), D ** -0.5)
+            lp["w_down"] = n((held, F, D), (2 * F * L) ** -0.5)
         if kind == "E":
             E, held = cfg.moe_experts, cfg.experts_held
             R, F, Fs = cfg.moe_latent, cfg.moe_d_ff, cfg.moe_shared_d_ff
@@ -604,8 +668,8 @@ def matmul_weight(tree, name, dt):
 
 
 #: leaves whose use sites compute in float32 whatever ``cfg.dtype`` is:
-#: the MoE router (parallel/moe._route, sigmoid_route) and its selection
-#: bias, the Mamba-2 mixer's decay, skip and step bias (models/ssm.py);
+#: the MoE router (parallel/moe._route, sigmoid_route, softmax_route) and
+#: its selection bias, the Mamba-2 mixer's decay, skip and step bias (models/ssm.py);
 #: the ``*_qscale`` siblings (:func:`matmul_weight`) are matched by suffix
 _FLOAT32_AT_USE = ("router", "router_bias", "A_log", "D", "dt_bias")
 
@@ -723,17 +787,27 @@ def project_qkv(h, lp, cfg: TransformerConfig):
     h = scaled(h, cfg.attention_in_multiplier)
     qkv = jnp.dot(h, matmul_weight(lp, "wqkv", dt))  # column-parallel
     q, k, v = jnp.split(qkv, [H * Dh, (H + Hkv) * Dh], axis=-1)
-    return (
-        q.reshape(*lead, H, Dh),
-        scaled(k, cfg.key_multiplier).reshape(*lead, Hkv, Dh),
-        v.reshape(*lead, Hkv, Dh),
-    )
+    q = q.reshape(*lead, H, Dh)
+    k = scaled(k, cfg.key_multiplier).reshape(*lead, Hkv, Dh)
+    if cfg.qk_norm:   # over each head's Dh, before the rotation
+        with jax.named_scope("qk_norm"):
+            q = _rmsnorm(q, lp["q_norm"], cfg.norm_eps)
+            k = _rmsnorm(k, lp["k_norm"], cfg.norm_eps)
+    return q, k, v.reshape(*lead, Hkv, Dh)
 
 
 def _attention(q, k, v, cfg: TransformerConfig, mesh):
     """Dispatch to the configured attention impl. ring/ulysses wrap the
     rank-local kernels in ``shard_map`` over (dp, sp, tp) — sequence
-    travels the ``sp`` ring while heads stay tensor-sharded."""
+    travels the ``sp`` ring while heads stay tensor-sharded. A model with
+    ``cfg.block_len`` runs unsharded under the block mask."""
+    if cfg.block_len:
+        if cfg.attention == "flash":
+            from hpc_patterns_tpu.ops import flash_attention
+
+            return flash_attention(q, k, v, mask_block=cfg.block_len)
+        return full_attention(q, k, v, causal=True,
+                              mask_block=cfg.block_len)
     if cfg.attention == "flash":
         from hpc_patterns_tpu.ops import flash_attention
 
@@ -1084,6 +1158,26 @@ def moe_mixer(x, lp, cfg: TransformerConfig, valid=None):
     return x + out.reshape(x.shape).astype(dt), stats
 
 
+@scoped("moe")
+def routed_mlp(x, lp, cfg: TransformerConfig, valid=None):
+    """An "R" layer's close, x (..., D): the routed SiLU-gated experts held
+    here under their own pre-norm residual. ``valid`` (...,) bool: tokens
+    that count (the others pick no expert). Returns (x, the route's stats,
+    parallel/moe.ROUTE_STATS)."""
+    from hpc_patterns_tpu.parallel import moe
+
+    dt = x.dtype
+    D = x.shape[-1]
+    h = _rmsnorm(x, lp["ln2_scale"], cfg.norm_eps).reshape(-1, D)
+    w = lambda name: matmul_weight(lp, name, dt)
+    out, stats = moe.gated_moe(
+        h, lp["router"], w("w_gate"), w("w_up"), w("w_down"),
+        held_start=cfg.moe_held_start, top_k=cfg.moe_top_k,
+        renorm=cfg.moe_renorm,
+        valid=None if valid is None else valid.reshape(-1))
+    return x + out.reshape(x.shape).astype(dt), stats
+
+
 def _layer(x, lp, cfg: TransformerConfig, mesh, act_spec,
            split_remat: bool = False):
     """One pre-norm block: attn + mlp/moe, Megatron-sharded (wqkv/w1
@@ -1222,10 +1316,12 @@ def forward_hidden(params, tokens, cfg: TransformerConfig, mesh=None):
                 "a patterned model runs unsharded (mesh=None): the state "
                 "and expert layers carry no sharding rules yet")
         for kind, lp in zip(cfg.layer_pattern, params["layers"]):
-            if kind == "*":
+            if kind in "*R":   # "R": attention, then the routed experts
                 q, k, v = _qkv_block(x, lp, cfg, None)
                 x = x + attn_proj(_attention(q, k, v, cfg, None), lp, cfg,
                                   dt)
+                if kind == "R":
+                    x, _ = routed_mlp(x, lp, cfg)
             elif kind == "M":
                 x, _ = ssm_mixer(x, lp, cfg)
             elif kind == "E":

@@ -61,14 +61,20 @@ _NEG_INF = -1e30
 # default sequential walk is the fast path; do not "optimize" this.
 
 
-def _causal_mask(s, q_start, k_start):
+def _causal_mask(s, q_start, k_start, mask_block: int = 1):
     """Mask score block ``s`` so position (i, j) survives iff the global
     key index k_start+j is at or before the global query index q_start+i.
     Shared by the forward and both backward kernels — the mask must be
     identical or the recomputed P diverges from the forward's. Offsets
-    may be traced (dynamic) values."""
+    may be traced (dynamic) values. ``mask_block`` > 1 (the forward
+    alone): the BLOCK mask, a key survives up to the last position of the
+    query's block of that many positions. It divides the tiles and the
+    offsets, so a tile's last query is its block's last and the tiles
+    that are skipped and elided stay the causal ones."""
     q_pos = q_start + lax.broadcasted_iota(jnp.int32, s.shape, 0)
     k_pos = k_start + lax.broadcasted_iota(jnp.int32, s.shape, 1)
+    if mask_block > 1:
+        q_pos = q_pos // mask_block * mask_block + (mask_block - 1)
     return jnp.where(k_pos <= q_pos, s, _NEG_INF)
 
 
@@ -125,7 +131,7 @@ def _q_index_map(block_q, block_k, causal, n_q, H, Hkv):
 
 
 def _kernel(offs_ref, q_ref, k_ref, v_ref, o_ref, *rest, scale: float,
-            causal: bool, with_lse: bool):
+            causal: bool, with_lse: bool, mask_block: int = 1):
     # grid (B·H, n_q, n_kv): K/V stream through VMEM one block per grid
     # step (no whole-sequence residency — T is bounded by HBM, not VMEM);
     # the online-softmax state (m, l, acc) carries across the kv axis in
@@ -162,7 +168,7 @@ def _kernel(offs_ref, q_ref, k_ref, v_ref, o_ref, *rest, scale: float,
         v = v_ref[:]
         s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
         if causal:
-            s = _causal_mask(s, q_start_g, k_start_g)
+            s = _causal_mask(s, q_start_g, k_start_g, mask_block)
         m = m_ref[:]
         m_new = jnp.maximum(m, s.max(axis=-1, keepdims=True))
         p = jnp.exp(s - m_new)
@@ -425,7 +431,7 @@ def _sds(shape, dtype, vma):
     return jax.ShapeDtypeStruct(shape, dtype, vma=vma)
 
 
-def _masked_scores(qr, kr, offs, scale, causal):
+def _masked_scores(qr, kr, offs, scale, causal, mask_block: int = 1):
     """(N, Tq, Tk) scaled scores with the global causal mask — the dense
     mirror of the kernels' per-block ``_causal_mask`` walk."""
     s = jnp.einsum(
@@ -434,11 +440,14 @@ def _masked_scores(qr, kr, offs, scale, causal):
     if causal:
         q_pos = offs[0] + lax.broadcasted_iota(jnp.int32, s.shape, 1)
         k_pos = offs[1] + lax.broadcasted_iota(jnp.int32, s.shape, 2)
+        if mask_block > 1:
+            q_pos = q_pos // mask_block * mask_block + (mask_block - 1)
         s = jnp.where(k_pos <= q_pos, s, _NEG_INF)
     return s
 
 
-def _dense_forward(qr, kr, vr, offs, *, causal, scale, need_lse, out_dtype):
+def _dense_forward(qr, kr, vr, offs, *, causal, scale, need_lse, out_dtype,
+                   mask_block: int = 1):
     """jnp mirror of ``_kernel`` (same clamps and dead-row semantics),
     used where Pallas interpret mode can't run — inside ``shard_map`` on
     CPU (its vma tracking rejects kernel-internal constants). Real-TPU
@@ -446,7 +455,7 @@ def _dense_forward(qr, kr, vr, offs, *, causal, scale, need_lse, out_dtype):
     exactly for f32 inputs; for bf16 inputs the kernel's native-dtype
     matmuls round p to bf16 where this mirror keeps f32 — equal only to
     bf16 precision."""
-    s = _masked_scores(qr, kr, offs, scale, causal)
+    s = _masked_scores(qr, kr, offs, scale, causal, mask_block)
     m = s.max(-1, keepdims=True)
     p = jnp.exp(s - m) * (s > _NEG_INF / 2)  # fully-masked rows stay 0
     l = jnp.maximum(p.sum(-1, keepdims=True), 1e-30)
@@ -474,11 +483,12 @@ def _dense_backward(qr, kr, vr, dor, lse, delta, offs, *, causal, scale):
 
 
 def _forward_impl(q, k, v, offs, *, causal, scale, block_q, block_k,
-                  interpret, need_lse):
+                  interpret, need_lse, mask_block: int = 1):
     """Shared forward. ``offs``: (1, 2) int32 [q_offset, k_offset].
     Returns (out, residuals) — residuals in kernel layout (B·H, T, D),
     lse (B·H, Tq, 1) f32; both None-lse when ``need_lse`` is False (the
-    inference path skips the lse work entirely)."""
+    inference path skips the lse work entirely). ``mask_block`` > 1: the
+    block mask (:func:`_causal_mask`), offsets 0."""
     if q.ndim != 4:
         raise ValueError(f"want (batch, seq, heads, head_dim), got {q.shape}")
     B, Tq, H, D = q.shape
@@ -498,6 +508,7 @@ def _forward_impl(q, k, v, offs, *, causal, scale, block_q, block_k,
 
     kernel = functools.partial(
         _kernel, scale=scale, causal=causal, with_lse=need_lse,
+        **({"mask_block": mask_block} if mask_block > 1 else {}),
     )
     # index maps see the prefetched offsets: for causal, clamp the kv
     # block index to the last visible block — consecutive clamped steps
@@ -514,7 +525,7 @@ def _forward_impl(q, k, v, offs, *, causal, scale, block_q, block_k,
         vr_e = _expand_rows(vr, B, Hkv, group)
         outr, lse = _dense_forward(qr, kr_e, vr_e, offs, causal=causal,
                                    scale=scale, need_lse=need_lse,
-                                   out_dtype=q.dtype)
+                                   out_dtype=q.dtype, mask_block=mask_block)
         out = outr.reshape(B, H, Tq, D).transpose(0, 2, 1, 3)
         return out, (qr, kr, vr, outr, lse)
     out_specs = [blk_q]
@@ -827,6 +838,7 @@ def flash_attention(
     bwd: str | None = None,
     block_q_bwd: int | None = None,
     block_k_bwd: int | None = None,
+    mask_block: int = 1,
 ):
     """Softmax attention over (batch, seq, heads, head_dim) inputs.
 
@@ -837,7 +849,21 @@ def flash_attention(
     backward is two blockwise Pallas kernels (dQ pass, dK/dV pass)
     recomputing P from the forward's saved logsumexp — O(block) VMEM in
     both directions.
+
+    ``mask_block`` > 1 (a power of two up to 128, so that it divides the
+    tiles): the BLOCK mask in place of the causal one, position i sees j
+    iff ``j // mask_block <= i // mask_block``: the whole blocks before
+    it and all of its own. Forward only (no VJP is defined for it).
     """
+    if mask_block > 1:
+        if not causal or mask_block > 128 or mask_block & (mask_block - 1):
+            raise ValueError(
+                f"mask_block {mask_block}: a power of two up to 128, with "
+                "causal=True (it widens the causal mask to whole blocks)")
+        return _forward_impl(q, k, v, _zero_offs(), causal=True, scale=scale,
+                             block_q=block_q, block_k=block_k,
+                             interpret=interpret, need_lse=False,
+                             mask_block=mask_block)[0]
     return _flash_with_vjp(q, k, v, causal, scale, block_q, block_k,
                            interpret, bwd, block_q_bwd, block_k_bwd)
 

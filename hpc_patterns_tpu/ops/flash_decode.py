@@ -389,3 +389,39 @@ def flash_decode_paged(
         interpret=interpret,
     )(*operands)
     return out.reshape(B, H, D)
+
+
+def fold_block(q, kv_heads: int):
+    """q (B, c, n_heads, D) -> (B, kv_heads c g, D): a block's c positions
+    folded into the group of each K/V head (q head k g + j of position i
+    -> row k, group entry i g + j), for kernels that take one query a row
+    and a group of them a K/V head."""
+    B, c, H, D = q.shape
+    g = H // kv_heads
+    return jnp.einsum("bikjd->bkijd", q.reshape(B, c, kv_heads, g, D)
+                      ).reshape(B, kv_heads * c * g, D)
+
+
+def unfold_block(o, c: int, kv_heads: int):
+    """:func:`fold_block`'s inverse on an attention output."""
+    B, rows, D = o.shape
+    g = rows // (kv_heads * c)
+    return jnp.einsum("bkijd->bikjd", o.reshape(B, kv_heads, c, g, D)
+                      ).reshape(B, c, kv_heads * g, D)
+
+
+def flash_decode_paged_block(q, k_pool, v_pool, table, pos, **kw):
+    """A BLOCK of query positions a sequence against the paged cache, all
+    of them seeing the same keys: ``q`` (B, c, n_heads, head_dim), the
+    block at positions ``pos .. pos + c - 1`` (``pos`` (B,) int32), whose
+    own K/V rows are already in the pool; every query attends over keys
+    ``0 .. pos + c - 1`` (generation by diffusion over blocks: a position
+    sees all of its own block). The c positions fold into the kernel's
+    group dimension, c x n_heads / kv_heads query rows over each K/V
+    head, so this is ONE :func:`flash_decode_paged` call, which streams
+    the keys once for all of them. Returns (B, c, n_heads, head_dim)
+    float32; the other arguments are :func:`flash_decode_paged`'s."""
+    c, Hkv = q.shape[1], k_pool.shape[1]
+    o = flash_decode_paged(fold_block(q, Hkv), k_pool, v_pool, table,
+                           pos + (c - 1), **kw)
+    return unfold_block(o, c, Hkv)

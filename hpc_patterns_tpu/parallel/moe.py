@@ -249,6 +249,15 @@ def relu2(x):
     return jnp.square(jax.nn.relu(x))
 
 
+def silu(x):
+    """x sigmoid(x), the gate's activation of the gated experts: computed
+    in float32 and rounded once to ``x``'s dtype (inside the grouped
+    product's kernel the chip's compiler refuses ``jax.nn.silu`` on
+    bfloat16: its logistic mixes a float32 constant in)."""
+    x32 = x.astype(jnp.float32)
+    return (x32 / (1.0 + jnp.exp(-x32))).astype(x.dtype)
+
+
 def sigmoid_route(h, router_w, router_b, *, top_k: int, scale: float):
     """Sigmoid scores over ALL experts, float32: the ``top_k`` chosen are
     the top of ``score + router_b`` (a selection bias: it picks, it does
@@ -262,6 +271,20 @@ def sigmoid_route(h, router_w, router_b, *, top_k: int, scale: float):
     return idx.astype(jnp.int32), scale * g / jnp.sum(g, -1, keepdims=True)
 
 
+def softmax_route(h, router_w, *, top_k: int, renorm: bool = True):
+    """A softmax over ALL experts, float32: the ``top_k`` largest
+    probabilities are chosen (ties by index, as ``lax.top_k``), the gates
+    those probabilities, divided by their sum where ``renorm``.
+    h (N, D) -> (idx (N, k) int32, gates (N, k) float32)."""
+    p = jax.nn.softmax(jnp.dot(h.astype(jnp.float32),
+                               router_w.astype(jnp.float32),
+                               precision=jax.lax.Precision.HIGHEST), axis=-1)
+    g, idx = jax.lax.top_k(p, top_k)
+    if renorm:
+        g = g / jnp.sum(g, -1, keepdims=True)
+    return idx.astype(jnp.int32), g
+
+
 #: what :func:`held_experts` reports beside its result, one int32 each:
 #: picks computed here, tokens routed, the fullest held expert's picks,
 #: held experts with at least one pick, and 1 (a call). Whole numbers, so
@@ -272,12 +295,16 @@ ROUTE_STATS = ("picks", "tokens", "max_load", "touched", "calls")
 
 
 def held_experts(x, idx, gates, w1, w2, *, held_start: int = 0,
-                 activation=relu2, valid=None):
+                 activation=relu2, valid=None, w_gate=None):
     """The part of a routed layer that the experts held here give.
 
     x (N, d) tokens, idx / gates (N, k) from a route over all experts, w1
     (held, d, f) and w2 (held, f, d): experts ``held_start ..
-    held_start + held`` of the layer. Only the picks whose expert lies in
+    held_start + held`` of the layer, each ``w2 activation(w1 x)``. With
+    ``w_gate`` (held, d, f) the experts are GATED, three stacks each:
+    ``w2 (activation(w_gate x) * (w1 x))``, the two first products over
+    the same sorted rows and their product taken between them and the
+    last. Only the picks whose expert lies in
     that range are computed: the picks sort by held expert (a stable
     sort; the others sort behind every group) and two grouped products
     (ops/grouped_matmul) run over the groups, so the cost follows the
@@ -295,8 +322,13 @@ def held_experts(x, idx, gates, w1, w2, *, held_start: int = 0,
     order = jnp.argsort(e_flat, stable=True)
     sizes = jnp.zeros((held + 1,), jnp.int32).at[e_flat].add(1)[:held]
     rows = x[order // k]                                  # (N k, d)
-    mid = grouped_matmul(rows, w1.astype(x.dtype), sizes,
-                         activation=activation)
+    if w_gate is None:
+        mid = grouped_matmul(rows, w1.astype(x.dtype), sizes,
+                             activation=activation)
+    else:   # the rows behind every group stay unwritten through all three
+        mid = (grouped_matmul(rows, w_gate.astype(x.dtype), sizes,
+                              activation=activation)
+               * grouped_matmul(rows, w1.astype(x.dtype), sizes))
     out = grouped_matmul(mid, w2.astype(x.dtype), sizes,
                          preferred_element_type=jnp.float32)
     # back to (token, choice) order: a gather, then the gated sum. The
@@ -340,3 +372,22 @@ def latent_moe(h, router_w, router_b, w_down, w_up, w1, w2, ws1, ws2, *,
     with jax.named_scope("shared"):
         shared = jnp.dot(relu2(jnp.dot(h, ws1.astype(dt))), ws2.astype(dt))
     return routed + shared, stats
+
+
+def gated_moe(h, router_w, w_gate, w_up, w_down, *, held_start: int,
+              top_k: int, renorm: bool = True, valid=None):
+    """A layer of softmax-routed SiLU-gated experts on its share of them:
+    h (N, D) -> (out (N, D) float32, stats).
+
+        idx, g = softmax_route(h)               over all experts
+        out = sum_k g_k Wd_k (silu(Wg_k h) * (Wu_k h))      the held picks
+
+    No shared expert and no latent width: the experts read the hidden
+    state. On one chip the layer runs without its exchange: what the
+    experts held elsewhere would add is not here."""
+    with jax.named_scope("route"):
+        idx, gates = softmax_route(h, router_w, top_k=top_k, renorm=renorm)
+    with jax.named_scope("experts"):
+        return held_experts(h, idx, gates, w_up, w_down,
+                            held_start=held_start, activation=silu,
+                            valid=valid, w_gate=w_gate)

@@ -230,11 +230,14 @@ def _ring_attention_flash(q, k, v, axis, *, size, me, q_offset, causal,
     return out.astype(q.dtype)
 
 
-def full_attention(q, k, v, *, causal: bool = False, scale: float | None = None):
+def full_attention(q, k, v, *, causal: bool = False, scale: float | None = None,
+                   mask_block: int = 1):
     """Single-device oracle: plain softmax attention over the full
     sequence, used by tests to validate the ring result (§4.2 style).
     K/V may be GQA-narrow (kv_heads dividing q's heads) — grouped-query
-    scores, never an expanded K/V copy."""
+    scores, never an expanded K/V copy. ``mask_block`` > 1 (with
+    ``causal``): the BLOCK mask, position i sees j iff
+    j // mask_block <= i // mask_block."""
     _check_gqa(q, k, v)
     B, T, H, D = q.shape
     if scale is None:
@@ -243,6 +246,8 @@ def full_attention(q, k, v, *, causal: bool = False, scale: float | None = None)
     if causal:
         t_idx = lax.broadcasted_iota(jnp.int32, s.shape, 2)
         s_idx = lax.broadcasted_iota(jnp.int32, s.shape, 3)
+        if mask_block > 1:   # the last position of the query's block
+            t_idx = t_idx // mask_block * mask_block + (mask_block - 1)
         s = jnp.where(s_idx <= t_idx, s, _NEG_INF)
     p = jax.nn.softmax(s, axis=-1)
     out = _grouped_pv(p, v)

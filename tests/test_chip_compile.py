@@ -18,10 +18,11 @@ from jax.sharding import SingleDeviceSharding
 
 from hpc_patterns_tpu.models.decode import _pool_write
 from hpc_patterns_tpu.ops.flash_attention import flash_attention
-from hpc_patterns_tpu.ops.flash_decode import flash_decode_paged
+from hpc_patterns_tpu.ops.flash_decode import (flash_decode_paged,
+                                               flash_decode_paged_block)
 from hpc_patterns_tpu.ops.grouped_matmul import grouped_matmul
 from hpc_patterns_tpu.ops.ssm_step import ssm_step
-from hpc_patterns_tpu.parallel.moe import relu2
+from hpc_patterns_tpu.parallel.moe import relu2, silu
 
 
 @pytest.fixture(scope="module")
@@ -203,6 +204,94 @@ def test_a_decode_steps_kv_row_lands_in_its_pool_in_place(
     compiled = jax.jit(chunk, donate_argnums=(0,)).lower(
         pools, shape(slots, q_heads, 128), shape(slots, pool[1], 128),
         shape(slots, 16, dt=jnp.int32), shape(slots, dt=jnp.int32)).compile()
+    whole = r"= bf16\[{},{},{},{}\]\S* copy\(".format(*pool)
+    text = compiled.as_text()
+    assert len(re.findall(r"%flash_decode_paged[.\d]* = ", text)) == 2
+    assert not [line for line in text.splitlines() if re.search(whole, line)]
+    memory = compiled.memory_analysis()
+    assert memory.temp_size_in_bytes < 64 << 20
+    assert memory.alias_size_in_bytes >= 4 * 2 * pool[0] * pool[1] * 256 * 128
+
+
+# sdar-30b-a3b-stage: 32 query heads on 4 K/V heads of 128; a prefill rung
+# under the block mask of 4, and a block step of 64 slots: 4 positions x a
+# group of 8 = 32 query rows over each K/V head, 1025 pages of 256
+@pytest.mark.parametrize("kernel", ["flash_fwd", "flash_decode_paged"])
+def test_attention_kernels_compile_under_the_block_mask(
+        one_chip, no_compile_cache, kernel):
+    shape = lambda *s, dt=jnp.bfloat16: jax.ShapeDtypeStruct(
+        s, dt, sharding=one_chip)
+    if kernel == "flash_fwd":
+        fn = lambda q, k, v: flash_attention(q, k, v, mask_block=4,
+                                             interpret=False)
+        args = [shape(1, 2048, 32, 128), shape(1, 2048, 4, 128),
+                shape(1, 2048, 4, 128)]
+    else:
+        fn = lambda q, kp, vp, table, pos: flash_decode_paged_block(
+            q, kp, vp, table, pos, scale=128 ** -0.5, interpret=False)
+        args = [shape(64, 4, 32, 128), shape(1025, 4, 256, 128),
+                shape(1025, 4, 256, 128), shape(64, 16, dt=jnp.int32),
+                shape(64, dt=jnp.int32)]
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    calls = [line for line in text.splitlines() if "tpu_custom_call" in line]
+    assert len(calls) == 1 and re.search(rf"%{kernel}[.\d]* = ", calls[0])
+
+
+# sdar-30b-a3b-stage: 128 experts of 2048 -> 768 -> 2048, 8 picks a token;
+# a block forward of 64 slots x 4 positions, prefills of 512 and 4096
+@pytest.mark.parametrize("rows", [64 * 4 * 8, 512 * 8, 4096 * 8])
+@pytest.mark.parametrize("product", ["gate", "down"])
+def test_grouped_matmul_compiles_at_the_gated_experts_widths(
+        one_chip, no_compile_cache, rows, product):
+    k, n = (2048, 768) if product == "gate" else (768, 2048)
+    kw = ({"activation": silu} if product == "gate"
+          else {"preferred_element_type": jnp.float32})
+    shape = lambda s, dt: jax.ShapeDtypeStruct(s, dt, sharding=one_chip)
+    text = jax.jit(
+        lambda a, b, s: grouped_matmul(a, b, s, interpret=False, **kw)
+    ).lower(shape((rows, k), jnp.bfloat16), shape((128, k, n), jnp.bfloat16),
+            shape((128,), jnp.int32)).compile().as_text()
+    assert "tpu_custom_call" in text and "grouped_matmul" in text
+
+
+def test_a_block_steps_kv_rows_land_in_their_pool_in_place(
+        one_chip, no_compile_cache):
+    """A block chunk's loop of write-then-attend over donated pools, 64
+    slots x 4 positions a forward, idle rows' page ids past the pool: the
+    scatter must stay in the kernel's layout (no whole-pool copy), as a
+    decode step's does."""
+    pool = (1025, 4, 256, 128)
+
+    def chunk(pools, q, new, table, pos, active):
+        def step(carry, _):
+            pools, pos, acc = carry
+            at = pos[:, None] + jnp.arange(4, dtype=jnp.int32)
+            ids = jnp.take_along_axis(table, at // 256, axis=1)
+            ids = jnp.where(active[:, None], ids, pool[0]).reshape(-1)
+            out = []
+            for k_pool, v_pool in pools:
+                k_pool = _pool_write(k_pool, ids, None, (at % 256).reshape(-1),
+                                     new, 16, False)
+                v_pool = _pool_write(v_pool, ids, None, (at % 256).reshape(-1),
+                                     new, 16, False)
+                acc = acc + flash_decode_paged_block(
+                    q, k_pool, v_pool, table, pos, scale=128 ** -0.5,
+                    interpret=False)
+                out.append((k_pool, v_pool))
+            return (tuple(out), pos + 4, acc), None
+
+        acc = jnp.zeros(q.shape, jnp.float32)
+        (pools, _, acc), _ = lax.scan(step, (pools, pos, acc), None,
+                                      length=6)
+        return pools, acc
+
+    shape = lambda *s, dt=jnp.bfloat16: jax.ShapeDtypeStruct(
+        s, dt, sharding=one_chip)
+    pools = tuple((shape(*pool), shape(*pool)) for _ in range(2))
+    compiled = jax.jit(chunk, donate_argnums=(0,)).lower(
+        pools, shape(64, 4, 32, 128), shape(256, 4, 128),
+        shape(64, 16, dt=jnp.int32), shape(64, dt=jnp.int32),
+        shape(64, dt=jnp.bool_)).compile()
     whole = r"= bf16\[{},{},{},{}\]\S* copy\(".format(*pool)
     text = compiled.as_text()
     assert len(re.findall(r"%flash_decode_paged[.\d]* = ", text)) == 2
