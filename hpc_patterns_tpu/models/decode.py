@@ -1404,15 +1404,17 @@ def paged_decode_step(params, cache, pos, tokens, cfg: TransformerConfig,
             paged_attention_decode,
         )
 
-        def kernel_fn(q, kp, vp, tbl, p, ksp, vsp):
+        def kernel_fn(q, kp, vp, tbl, p, act, ksp, vsp):
             return paged_attention_decode(
                 q, kp, vp, tbl, p, k_scale_pool=ksp, v_scale_pool=vsp,
                 scale=scale)
     else:
-        def kernel_fn(q, kp, vp, tbl, p, ksp, vsp):
+        def kernel_fn(q, kp, vp, tbl, p, act, ksp, vsp):
+            # the rows that do not count are not visited: zeros
             return flash_decode_paged(
-                q, kp, vp, tbl, p, k_scale_pool=ksp, v_scale_pool=vsp,
-                scale=scale, pages_per_step=pages_per_step)
+                q, kp, vp, tbl, p, active=act, k_scale_pool=ksp,
+                v_scale_pool=vsp, scale=scale,
+                pages_per_step=pages_per_step)
 
     @scoped("kv_write")
     def write(k_new, v_new, state):
@@ -1443,9 +1445,9 @@ def paged_decode_step(params, cache, pos, tokens, cfg: TransformerConfig,
         elif paged_sharded:
             # manual partition over tp, mirroring decode_step's linear
             # route: q heads block-shard with their kv heads, pools
-            # shard on the kv_heads dim, table/pos ride replicated.
-            # (PS, not the module alias P — the page size shadows it
-            # in this scope.)
+            # shard on the kv_heads dim, table/pos/active ride
+            # replicated. (PS, not the module alias P — the page size
+            # shadows it in this scope.)
             from jax.sharding import PartitionSpec as PS
 
             spec_q, spec_pool = _tp_serving_specs(mesh, cfg)
@@ -1456,10 +1458,15 @@ def paged_decode_step(params, cache, pos, tokens, cfg: TransformerConfig,
             if quant:
                 args += [ks_pool, vs_pool]
                 specs += [spec_pool, spec_pool]
+            if active is not None:
+                args.append(active)
+                specs.append(PS())
 
-            def local_attn(q, kp, vp, tbl, p, ksp=None, vsp=None):
-                return kernel_fn(q, kp, vp, tbl,
-                                 p if ragged else p[0], ksp, vsp)
+            def local_attn(q, kp, vp, tbl, p, *rest):
+                ksp, vsp = rest[:2] if quant else (None, None)
+                act = None if active is None else rest[-1]
+                return kernel_fn(q, kp, vp, tbl, p if ragged else p[0],
+                                 act, ksp, vsp)
 
             o = shard_map(
                 local_attn, mesh=mesh, in_specs=tuple(specs),
@@ -1467,8 +1474,8 @@ def paged_decode_step(params, cache, pos, tokens, cfg: TransformerConfig,
                 check_vma=False,  # pallas_call can't declare vma
             )(*args)
         else:
-            o = kernel_fn(q, k_pool, v_pool, table, pos, ks_pool,
-                          vs_pool)
+            o = kernel_fn(q, k_pool, v_pool, table, pos, active,
+                          ks_pool, vs_pool)
         return o
 
     states = [
@@ -1706,7 +1713,7 @@ def paged_block_step(params, cache, pos, tokens, cfg: TransformerConfig,
                 None, None, table, pos + (c - 1), cfg, scale)
             return flash_decode.unfold_block(o, c, cfg.kv_heads)
         return flash_decode.flash_decode_paged_block(
-            q, k_pool, v_pool, table, pos, scale=scale)
+            q, k_pool, v_pool, table, pos, active=active, scale=scale)
 
     ks, vs, stats = [], [], []
     for l, lp in enumerate(params["layers"]):

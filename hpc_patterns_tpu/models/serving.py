@@ -2084,8 +2084,11 @@ class EngineCore:
         with metricslib.span("serve.decode_dispatch", chunk=self.chunk,
                              rows=len(parts), round=self._round,
                              # the live rows' positions, summed: what the
-                             # chunk's first step attends over
-                             ctx_tokens=lambda: pos_start[parts].sum()
+                             # chunk's first step attends over, and the
+                             # pages of a K/V head it fetches for that
+                             ctx_tokens=lambda: pos_start[parts].sum(),
+                             kv_pages=lambda: (
+                                 pos_start[parts] // self.page_size + 1).sum()
                              ), \
                 tracelib.compile_watch("serving._chunk_step",
                                        _chunk_step, chunk=self.chunk):
@@ -2143,10 +2146,15 @@ class EngineCore:
         # the stored positions the live rows attend over beside their own
         # blocks
         ctx = sum(self._slots[i].cursor for i in parts)
+        # the pages of a K/V head a forward fetches: up to its block's end
+        end = self.cfg.block_len - 1
         with metricslib.span("serve.decode_dispatch", chunk=self.chunk,
                              forwards=self.chunk, rows=len(parts),
                              block=self.cfg.block_len, round=self._round,
-                             ctx_tokens=ctx), \
+                             ctx_tokens=ctx,
+                             kv_pages=lambda: sum(
+                                 (self._slots[i].cursor + end)
+                                 // self.page_size + 1 for i in parts)), \
                 tracelib.compile_watch("serving._block_chunk",
                                        _block_chunk, forwards=self.chunk):
             (self.cache, self.pos, self.blk, self.msk, self.fidx, self.nfw,

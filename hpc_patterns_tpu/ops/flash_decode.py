@@ -3,27 +3,41 @@
 The serving-side analog of ops/flash_attention.py (the framework rule:
 hot loops are Pallas — docs/ARCHITECTURE.md; reference analog: the
 own-the-hot-loop principle of concurency/sycl_con.cpp:26-33). A decode
-step is cache-read-bound, so the kernel's job is to
-make exactly one streamed pass over the *live* prefix of the cache:
+step is cache-read-bound, so a kernel's job is to make exactly one
+streamed pass over the *live* prefix of the cache. Two kernels share the
+arithmetic (:func:`_softmax_block`, one online-softmax update a block)
+and differ in how the blocks reach VMEM:
 
-- grid = (batch·kv_heads, S_max/BLOCK_S): each step loads one
-  (BLOCK_S, head_dim) cache block into VMEM while the previous block
-  computes (Pallas double-buffers the stream); the online-softmax state
-  (m, l, acc) for the g = n_heads/kv_heads grouped queries carries in
-  f32 scratch across the S axis.
-- the current fill position arrives via scalar prefetch, and the cache
-  index map CLAMPS blocks past it to the last live block — consecutive
-  clamped steps revisit that block, Pallas elides the fetch, and
-  ``pl.when`` skips the compute. Per-step HBM traffic is proportional
-  to the POSITION, not the allocated cache length (the XLA gather path
-  always reads all of max_len and masks).
-- GQA is native: the q block is the (g, head_dim) group sharing this
-  kv head; the cache is streamed kv_heads-narrow. MHA is g = 1.
-
-The cache must be kernel-layout: (batch·kv_heads, S_max, head_dim) with
-S contiguous — models/decode.py stores it that way from prefill on
-(a per-step transpose would itself read the whole cache and defeat the
-point).
+- the LINEAR cache (:func:`flash_decode_attention`): grid =
+  (batch·kv_heads, S_max/BLOCK_S); each step loads one (BLOCK_S,
+  head_dim) cache block into VMEM while the previous block computes
+  (Pallas double-buffers the stream); the online-softmax state (m, l,
+  acc) for the g = n_heads/kv_heads grouped queries carries in f32
+  scratch across the S axis. The current fill position arrives via
+  scalar prefetch, and the cache index map CLAMPS blocks past it to the
+  last live block — consecutive clamped steps revisit that block, Pallas
+  elides the fetch, and ``pl.when`` skips the compute. Per-step HBM
+  traffic is proportional to the POSITION, not the allocated cache
+  length (the XLA gather path always reads all of max_len and masks).
+  The cache must be kernel-layout: (batch·kv_heads, S_max, head_dim)
+  with S contiguous — models/decode.py stores it that way from prefill
+  on (a per-step transpose would itself read the whole cache and defeat
+  the point).
+- the PAGED cache (:func:`flash_decode_paged`): grid = (live rows,).
+  The pools stay in HBM and the kernel copies pages itself, so its
+  traffic follows the DATA it is given and not the engine's shape: the
+  list of live rows arrives by scalar prefetch and the grid's extent is
+  its length (as ops/ssm_step's is), so an idle slot is not visited; a
+  visited row at position p has pages 0 .. p // page_size copied once
+  each (all K/V heads of a page in one copy: they are contiguous in the
+  pool) through a ring of ``pages_per_step`` page buffers that runs on
+  from one row into the next, and no page past p. A clamped index map
+  cannot do that: every page block of a grid step is an operand of its
+  own, fetched whenever its index moves, so an idle slot costs as many
+  fetches as a live one and a live row more than twice its pages
+  (PERF.md section 6, PR 33).
+- GQA is native in both: the q block is the (g, head_dim) group sharing
+  a kv head; the cache is streamed kv_heads-narrow. MHA is g = 1.
 """
 
 from __future__ import annotations
@@ -36,7 +50,7 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from hpc_patterns_tpu.ops.tiling import resolve_interpret
+from hpc_patterns_tpu.ops.tiling import live_rows, resolve_interpret
 
 _NEG_INF = -1e30
 
@@ -213,51 +227,147 @@ def flash_decode_attention(
     return out.reshape(B, H, D)
 
 
-def _decode_kernel_paged(pos_ref, table_ref, q_ref, *rest, scale: float,
-                         page_size: int, unroll: int,
-                         quantized: bool = False, hkv_per_row: int = 0):
-    # grid (B·Hkv, ceil(pages/unroll)): ``unroll`` page blocks arrive
-    # per grid step as separate refs (k_0..k_{U-1}, v_0..v_{U-1}
-    # [, ks_.., vs_..]) and the online softmax walks them in order —
-    # the round-4 page-hopping residue was one grid step (and one
-    # shallow DMA) per page; batching U pages per step restores the
-    # linear kernel's block depth (U·page ≈ its 2048-row block) while
-    # keeping page-granular allocation. The table ref is consumed by
-    # the index maps only.
-    del table_ref
-    U = unroll
-    k_refs, rest = rest[:U], rest[U:]
-    v_refs, rest = rest[:U], rest[U:]
+class _At:
+    """``ref[at]`` standing where :func:`_softmax_block` takes a whole
+    block's ref: read and written through ``ref`` at that index (a sliced
+    VIEW, ``ref.at[at]``, of a block whose group is no whole number of
+    tiles does not lower)."""
+
+    def __init__(self, ref, *at):
+        self.ref, self.at = ref, at
+
+    def __getitem__(self, _):
+        return self.ref[self.at]
+
+    def __setitem__(self, _, value):
+        self.ref[self.at] = value
+
+
+def _decode_kernel_paged(rows_ref, pos_ref, table_ref, q_ref, *rest,
+                         scale: float, pages: int, depth: int,
+                         quantized: bool, ragged: bool):
+    # grid (visited rows,): grid step i is row rows_ref[i] with all of its
+    # K/V heads. The pools stay in HBM and the kernel copies the pages
+    # itself. The call's work is ONE stream of (row, page) items in
+    # visiting order: item t lands in slot t % depth of a ring of page
+    # buffers (a page's K/V heads are contiguous in the pool, so one copy
+    # brings them all) and its copies start depth - 1 items before it is
+    # attended over, so the fetches run on across the rows: a row's first
+    # page is on its way while the row before it is attended over. ``cur``
+    # (SMEM, carried over the grid) is the fetch cursor.
+    n = 4 if quantized else 2               # k, v [, k scales, v scales]
+    pools, o_ref, bufs = rest[:n], rest[n], rest[n + 1:2 * n + 1]
+    sems, cur, m_ref, l_ref, acc_ref = rest[2 * n + 1:]
+    kv_heads, page_size = bufs[0].shape[1], bufs[0].shape[2]
+    i, count = pl.program_id(0), pl.num_programs(0)
+
+    def row_pos(r):
+        return pos_ref[r] if ragged else pos_ref[0]
+
+    def live_pages(r):
+        # the fetch cursor and the walk must count a row alike whatever
+        # its position holds: at least its first page, at most its table
+        return jnp.clip(row_pos(r) // page_size + 1, 1, pages)
+
+    def copies(r, page, slot):
+        at = table_ref[r * pages + page]
+        return [pltpu.make_async_copy(pool.at[at], buf.at[slot],
+                                      sems.at[j, slot])
+                for j, (pool, buf) in enumerate(zip(pools, bufs))]
+
+    def fetch():
+        # start the copies of the next item not yet asked for, if any
+        vi, page, t = cur[0], cur[1], cur[2]
+
+        @pl.when(vi < count)
+        def _():
+            r = rows_ref[vi]
+            for c in copies(r, page, t % depth):
+                c.start()
+            last = page + 1 >= live_pages(r)
+            cur[0] = jnp.where(last, vi + 1, vi)
+            cur[1] = jnp.where(last, 0, page + 1)
+            cur[2] = t + 1
+
+    @pl.when(i == 0)
+    def _():
+        cur[0] = cur[1] = cur[2] = cur[3] = 0
+        for _ in range(depth - 1):
+            fetch()
+
+    r = rows_ref[i]
+    pos = row_pos(r)
+    m_ref[:] = jnp.full(m_ref.shape, _NEG_INF, jnp.float32)
+    l_ref[:] = jnp.zeros(l_ref.shape, jnp.float32)
+    acc_ref[:] = jnp.zeros(acc_ref.shape, jnp.float32)
+
+    def attend(page, carry):
+        fetch()
+        slot = cur[3] % depth
+        for c in copies(r, page, slot):
+            c.wait()
+        for h in range(kv_heads):
+            _softmax_block(
+                _At(q_ref, h), *(_At(buf, slot, h) for buf in bufs),
+                *(() if quantized else (None, None)),
+                _At(m_ref, h), _At(l_ref, h), _At(acc_ref, h),
+                page * page_size, pos, scale, quantized)
+        cur[3] = cur[3] + 1
+        return carry
+
+    lax.fori_loop(0, live_pages(r), attend, 0)
+    o_ref[:] = acc_ref[:] / jnp.maximum(l_ref[:], 1e-30)
+
+
+# jitted: the layers of a program call it with one signature, so the outer
+# trace lowers the kernel once
+@functools.partial(jax.jit, static_argnums=(8, 9, 10))
+def _paged_call(q, k_pool, v_pool, table, pos, active, k_scale_pool,
+                v_scale_pool, scale, depth, interpret):
+    # ``pos`` (B,) a position a row, or (1,) one for all (B == 1: the same)
+    B, H, D = q.shape
+    _, Hkv, P, _ = k_pool.shape
+    pages = table.shape[1]
+    g = H // Hkv
+    quantized = k_scale_pool is not None
+    rows, count = live_rows(active, B)
+    pools = [k_pool, v_pool]
     if quantized:
-        ks_refs, rest = rest[:U], rest[U:]
-        vs_refs, rest = rest[:U], rest[U:]
-    else:
-        ks_refs = vs_refs = (None,) * U
-    o_ref, m_ref, l_ref, acc_ref = rest
-    g, d = q_ref.shape
-    si = pl.program_id(1)
-    n_s = pl.num_programs(1)
-    pos = (pos_ref[pl.program_id(0) // hkv_per_row] if hkv_per_row
-           else pos_ref[0])
-
-    @pl.when(si == 0)
-    def _():
-        m_ref[:] = jnp.full((g, 1), _NEG_INF, jnp.float32)
-        l_ref[:] = jnp.zeros((g, 1), jnp.float32)
-        acc_ref[:] = jnp.zeros((g, d), jnp.float32)
-
-    for j in range(U):
-        start = (si * U + j) * page_size
-
-        @pl.when(start <= pos)
-        def _(j=j, start=start):
-            _softmax_block(q_ref, k_refs[j], v_refs[j], ks_refs[j],
-                           vs_refs[j], m_ref, l_ref, acc_ref, start,
-                           pos, scale, quantized)
-
-    @pl.when(si == n_s - 1)
-    def _():
-        o_ref[:] = acc_ref[:] / jnp.maximum(l_ref[:], 1e-30)
+        # scales ride lane-major (1, page) rows (see _softmax_block)
+        pools += [k_scale_pool, v_scale_pool]
+    heads_of_row = pl.BlockSpec(
+        (None, Hkv, g, D), lambda i, rows, pos, table: (rows[i], 0, 0, 0),
+        memory_space=pltpu.VMEM)
+    out = pl.pallas_call(
+        functools.partial(_decode_kernel_paged, scale=scale, pages=pages,
+                          depth=depth, quantized=quantized,
+                          ragged=pos.shape[0] == B),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(count,),
+            in_specs=[heads_of_row]
+            + [pl.BlockSpec(memory_space=pl.ANY)] * len(pools),
+            out_specs=heads_of_row,
+            scratch_shapes=[pltpu.VMEM((depth,) + p.shape[1:], p.dtype)
+                            for p in pools] + [
+                pltpu.SemaphoreType.DMA((len(pools), depth)),
+                pltpu.SMEM((4,), jnp.int32),       # the fetch cursor
+                pltpu.VMEM((Hkv, g, 1), jnp.float32),   # running max
+                pltpu.VMEM((Hkv, g, 1), jnp.float32),   # running sumexp
+                pltpu.VMEM((Hkv, g, D), jnp.float32),   # accumulator
+            ],
+        ),
+        out_shape=jax.ShapeDtypeStruct((B, Hkv, g, D), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        name="flash_decode_paged",
+        interpret=interpret,
+    )(rows, pos, table.reshape(-1).astype(jnp.int32),
+      q.reshape(B, Hkv, g, D), *pools)
+    out = out.reshape(B, H, D)
+    if active is not None:   # the kernel left the idle rows unwritten
+        out = jnp.where(active[:, None, None], out, 0.0)
+    return out
 
 
 def flash_decode_paged(
@@ -267,6 +377,7 @@ def flash_decode_paged(
     table,
     pos,
     *,
+    active=None,
     k_scale_pool=None,
     v_scale_pool=None,
     scale: float | None = None,
@@ -280,21 +391,25 @@ def flash_decode_paged(
     ordered page list — allocation follows ACTUAL generation length,
     not the declared maximum (the linear cache's
     allocate-for-the-longest waste is the round-3 capacity ceiling).
-    The kernel is the linear ``flash_decode_attention`` body unchanged;
-    only the index map differs — the page id for grid step ``si`` is
-    read from the scalar-prefetched table, so the indirection costs
-    nothing per block and pages can live ANYWHERE in the pool.
+    The arithmetic is the linear ``flash_decode_attention``'s, one
+    online-softmax update a page; the FETCHES follow the data: the
+    kernel walks the rows that are live and, of each, the pages
+    ``0 .. pos // page_size`` once each, copying them from the pools in
+    HBM itself through the scalar-prefetched table, so pages can live
+    ANYWHERE in the pool and a call's traffic is what its live rows
+    attend over, whatever the engine's slots and pages a sequence.
 
     ``q``: (B, n_heads, head_dim); ``k_pool``/``v_pool``:
     (pool_pages, kv_heads, page_size, head_dim) in the compute dtype;
     ``table``: (B, pages_per_seq) int32 page ids (entries past the live
-    prefix may be any valid id — the clamped index map never fetches
-    them); ``pos``: traced int32 — a scalar (batch-uniform position)
-    or a (B,) vector of PER-SEQUENCE positions (ragged serving: every
-    sequence at its own length; each grid row masks and clamps by its
-    own sequence's fill position, so per-row HBM traffic follows
-    per-row length). Returns (B, n_heads, head_dim) f32, numerically
-    identical to the linear kernel on the equivalent cache.
+    prefix may be any id: they are never read); ``pos``: traced int32 —
+    a scalar (batch-uniform position) or a (B,) vector of PER-SEQUENCE
+    positions (ragged serving: every sequence at its own length).
+    ``active``: (B,) bool, the rows to attend for, ``None`` for every
+    row. A row where it does not hold is NOT VISITED (no page of it is
+    fetched, whatever its position and table row say) and its output is
+    zeros. Returns (B, n_heads, head_dim) f32, numerically identical to
+    the linear kernel on the equivalent cache.
 
     ``k_scale_pool``/``v_scale_pool``: (pool_pages, kv_heads, 1,
     page_size) f32 per-row dequant scales for int8 pools — the linear
@@ -302,16 +417,10 @@ def flash_decode_paged(
     (the CAPACITY levers stack: int8 halves page bytes, paging frees
     the allocate-for-longest waste).
 
-    ``pages_per_step``: page blocks fetched per grid step (separate
-    refs walked by one online-softmax pass). Default: enough pages to
-    match the linear kernel's 2048-row streaming block — the round-4
-    measurement showed the paged kernel's 1.7x/token residue was the
-    per-page grid/DMA granularity, not the table indirection. Tradeoff:
-    a row whose live prefix is SHORTER than one step's U pages pays up
-    to U-1 one-time fetches of its clamped last page (each ref is a
-    distinct operand; cross-step elision still applies, cross-ref
-    doesn't) — negligible next to the long-row streaming this buys,
-    and ``pages_per_step=1`` restores the exact old behavior.
+    ``pages_per_step``: pages in flight at a time, the depth of the
+    kernel's ring of page buffers (``pages_per_step`` x kv_heads x
+    page bytes of VMEM for K and for V). Default: the linear kernel's
+    2048-row streaming block in pages. 1 fetches and attends in turn.
     """
     B, H, D = q.shape
     n_pool, Hkv, P, Dp = k_pool.shape
@@ -323,72 +432,26 @@ def flash_decode_paged(
         )
     if table.shape[0] != B:
         raise ValueError(f"table rows {table.shape[0]} != batch {B}")
-    if scale is None:
-        scale = 1.0 / (D ** 0.5)
-    interpret = resolve_interpret(interpret, "flash_decode_paged")
-    g = H // Hkv
-
-    quantized = k_scale_pool is not None
-    qr = q.reshape(B * Hkv, g, D)
     ragged = jnp.ndim(pos) == 1
     if ragged and jnp.shape(pos)[0] != B:
         raise ValueError(
             f"ragged pos has {jnp.shape(pos)[0]} entries for batch {B}"
         )
-    pos_arr = jnp.asarray(pos, jnp.int32).reshape(B if ragged else 1)
-    table_flat = table.reshape(-1).astype(jnp.int32)
-
+    if active is not None and (jnp.shape(active) != (B,)
+                               or active.dtype != jnp.bool_):
+        raise ValueError(
+            f"active {jnp.shape(active)} {active.dtype}; want ({B},) bool")
+    if scale is None:
+        scale = 1.0 / (D ** 0.5)
     if pages_per_step is None:
         # match the linear kernel's streaming block (block_s = 2048)
         pages_per_step = max(1, 2048 // P)
-    U = max(1, min(int(pages_per_step), pages))
-    n_steps = -(-pages // U)
-
-    def page_idx(j):
-        # clamp to the last live page (same fetch-elision as the linear
-        # kernel), then indirect through this sequence's page list
-        def f(r, si, pos_ref, table_ref):
-            b = r // Hkv
-            live = jnp.minimum(si * U + j,
-                               pos_ref[b if ragged else 0] // P)
-            return table_ref[b * pages + live], r % Hkv, 0, 0
-
-        return f
-
-    row = functools.partial(pl.BlockSpec, memory_space=pltpu.VMEM)
-    in_specs = [row((None, g, D), lambda r, si, pos, tab: (r, 0, 0))]
-    in_specs += [row((None, None, P, D), page_idx(j)) for j in range(U)]
-    in_specs += [row((None, None, P, D), page_idx(j)) for j in range(U)]
-    operands = [pos_arr, table_flat, qr]
-    operands += [k_pool] * U + [v_pool] * U
-    if quantized:
-        # scales ride lane-major (1, page) rows, page-indirected like
-        # the value blocks (see the linear kernel's layout note)
-        in_specs += [row((None, None, 1, P), page_idx(j))
-                     for j in range(U)]
-        in_specs += [row((None, None, 1, P), page_idx(j))
-                     for j in range(U)]
-        operands += [k_scale_pool] * U + [v_scale_pool] * U
-    out = pl.pallas_call(
-        functools.partial(_decode_kernel_paged, scale=float(scale),
-                          page_size=P, unroll=U, quantized=quantized,
-                          hkv_per_row=Hkv if ragged else 0),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
-            grid=(B * Hkv, n_steps),
-            in_specs=in_specs,
-            out_specs=row((None, g, D), lambda r, si, pos, tab: (r, 0, 0)),
-            scratch_shapes=[
-                pltpu.VMEM((g, 1), jnp.float32),
-                pltpu.VMEM((g, 1), jnp.float32),
-                pltpu.VMEM((g, D), jnp.float32),
-            ],
-        ),
-        out_shape=jax.ShapeDtypeStruct((B * Hkv, g, D), jnp.float32),
-        name="flash_decode_paged",
-        interpret=interpret,
-    )(*operands)
-    return out.reshape(B, H, D)
+    return _paged_call(
+        q, k_pool, v_pool, table,
+        jnp.asarray(pos, jnp.int32).reshape(B if ragged else 1), active,
+        k_scale_pool, v_scale_pool, float(scale),
+        max(1, min(int(pages_per_step), pages)),
+        resolve_interpret(interpret, "flash_decode_paged"))
 
 
 def fold_block(q, kv_heads: int):

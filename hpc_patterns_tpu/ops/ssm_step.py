@@ -39,7 +39,8 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from hpc_patterns_tpu.ops.tiling import fit_block_divisor, resolve_interpret
+from hpc_patterns_tpu.ops.tiling import (fit_block_divisor, live_rows,
+                                          resolve_interpret)
 
 #: bytes of one tile of S; two come in and two go out at a time. From
 #: 1 MiB up the kernel runs at what its DMAs allow (a plain copy of the same
@@ -105,11 +106,7 @@ def _call(S, x, dt, A, B, C, active, interpret):
     G = B.shape[1]
     hb = _head_block(H, P * N * S.dtype.itemsize)
     nb = H // hb
-    if active is None:
-        rows, count = jnp.arange(b, dtype=jnp.int32), b
-    else:
-        rows = jnp.nonzero(active, size=b, fill_value=0)[0].astype(jnp.int32)
-        count = jnp.sum(active, dtype=jnp.int32)
+    rows, count = live_rows(active, b)
     decay = jnp.exp(dt * A).reshape(-1)                  # (b * H,) to SMEM
     dtx = jnp.swapaxes((dt[..., None] * x).reshape(b, nb, hb, P), 2, 3)
     block = functools.partial(pl.BlockSpec, memory_space=pltpu.VMEM)

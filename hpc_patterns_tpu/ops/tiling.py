@@ -21,6 +21,7 @@ bypasses it with a magic number.
 from __future__ import annotations
 
 import jax
+import jax.numpy as jnp
 from jax.experimental.pallas import tpu as pltpu
 
 #: name -> collective_id. Seeded with the historical 0-4 assignment so
@@ -111,6 +112,19 @@ def resolve_interpret(interpret: bool | None, kernel: str) -> bool:
 def kernel_modes() -> dict[str, dict[str, int]]:
     """Snapshot of the per-kernel trace-mode counts."""
     return {k: dict(v) for k, v in sorted(_KERNEL_MODES.items())}
+
+
+def live_rows(active, n: int):
+    """``(rows, count)`` for a kernel whose grid walks the live rows only:
+    the indices where ``active`` ((n,) bool) holds, first, as (n,) int32
+    for scalar prefetch (the tail is 0: never visited), and how many they
+    are, the grid's extent. ``None`` is every row, a static count. The
+    same ``active`` gives the same operations wherever it is asked, so
+    XLA keeps one list a step however many layers ask."""
+    if active is None:
+        return jnp.arange(n, dtype=jnp.int32), n
+    rows = jnp.nonzero(active, size=n, fill_value=0)[0].astype(jnp.int32)
+    return rows, jnp.sum(active, dtype=jnp.int32)
 
 
 def fit_block_divisor(n: int, cap: int) -> int:

@@ -162,6 +162,51 @@ def test_attention_kernels_compile_at_a_group_of_five(
     assert len(calls) == 1 and re.search(rf"%{kernel}[.\d]* = ", calls[0])
 
 
+#: ``reduce-window`` operations of ONE ``jnp.nonzero`` over the slots in a
+#: compiled program (its running sum and its count), however many layers
+#: call the kernel with the same ``active``
+LIVE_LIST_SUMS = 2
+
+
+# the five cells' decode calls (pool, slots, query heads; a block of 4
+# folded where the model generates by diffusion), each with a traced
+# ``active``: serve-code, serve-gen, serve-chat, serve-assist, serve-diffuse
+@pytest.mark.parametrize("pool,slots,q_heads,block", [
+    ((161, 2, 256, 128), 32, 24, 0),
+    ((385, 2, 256, 128), 32, 24, 0),
+    ((1025, 2, 256, 128), 64, 32, 0),
+    ((513, 4, 256, 128), 32, 20, 0),
+    ((1025, 4, 256, 128), 64, 32, 4),
+])
+def test_flash_decode_paged_compiles_over_the_live_rows_at_the_cells_shapes(
+        one_chip, no_compile_cache, pool, slots, q_heads, block):
+    """One kernel under its caller's scope (the four
+    ``*flash_decode_paged_roofline`` find it by name, ``*_attn_decode_ms_chunk``
+    by ``attn``), its ring of page buffers within the VMEM a kernel may
+    take, the pools handed over where they lie (no copy of one)."""
+    def layer(q, kp, vp, table, pos, active):
+        kernel = flash_decode_paged_block if block else flash_decode_paged
+        with jax.named_scope("attn"):
+            return kernel(q, kp, vp, table, pos, active=active,
+                          scale=128 ** -0.5, interpret=False)
+
+    shape = lambda *s, dt=jnp.bfloat16: jax.ShapeDtypeStruct(
+        s, dt, sharding=one_chip)
+    q = (shape(slots, block, q_heads, 128) if block
+         else shape(slots, q_heads, 128))
+    text = jax.jit(layer).lower(
+        q, shape(*pool), shape(*pool), shape(slots, 16, dt=jnp.int32),
+        shape(slots, dt=jnp.int32), shape(slots, dt=jnp.bool_)
+    ).compile().as_text()
+    calls = [line for line in text.splitlines() if "tpu_custom_call" in line]
+    assert len(calls) == 1
+    assert re.search(r"%flash_decode_paged[.\d]* = ", calls[0])
+    assert re.search(r'op_name="[^"]*attn/[^"]*flash_decode_paged', calls[0])
+    assert not re.search(r"= bf16\[{},{},{},{}\]\S* copy\(".format(*pool),
+                         text)
+    assert len(re.findall(r" reduce-window\(", text)) == LIVE_LIST_SUMS
+
+
 # the serving cells' K/V pools (pages + the trash page, K/V heads, page
 # 256, head 128) with their slots and query heads: serve-assist, serve-gen
 # (serve-code's pool is the same with 161 pages), serve-chat
@@ -176,9 +221,10 @@ def test_a_decode_steps_kv_row_lands_in_its_pool_in_place(
     of ``decode._pool_write`` must come out in the layout
     ``flash_decode_paged`` reads, so that no step copies a whole pool
     (12 copies of 134 MB a step in serve-assist before PR 31)."""
-    def chunk(pools, q, new, table, pos):
+    def chunk(pools, q, new, table, pos, limit):
         def step(carry, _):
             pools, pos, acc = carry
+            active = pos < limit          # as serving._chunk_step has it
             page_ids = jnp.take_along_axis(
                 table, (pos // 256)[:, None], axis=1)[:, 0]
             out = []
@@ -188,10 +234,10 @@ def test_a_decode_steps_kv_row_lands_in_its_pool_in_place(
                 v_pool = _pool_write(v_pool, page_ids, None, pos % 256, new,
                                      16, False)
                 acc = acc + flash_decode_paged(
-                    q, k_pool, v_pool, table, pos, scale=128 ** -0.5,
-                    interpret=False)
+                    q, k_pool, v_pool, table, pos, active=active,
+                    scale=128 ** -0.5, interpret=False)
                 out.append((k_pool, v_pool))
-            return (tuple(out), pos + 1, acc), None
+            return (tuple(out), jnp.where(active, pos + 1, pos), acc), None
 
         acc = jnp.zeros(q.shape, jnp.float32)
         (pools, _, acc), _ = lax.scan(step, (pools, pos, acc), None,
@@ -203,11 +249,15 @@ def test_a_decode_steps_kv_row_lands_in_its_pool_in_place(
     pools = tuple((shape(*pool), shape(*pool)) for _ in range(2))
     compiled = jax.jit(chunk, donate_argnums=(0,)).lower(
         pools, shape(slots, q_heads, 128), shape(slots, pool[1], 128),
-        shape(slots, 16, dt=jnp.int32), shape(slots, dt=jnp.int32)).compile()
+        shape(slots, 16, dt=jnp.int32), shape(slots, dt=jnp.int32),
+        shape(slots, dt=jnp.int32)).compile()
     whole = r"= bf16\[{},{},{},{}\]\S* copy\(".format(*pool)
     text = compiled.as_text()
     assert len(re.findall(r"%flash_decode_paged[.\d]* = ", text)) == 2
     assert not [line for line in text.splitlines() if re.search(whole, line)]
+    # the list of live rows is made once a step, not once a layer: the two
+    # layers' ``nonzero`` (a running sum and two scatters) became one
+    assert len(re.findall(r" reduce-window\(", text)) == LIVE_LIST_SUMS
     memory = compiled.memory_analysis()
     assert memory.temp_size_in_bytes < 64 << 20
     assert memory.alias_size_in_bytes >= 4 * 2 * pool[0] * pool[1] * 256 * 128
@@ -275,8 +325,8 @@ def test_a_block_steps_kv_rows_land_in_their_pool_in_place(
                 v_pool = _pool_write(v_pool, ids, None, (at % 256).reshape(-1),
                                      new, 16, False)
                 acc = acc + flash_decode_paged_block(
-                    q, k_pool, v_pool, table, pos, scale=128 ** -0.5,
-                    interpret=False)
+                    q, k_pool, v_pool, table, pos, active=active,
+                    scale=128 ** -0.5, interpret=False)
                 out.append((k_pool, v_pool))
             return (tuple(out), pos + 4, acc), None
 

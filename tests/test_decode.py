@@ -234,6 +234,13 @@ class TestShardedServing:
                         jax.tree.leaves(want_cache)):
             np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                        atol=1e-6)
+        # ``active`` rides replicated like ``pos``: the live row's logits
+        # are the same, whatever the idle row's came to
+        live = jnp.array([False, True])
+        one, _ = paged_decode_step(p_sh, sc, pos, tok, cfg,
+                                   mesh=mesh_dp_sp_tp, active=live)
+        np.testing.assert_allclose(np.asarray(one[1]), np.asarray(want[1]),
+                                   atol=1e-5)
 
     def test_tp_paged_rejects_indivisible_kv_heads(self, mesh_dp_sp_tp):
         from hpc_patterns_tpu.models.decode import (
@@ -1146,3 +1153,229 @@ class TestPoolWrite:
             lambda pool, ids, page, off, rows, pages, identity, tp=1:
             real(pool, ids, page, off, rows, pages, identity))
         assert collectives()["all-gather"] > ours["all-gather"]
+
+
+def _parent_kernel_paged(pos_ref, table_ref, q_ref, *rest, scale, page_size,
+                         unroll, quantized, hkv_per_row):
+    """The paged kernel as it stood before the fetches followed the data
+    (PR 32's tree): ``unroll`` page blocks a grid step as separate refs,
+    every row walked, pages past the position clamped to the last."""
+    from jax.experimental import pallas as pl
+
+    from hpc_patterns_tpu.ops.flash_decode import _NEG_INF, _softmax_block
+
+    del table_ref
+    U = unroll
+    k_refs, rest = rest[:U], rest[U:]
+    v_refs, rest = rest[:U], rest[U:]
+    ks_refs = vs_refs = (None,) * U
+    if quantized:
+        ks_refs, rest = rest[:U], rest[U:]
+        vs_refs, rest = rest[:U], rest[U:]
+    o_ref, m_ref, l_ref, acc_ref = rest
+    g, d = q_ref.shape
+    si = pl.program_id(1)
+    pos = (pos_ref[pl.program_id(0) // hkv_per_row] if hkv_per_row
+           else pos_ref[0])
+
+    @pl.when(si == 0)
+    def _():
+        m_ref[:] = jnp.full((g, 1), _NEG_INF, jnp.float32)
+        l_ref[:] = jnp.zeros((g, 1), jnp.float32)
+        acc_ref[:] = jnp.zeros((g, d), jnp.float32)
+
+    for j in range(U):
+        start = (si * U + j) * page_size
+
+        @pl.when(start <= pos)
+        def _(j=j, start=start):
+            _softmax_block(q_ref, k_refs[j], v_refs[j], ks_refs[j],
+                           vs_refs[j], m_ref, l_ref, acc_ref, start, pos,
+                           scale, quantized)
+
+    @pl.when(si == pl.num_programs(1) - 1)
+    def _():
+        o_ref[:] = acc_ref[:] / jnp.maximum(l_ref[:], 1e-30)
+
+
+def _parent_flash_decode_paged(q, k_pool, v_pool, table, pos, *,
+                               k_scale_pool=None, v_scale_pool=None, scale,
+                               pages_per_step):
+    """The oracle of :class:`TestPagedFetchSchedule`: the parent's
+    ``flash_decode_paged``, interpreted."""
+    import functools
+
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    B, H, D = q.shape
+    _, Hkv, P, _ = k_pool.shape
+    pages = table.shape[1]
+    g = H // Hkv
+    quantized = k_scale_pool is not None
+    ragged = jnp.ndim(pos) == 1
+    U = max(1, min(pages_per_step, pages))
+
+    def page_idx(j):
+        def f(r, si, pos_ref, table_ref):
+            b = r // Hkv
+            live = jnp.minimum(si * U + j, pos_ref[b if ragged else 0] // P)
+            return table_ref[b * pages + live], r % Hkv, 0, 0
+        return f
+
+    row = functools.partial(pl.BlockSpec, memory_space=pltpu.VMEM)
+    in_specs = [row((None, g, D), lambda r, si, pos, tab: (r, 0, 0))]
+    in_specs += [row((None, None, P, D), page_idx(j)) for j in range(U)] * 2
+    operands = [k_pool] * U + [v_pool] * U
+    if quantized:
+        in_specs += [row((None, None, 1, P), page_idx(j))
+                     for j in range(U)] * 2
+        operands += [k_scale_pool] * U + [v_scale_pool] * U
+    out = pl.pallas_call(
+        functools.partial(_parent_kernel_paged, scale=scale, page_size=P,
+                          unroll=U, quantized=quantized,
+                          hkv_per_row=Hkv if ragged else 0),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(B * Hkv, -(-pages // U)),
+            in_specs=in_specs,
+            out_specs=row((None, g, D), lambda r, si, pos, tab: (r, 0, 0)),
+            scratch_shapes=[pltpu.VMEM((g, 1), jnp.float32),
+                            pltpu.VMEM((g, 1), jnp.float32),
+                            pltpu.VMEM((g, D), jnp.float32)],
+        ),
+        out_shape=jax.ShapeDtypeStruct((B * Hkv, g, D), jnp.float32),
+        interpret=True,
+    )(jnp.asarray(pos, jnp.int32).reshape(B if ragged else 1),
+      table.reshape(-1), q.reshape(B * Hkv, g, D), *operands)
+    return out.reshape(B, H, D)
+
+
+class TestPagedFetchSchedule:
+    """``flash_decode_paged`` visits the rows that are live and, of each,
+    the pages up to its position: whatever ``active``, ``pos`` and the
+    table hold, a live row's output is the parent kernel's to the bit
+    (the same updates in the same order) and the gather route's to
+    rounding, and a row that is not live comes out as zeros."""
+
+    P, D, PAGES, B = 8, 16, 16, 6
+    # a page's first row and its last, early and on the last page of 16
+    ENDS = (0, 7, 8, 23, 120, 127)
+
+    def _draw(self, case, group, kv_heads, dtype, fold):
+        P, D, pages, B = self.P, self.D, self.PAGES, self.B
+        rng = np.random.default_rng(case)
+        n_pool = B * pages + 1                    # the last: the trash page
+        shape = (n_pool, kv_heads, P, D)
+        f32 = lambda *s: jnp.asarray(rng.standard_normal(s), jnp.float32)
+        pools = {"k": f32(*shape), "v": f32(*shape)}
+        if dtype == "int8":
+            from hpc_patterns_tpu.models.decode import _quantize_rows
+            for name in ("k", "v"):
+                rows, scales = _quantize_rows(pools[name], "int8")
+                pools[name] = rows
+                pools[name + "_scale"] = jnp.swapaxes(
+                    scales.reshape(n_pool, kv_heads, P, 1), 2, 3)
+        else:
+            pools = {n: p.astype(dtype) for n, p in pools.items()}
+        q = (f32(B, fold, group // fold * kv_heads, D) if fold > 1
+             else f32(B, group * kv_heads, D))
+        table = jnp.asarray(rng.permutation(n_pool - 1).reshape(B, pages),
+                            jnp.int32)
+        return rng, q, pools, table
+
+    @pytest.mark.parametrize(
+        "case,group,kv_heads,dtype,pages_per_step,pos_form", [
+            (0, 5, 4, "bfloat16", 8, "ragged"),
+            (1, 5, 2, "int8", 1, "ragged"),
+            (2, 5, 4, "bfloat16", 3, 0),
+            (3, 12, 2, "bfloat16", 8, "ragged"),
+            (4, 12, 2, "int8", 3, "ragged"),
+            (5, 12, 4, "bfloat16", 1, 7),
+            (6, 12, 2, "int8", 8, 8),
+            (7, 16, 2, "bfloat16", 8, "ragged"),
+            (8, 16, 4, "int8", 8, "ragged"),
+            (9, 16, 2, "bfloat16", 3, 120),
+            (10, 16, 4, "int8", 1, 127),
+            (11, 32, 4, "bfloat16", 8, "ragged"),
+            (12, 32, 2, "bfloat16", 1, "ragged"),
+            (13, 32, 4, "bfloat16", 3, "ragged"),
+        ])
+    def test_live_rows_equal_the_parent_kernel_and_idle_rows_are_zero(
+            self, case, group, kv_heads, dtype, pages_per_step, pos_form):
+        from types import SimpleNamespace
+
+        from hpc_patterns_tpu.models.decode import _paged_attend_gather
+        from hpc_patterns_tpu.ops.flash_decode import (
+            flash_decode_paged, flash_decode_paged_block, fold_block,
+            unfold_block)
+
+        fold = 4 if group == 32 else 1    # a block of 4 folded into 8
+        B = self.B
+        rng, q, pools, table = self._draw(case, group, kv_heads, dtype, fold)
+        scale = self.D ** -0.5
+        kw = dict(k_scale_pool=pools.get("k_scale"),
+                  v_scale_pool=pools.get("v_scale"), scale=scale,
+                  pages_per_step=pages_per_step)
+        if pos_form == "ragged":
+            pos = jnp.asarray(rng.permutation(self.ENDS), jnp.int32)
+            if fold > 1:                  # block starts: multiples of 4
+                pos = pos // fold * fold
+        else:
+            pos = jnp.int32(pos_form)
+        if fold > 1:
+            attend = lambda **k: flash_decode_paged_block(
+                q, pools["k"], pools["v"], table, pos, **kw, **k)
+            last, flat = pos + (fold - 1), fold_block(q, kv_heads)
+            back = lambda o: unfold_block(o, fold, kv_heads)
+        else:
+            attend = lambda **k: flash_decode_paged(
+                q, pools["k"], pools["v"], table, pos, **kw, **k)
+            last, flat, back = pos, q, lambda o: o
+        parent = np.asarray(back(_parent_flash_decode_paged(
+            flat, pools["k"], pools["v"], table, last, **kw)))
+        gather = np.asarray(back(_paged_attend_gather(
+            flat, pools["k"], pools["v"], pools.get("k_scale"),
+            pools.get("v_scale"), table, last,
+            SimpleNamespace(kv_heads=kv_heads, head_dim=self.D), scale)))
+
+        every = np.asarray(attend())
+        np.testing.assert_array_equal(every, parent)
+        np.testing.assert_allclose(every, gather, atol=2e-5)
+        half = np.zeros(B, bool)
+        half[rng.permutation(B)[:B // 2]] = True
+        for live in (np.ones(B, bool), np.zeros(B, bool),
+                     np.arange(B) == case % B, half):
+            got = np.asarray(attend(active=jnp.asarray(live)))
+            np.testing.assert_array_equal(got[live], parent[live])
+            assert not got[~live].any(), "an idle row's output is not zero"
+
+    @pytest.mark.parametrize("dtype", ["bfloat16", "int8"])
+    def test_nothing_past_a_live_rows_position_is_used(self, dtype):
+        """Every page no live row owns up to its position, the trash page
+        among them, holds NaN, and the idle rows' table rows point at
+        those: what the live rows get is finite and the clean run's."""
+        from hpc_patterns_tpu.ops.flash_decode import flash_decode_paged
+
+        B, P = self.B, self.P
+        rng, q, pools, table = self._draw(33, 12, 2, dtype, 1)
+        pos = jnp.asarray(rng.permutation(self.ENDS), jnp.int32)
+        live = np.arange(B) % 2 == 0
+        owned = np.zeros(pools["k"].shape[0], bool)
+        for b in np.flatnonzero(live):
+            owned[np.asarray(table)[b, :int(pos[b]) // P + 1]] = True
+        # a NaN where a pool can hold one: an int8 page's in its scales
+        dirty = {n: (jnp.where(owned[:, None, None, None], p, jnp.nan)
+                     if jnp.issubdtype(p.dtype, jnp.floating) else p)
+                 for n, p in pools.items()}
+        run = lambda p, t: np.asarray(flash_decode_paged(
+            q, p["k"], p["v"], t, pos, active=jnp.asarray(live),
+            k_scale_pool=p.get("k_scale"), v_scale_pool=p.get("v_scale"),
+            pages_per_step=3))
+        clean = run(pools, table)
+        # the idle rows own nothing: the trash page, as the engine leaves it
+        strewn = jnp.where(jnp.asarray(live)[:, None], table,
+                           pools["k"].shape[0] - 1)
+        got = run(dirty, strewn)
+        assert np.isfinite(got).all()
+        np.testing.assert_array_equal(got, clean)

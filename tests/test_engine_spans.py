@@ -120,7 +120,8 @@ def test_round_rises_by_one_a_round_and_children_share_it(recorded):
     ("serve.round/serve.admit_pass/serve.prefill",
      {"seq_id", "slot", "overlapped", "prompt_len", "padded_len", "matched"}),
     ("serve.round/serve.first_token", {"seq_id", "slot"}),
-    ("serve.round/serve.decode_dispatch", {"rows", "chunk", "round"}),
+    ("serve.round/serve.decode_dispatch",
+     {"rows", "chunk", "round", "ctx_tokens", "kv_pages"}),
     ("serve.round/serve.collect", {"rows", "round"}),
 ])
 def test_spans_carry_their_attributes(recorded, span, keys):

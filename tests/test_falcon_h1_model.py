@@ -361,6 +361,8 @@ def test_spans_carry_the_bytes_of_both_halves_of_the_cache(params):
     chunks = [a for n, a in seen if n == "serve.decode_dispatch"]
     assert chunks[0]["ctx_tokens"] == 20 and chunks[0]["rows"] == 1
     assert chunks[1]["ctx_tokens"] == 24
+    # the pages of 16 a K/V head's fetches cover: positions 0 .. 20, 0 .. 24
+    assert [c["kv_pages"] for c in chunks[:2]] == [2, 2]
     assert mx.gauge("engine.kv_bytes_per_token").last == \
         eng.kv_bytes_per_token
     assert mx.gauge("engine.state_bytes").last == eng.state_bytes
@@ -395,7 +397,7 @@ COMMON = ["attn", "ssm/conv", "mlp/gate_up", "mlp/down", "kv_write", "embed",
 SCOPES = {"prefill": COMMON + ["ssm/scan", "ssm/state_write"],
           "chunk": COMMON + ["ssm/step", "ssm/step/state_write",
                              "ssm/step/jit(_call)",
-                             "attn/flash_decode_paged", "sample"]}
+                             "attn/jit(_paged_call)", "sample"]}
 
 
 @pytest.mark.parametrize("program,path", [

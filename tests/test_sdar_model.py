@@ -575,10 +575,11 @@ def test_spans_and_counters_of_a_block_engine():
                   if n == "serve.prefill") == [1, 2]
     dispatch = [a for n, a in sink.begun if n == "serve.decode_dispatch"]
     assert dispatch and all(
-        {"rows", "forwards", "block", "ctx_tokens", "round", "chunk"}
-        <= set(a) for a in dispatch)
+        {"rows", "forwards", "block", "ctx_tokens", "kv_pages", "round",
+         "chunk"} <= set(a) for a in dispatch)
     assert dispatch[0]["block"] == 4 and dispatch[0]["forwards"] == 6
     assert dispatch[0]["ctx_tokens"] == 8 + 4     # 9 // 4 * 4 + 6 // 4 * 4
+    assert dispatch[0]["kv_pages"] == 2 + 1       # pages of 8 up to 11, 7
     assert "serve.decode_round" in names and "serve.collect" in names
     # the first look counts everything since the engine was built
     counters = m.snapshot()["counters"]
