@@ -22,7 +22,9 @@ Event sources, all zero-cost when disabled:
   between a dispatch marker and its completion is drawn as a slice on
   a synthetic "device" track.
 - **compile events** — a process-wide ``jax.monitoring`` duration
-  listener (``/jax/core/compile/backend_compile_duration``) plus
+  listener (every trace, lowering, backend compile and cache load, kept
+  in the bounded :func:`compile_events` log and mirrored as ``jit.event``
+  marker spans; the recorder counts the backend compiles) plus
   explicit :func:`compile_watch` / :func:`instrument_jit` hooks at the
   jit entry points (models/decode.py, models/serving.py,
   models/train.py) that attach the FUNCTION NAME and triggering arg
@@ -567,26 +569,70 @@ def configure(*, enabled: bool = False,
 
 _monitoring_installed = False
 
-# the one backend-compile event gated on for counting; the other
-# /jax/core/compile/* phases (jaxpr trace, MLIR lowering) would triple-
-# count a single compilation
-_BACKEND_COMPILE_EVENT = "backend_compile"
+# what jax.monitoring reports of a compilation, by the short name the
+# ``jit.event`` marker and :func:`compile_events` carry. A cache load is
+# reported INSIDE its backend_compile event (jax times
+# compile_or_get_cached whole), so the two must not be added up; the
+# seconds of a trace are its own (the listener takes nested traces off).
+JIT_EVENTS = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+    "/jax/core/compile/backend_compile_duration": "backend_compile",
+    "/jax/compilation_cache/cache_retrieval_time_sec": "cache_load",
+}
+COMPILE_LOG_CAPACITY = 4096
+
+# (host perf_counter instant at the event's end, short name, seconds,
+# the function jax names or ""): kept whether or not anything listens,
+# because the listener runs only when jax traces or compiles
+_compile_log: deque = deque(maxlen=COMPILE_LOG_CAPACITY)
+
+
+def compile_events() -> list[tuple[float, str, float, str]]:
+    """Every trace, lowering, backend compile and cache load jax has
+    reported since :func:`install_monitoring_listener`, oldest first and
+    at most ``COMPILE_LOG_CAPACITY`` of them: what to read after a run
+    that was slower than its neighbours."""
+    return list(_compile_log)
 
 
 def _monitoring_listener(event: str, duration: float, **kw) -> None:
-    rec = active()
-    if rec is None or _BACKEND_COMPILE_EVENT not in event:
+    kind = JIT_EVENTS.get(event)
+    if kind is None:
         return
-    rec.compile_event("xla.backend_compile", float(duration),
-                      args={"event": event})
+    secs, fn = float(duration), str(kw.get("fun_name", ""))
+    now = time.perf_counter()
+    if kind == "trace":
+        # jax reports a jitted function traced inside another one first,
+        # then the outer one with the inner's time in its own: keep each
+        # trace's SELF seconds, so that the log's seconds add up
+        for t, k, inner, _ in reversed(_compile_log):
+            if t <= now - duration:
+                break
+            if k == "trace":
+                secs -= inner
+        secs = max(secs, 0.0)
+    _compile_log.append((now, kind, secs, fn))
+    rec = active()
+    if rec is not None and kind == "backend_compile":
+        # the one event counted: trace and lowering would triple-count
+        # a single compilation
+        rec.compile_event("xla.backend_compile", secs,
+                          args={"event": event})
+    if rec is not None or metricslib.get_metrics().mirror_traces:
+        # a marker, as compile_watch's: it opens when the event has
+        # ended, under whatever span encloses the call that compiled
+        with metricslib.span("jit.event", event=kind, secs=secs, fn=fn):
+            pass
 
 
 def install_monitoring_listener() -> bool:
     """Register the ``jax.monitoring`` duration listener exactly once
-    per process. The listener itself checks :func:`active`, so leaving
-    it registered when tracing is off costs one None check per compile
-    — registration is deliberately never undone (jax's unregister API
-    is private and the listener list is append-only in practice)."""
+    per process (``configure(enabled=True)`` and every ``EngineCore``
+    ask for it). It runs only when jax traces or compiles, so leaving
+    it registered costs a steady loop nothing — registration is
+    deliberately never undone (jax's unregister API is private and the
+    listener list is append-only in practice)."""
     global _monitoring_installed
     if _monitoring_installed:
         return True

@@ -1040,6 +1040,10 @@ class EngineCore:
         # speculative rounds touch positions up to pos+gamma; the page
         # allocation (NOT max_seq) must cover the overshoot
         self.spec_slack = gamma + 1 if draft_params is not None else 0
+        # whatever this engine's process traces or compiles from here on
+        # is kept in tracelib.compile_events(), a jit.event marker each
+        # when spans are mirrored: also the eager pieces no watch wraps
+        tracelib.install_monitoring_listener()
         # the engine holds its weights in the compute dtype: one cast
         # here instead of one in every prefill and every decode chunk
         # (the caller's tree is read, never donated or deleted)
@@ -1614,7 +1618,7 @@ class EngineCore:
         row = np.full((self.pages_per_seq,), self.trash, np.int32)
         row[:need] = pages
         self._table[slot] = row
-        self.cache["table"] = jnp.asarray(self._table)
+        self.cache["table"] = self._upload_table()
         T = int(req.prompt.size)
         padded = self._bucket_len(T)
         prompt = req.prompt
@@ -1633,7 +1637,7 @@ class EngineCore:
         # table: a full-range slice can alias the same buffer, and
         # _prefill_one donates its table — an alias would delete the
         # engine's live table with it
-        one["table"] = jnp.asarray(self._table[slot:slot + 1])
+        one["table"] = self._upload_table(slot)
         M = m * self.page_size
         span_attrs = dict(prompt_len=T, padded_len=padded, matched=M,
                           seq_id=req.seq_id, slot=slot,
@@ -1644,6 +1648,9 @@ class EngineCore:
         span_attrs["kv_bytes"] = lambda: (
             -(-(padded - M) // self.page_size) * self.page_size
             * self.kv_bytes_per_token)
+        # from the instant the request was due to this dispatch
+        span_attrs["queued_ms"] = lambda: (
+            time.perf_counter() - req.t_submit) * 1e3
         if m:
             # tail-only prefill: positions [M, padded) computed against
             # the mapped prefix pages; the matched span's compute AND
@@ -1684,9 +1691,9 @@ class EngineCore:
         self._prefill_total_tokens += T
         self._prefill_skip_tokens += M
         if self.draft_params is not None:
-            self.dcache["table"] = jnp.asarray(self._table)
+            self.dcache["table"] = self._upload_table()
             done = dict(self.dcache)
-            done["table"] = jnp.asarray(self._table[slot:slot + 1])
+            done["table"] = self._upload_table(slot)
             with tracelib.compile_watch("serving._prefill_one[draft]",
                                         _prefill_one,
                                         padded_len=padded):
@@ -1699,6 +1706,10 @@ class EngineCore:
                 if k != "table":
                     self.dcache[k] = v
         first_dev = None
+        # the install donates the cursors, which the chunk in flight may
+        # still own: a host span that can wait for the device
+        install = metricslib.span("serve.admit_row", seq_id=req.seq_id,
+                                  slot=slot)
         if self.cfg.block_len:
             # no token comes out of the prompt pass: the row's first block
             # is the prompt's remainder, then masks
@@ -1706,11 +1717,12 @@ class EngineCore:
             start = T // Bk * Bk
             first = np.full((Bk,), self.cfg.mask_id, np.int32)
             first[:T - start] = req.prompt[start:]
-            (self.pos, self.limit, self.blk, self.msk, self.fidx,
-             self.nfw) = _admit_block_row(
-                self.pos, self.limit, self.blk, self.msk, self.fidx,
-                self.nfw, slot, start, T + req.max_new, jnp.asarray(first),
-                T - start)
+            with install:
+                (self.pos, self.limit, self.blk, self.msk, self.fidx,
+                 self.nfw) = _admit_block_row(
+                    self.pos, self.limit, self.blk, self.msk, self.fidx,
+                    self.nfw, slot, start, T + req.max_new,
+                    jnp.asarray(first), T - start)
             self._slots[slot].cursor = start
             self.stats[req.seq_id]["blocks"] = []
         else:
@@ -1720,12 +1732,14 @@ class EngineCore:
                     else self.temperature)
             state = ({k: self.cache[k] for k in row_state}
                      if row_state else None)
-            (self.pos, self.limit, self.tokens, self.keys, self.temps,
-             first_dev, state) = _admit_row(
-                self.pos, self.limit, self.tokens, self.keys, self.temps,
-                logits, key, jnp.float32(max(temp, 1e-6)), slot, T,
-                req.max_new, state, row_state or None, eos_id=self.eos_id,
-                greedy=self.greedy, top_k=self.top_k)
+            with install:
+                (self.pos, self.limit, self.tokens, self.keys, self.temps,
+                 first_dev, state) = _admit_row(
+                    self.pos, self.limit, self.tokens, self.keys,
+                    self.temps, logits, key, jnp.float32(max(temp, 1e-6)),
+                    slot, T, req.max_new, state, row_state or None,
+                    eos_id=self.eos_id, greedy=self.greedy,
+                    top_k=self.top_k)
             if state is not None:
                 self.cache.update(state)
         st = self._slots[slot]
@@ -1854,14 +1868,24 @@ class EngineCore:
         or another row still maps stays allocated (the sharing arena's
         one release rule)."""
         st = self._slots[slot]
-        self._decref_pages(st.pages)
-        self._table[slot] = self.trash
-        self.cache["table"] = jnp.asarray(self._table)
-        if self.draft_params is not None:
-            self.dcache["table"] = jnp.asarray(self._table)
-        self._slots[slot] = _Slot()
-        self.pos = self.pos.at[slot].set(0)
-        self.limit = self.limit.at[slot].set(0)
+        with metricslib.span("serve.release", slot=slot,
+                             pages=len(st.pages)):
+            self._decref_pages(st.pages)
+            self._table[slot] = self.trash
+            self.cache["table"] = self._upload_table()
+            if self.draft_params is not None:
+                self.dcache["table"] = self._upload_table()
+            self._slots[slot] = _Slot()
+            self.pos = self.pos.at[slot].set(0)
+            self.limit = self.limit.at[slot].set(0)
+
+    def _upload_table(self, slot: int | None = None):
+        """The host's page table (``slot``'s one row of it) as a fresh
+        device array, dispatch-only: every upload of the table goes
+        through here, under its own span."""
+        rows = self._table if slot is None else self._table[slot:slot + 1]
+        with metricslib.span("serve.table_upload", bytes=rows.nbytes):
+            return jnp.asarray(rows)
 
     def _residency_release(self, seq_id: int) -> None:
         """Drop a row's blocks from the residency accounting (it
@@ -1872,35 +1896,37 @@ class EngineCore:
 
     def _finish(self, slot: int):
         st = self._slots[slot]
-        self._residency_release(st.seq_id)
-        self.finished[st.seq_id] = np.asarray(st.out, np.int32)
-        self._emit(kind="serve_finish", seq_id=st.seq_id, slot=slot,
-                   tokens=len(st.out), pages_freed=len(st.pages))
-        now = time.perf_counter()
-        rec_s = self.stats.get(st.seq_id)
-        if rec_s is not None:
-            rec_s["t_finish"] = now
-            rec_s["tokens"] = len(st.out)
-            rec_s["outcome"] = "ok"
-        rtr = reqtracelib.active()
-        if rtr is not None:
-            rtr.finish_request(st.seq_id, now)
-        m = metricslib.get_metrics()
-        if m.enabled:
-            dt = now - st.t_admit
-            m.histogram("serve.per_token_s").observe(
-                dt / max(1, len(st.out)))
-            if self.slo is not None and rec_s is not None \
-                    and rec_s["t_first"] is not None and len(st.out) > 1:
-                m.histogram(f"serve.tpot_s.p{st.priority}").observe(
-                    (now - rec_s["t_first"]) / (len(st.out) - 1))
-            m.counter("serve.finished").inc()
-            m.counter("serve.tokens").inc(len(st.out))
-            # shared pages don't free with the row — count only what
-            # the release will actually return to the arena
-            m.gauge("serve.free_pages").set(
-                len(self.free_pages) + self._row_freeable_pages(slot))
-        self._release_slot(slot)
+        with metricslib.span("serve.finish", seq_id=st.seq_id, slot=slot,
+                             tokens=len(st.out)):
+            self._residency_release(st.seq_id)
+            self.finished[st.seq_id] = np.asarray(st.out, np.int32)
+            self._emit(kind="serve_finish", seq_id=st.seq_id, slot=slot,
+                       tokens=len(st.out), pages_freed=len(st.pages))
+            now = time.perf_counter()
+            rec_s = self.stats.get(st.seq_id)
+            if rec_s is not None:
+                rec_s["t_finish"] = now
+                rec_s["tokens"] = len(st.out)
+                rec_s["outcome"] = "ok"
+            rtr = reqtracelib.active()
+            if rtr is not None:
+                rtr.finish_request(st.seq_id, now)
+            m = metricslib.get_metrics()
+            if m.enabled:
+                dt = now - st.t_admit
+                m.histogram("serve.per_token_s").observe(
+                    dt / max(1, len(st.out)))
+                if self.slo is not None and rec_s is not None \
+                        and rec_s["t_first"] is not None and len(st.out) > 1:
+                    m.histogram(f"serve.tpot_s.p{st.priority}").observe(
+                        (now - rec_s["t_first"]) / (len(st.out) - 1))
+                m.counter("serve.finished").inc()
+                m.counter("serve.tokens").inc(len(st.out))
+                # shared pages don't free with the row — count only what
+                # the release will actually return to the arena
+                m.gauge("serve.free_pages").set(
+                    len(self.free_pages) + self._row_freeable_pages(slot))
+            self._release_slot(slot)
 
     # -- preemption --------------------------------------------------------
 
@@ -2072,14 +2098,17 @@ class EngineCore:
         """Enqueue one ``chunk`` dispatch for the currently active rows
         and return the in-flight handle (participants, their start
         cursors, the un-read token block) — no readback here."""
-        # a true COPY, not np.asarray: on CPU that returns a zero-copy
-        # view of the device buffer, and _chunk_step DONATES it — an
-        # executable that honors the donation (cache-loaded ones do)
-        # overwrites the "snapshot" in place with the post-chunk cursors
-        # jaxlint: disable=host-sync-in-dispatch — the copy is the PR 2
-        # donation-alias fix; it syncs only on the PREVIOUS chunk's
-        # cursors, which _collect_chunk already resolved
-        pos_start = np.array(self.pos)
+        with metricslib.span("serve.cursor_sync", site="dispatch",
+                             round=self._round):
+            # a true COPY, not np.asarray: on CPU that returns a
+            # zero-copy view of the device buffer, and _chunk_step
+            # DONATES it — an executable that honors the donation
+            # (cache-loaded ones do) overwrites the "snapshot" in place
+            # with the post-chunk cursors
+            # jaxlint: disable=host-sync-in-dispatch — the copy is the
+            # PR 2 donation-alias fix; it syncs only on the PREVIOUS
+            # chunk's cursors, which _collect_chunk already resolved
+            pos_start = np.array(self.pos)
         parts = [i for i, s in enumerate(self._slots) if s.active]
         with metricslib.span("serve.decode_dispatch", chunk=self.chunk,
                              rows=len(parts), round=self._round,
@@ -2116,7 +2145,9 @@ class EngineCore:
             # track; host gaps between slices are admission bubbles
             rec.mark_complete("serve.chunk", t_disp,
                               {"chunk": self.chunk, "rows": len(parts)})
-        limit_new = np.asarray(self.limit)
+        with metricslib.span("serve.cursor_sync", site="collect",
+                             round=self._round):
+            limit_new = np.asarray(self.limit)
         if metricslib.get_metrics().enabled:
             self._count_route()
         # the chunk's tokens all became host-visible at THIS readback —
@@ -2124,18 +2155,20 @@ class EngineCore:
         # timing is invisible; the inter-token digest tiles stall
         # segments over the gaps BETWEEN these instants)
         now = time.perf_counter()
-        for i in parts:
-            st = self._slots[i]
-            if not st.active:
-                continue
-            valid = int(np.clip(limit_new[i] - pos_start[i], 0,
-                                self.chunk))
-            st.out.extend(int(t) for t in out[:valid, i])
-            rec_s = self.stats.get(st.seq_id)
-            if rec_s is not None and valid:
-                rec_s.setdefault("token_ts", []).extend([now] * valid)
-            if pos_start[i] + valid >= limit_new[i]:
-                self._finish(i)
+        with metricslib.span("serve.collect_rows", rows=len(parts),
+                             round=self._round):
+            for i in parts:
+                st = self._slots[i]
+                if not st.active:
+                    continue
+                valid = int(np.clip(limit_new[i] - pos_start[i], 0,
+                                    self.chunk))
+                st.out.extend(int(t) for t in out[:valid, i])
+                rec_s = self.stats.get(st.seq_id)
+                if rec_s is not None and valid:
+                    rec_s.setdefault("token_ts", []).extend([now] * valid)
+                if pos_start[i] + valid >= limit_new[i]:
+                    self._finish(i)
 
     def _dispatch_block(self):
         """:meth:`_dispatch_chunk` for a block-diffusion model: ``chunk``
@@ -2189,28 +2222,30 @@ class EngineCore:
             self._count_diffusion()
         B = self.cfg.block_len
         now = time.perf_counter()
-        for i in parts:
-            st = self._slots[i]
-            if not st.active:
-                continue
-            end = st.prompt_len + st.budget
-            rec_s = self.stats.get(st.seq_id)
-            new = 0
-            for f in np.nonzero(commit[:, i])[0]:
-                lo = max(0, st.prompt_len - st.cursor)
-                hi = min(B, end - st.cursor)
-                st.out.extend(int(t) for t in toks[f, i, lo:hi])
-                new += hi - lo
-                st.cursor += B
-                if rec_s is not None:
-                    rec_s["blocks"].append(
-                        np.stack([toks[f, i], fidx[f, i]]))
-            if new and len(st.out) == new:
-                self._first_tokens(i, now)
-            if rec_s is not None and new:
-                rec_s.setdefault("token_ts", []).extend([now] * new)
-            if st.cursor >= end:
-                self._finish(i)
+        with metricslib.span("serve.collect_rows", rows=len(parts),
+                             round=self._round):
+            for i in parts:
+                st = self._slots[i]
+                if not st.active:
+                    continue
+                end = st.prompt_len + st.budget
+                rec_s = self.stats.get(st.seq_id)
+                new = 0
+                for f in np.nonzero(commit[:, i])[0]:
+                    lo = max(0, st.prompt_len - st.cursor)
+                    hi = min(B, end - st.cursor)
+                    st.out.extend(int(t) for t in toks[f, i, lo:hi])
+                    new += hi - lo
+                    st.cursor += B
+                    if rec_s is not None:
+                        rec_s["blocks"].append(
+                            np.stack([toks[f, i], fidx[f, i]]))
+                if new and len(st.out) == new:
+                    self._first_tokens(i, now)
+                if rec_s is not None and new:
+                    rec_s.setdefault("token_ts", []).extend([now] * new)
+                if st.cursor >= end:
+                    self._finish(i)
         self._emit(kind="serve_block_chunk", round=self._round,
                    rows=len(parts), ctx_tokens=ctx, forwards=self.chunk,
                    blocks=int(commit[:, parts].sum()))
@@ -2329,24 +2364,28 @@ class EngineCore:
             rec.mark_complete("serve.spec_chunk", t_disp,
                               {"rounds": self.chunk,
                                "rows": len(parts)})
-        pos_np = np.asarray(self.pos)
-        limit_np = np.asarray(self.limit)
+        with metricslib.span("serve.cursor_sync", site="collect",
+                             round=self._round):
+            pos_np = np.asarray(self.pos)
+            limit_np = np.asarray(self.limit)
         now = time.perf_counter()
-        for i in parts:
-            st = self._slots[i]
-            if not st.active:
-                continue
-            accepted = 0
-            for k in range(advs.shape[0]):
-                v = int(advs[k, i])
-                if v:
-                    st.out.extend(int(t) for t in emits[k, i, :v])
-                    accepted += v
-            rec_s = self.stats.get(st.seq_id)
-            if rec_s is not None and accepted:
-                rec_s.setdefault("token_ts", []).extend([now] * accepted)
-            if pos_np[i] >= limit_np[i]:
-                self._finish(i)
+        with metricslib.span("serve.collect_rows", rows=len(parts),
+                             round=self._round):
+            for i in parts:
+                st = self._slots[i]
+                if not st.active:
+                    continue
+                accepted = 0
+                for k in range(advs.shape[0]):
+                    v = int(advs[k, i])
+                    if v:
+                        st.out.extend(int(t) for t in emits[k, i, :v])
+                        accepted += v
+                rec_s = self.stats.get(st.seq_id)
+                if rec_s is not None and accepted:
+                    rec_s.setdefault("token_ts", []).extend([now] * accepted)
+                if pos_np[i] >= limit_np[i]:
+                    self._finish(i)
 
     def service_round(self, *, decode: bool = True, chaos_index=None,
                       pre_collect=None) -> dict:
@@ -2435,8 +2474,10 @@ class EngineCore:
         if pre_collect is not None:
             pre_collect(inflight is not None)
         if inflight is not None:
-            # the readback (serve.decode_round / serve.spec_round) is
-            # the child; the host bookkeeping is this span's self time
+            # children: the readback (serve.decode_round /
+            # serve.spec_round), the second one of the cursors
+            # (serve.cursor_sync), the walk over the rows
+            # (serve.collect_rows, a finished row's serve.finish in it)
             with metricslib.span("serve.collect", rows=len(inflight[0]),
                                  round=self._round):
                 collect(inflight)
@@ -2732,7 +2773,7 @@ class EngineCore:
         row = np.full((self.pages_per_seq,), self.trash, np.int32)
         row[:bundle.n_pages] = pages
         self._table[slot] = row
-        self.cache["table"] = jnp.asarray(self._table)
+        self.cache["table"] = self._upload_table()
         if m < bundle.n_pages:
             idx = jnp.asarray(pages[m:], dtype=jnp.int32)
             for name, pools in list(self.cache.items()):
@@ -3047,7 +3088,14 @@ class ContinuousBatcher(EngineCore):
         """Submit the first ``due`` arrivals of the schedule."""
         for _ in range(due):
             t_arr, kw = pending_arrivals.popleft()
-            sid = self.submit(**kw)
+            asked = kw.get("seq_id")
+            with metricslib.span(
+                    "serve.submit",
+                    seq_id=self._next_id if asked is None else asked,
+                    # how long after the instant it was due
+                    late_ms=lambda: (time.perf_counter() - t_run0
+                                     - t_arr) * 1e3):
+                sid = self.submit(**kw)
             # the request entered on the SCHEDULE's clock, not when
             # the loop got around to draining it: TTFT, deadlines, and
             # the goodput must charge the queueing delay the
