@@ -38,6 +38,18 @@ and differ in how the blocks reach VMEM:
   (PERF.md section 6, PR 33).
 - GQA is native in both: the q block is the (g, head_dim) group sharing
   a kv head; the cache is streamed kv_heads-narrow. MHA is g = 1.
+
+A page's arithmetic is not free next to its copy: the float32
+``HIGHEST`` products (six bfloat16 passes each over K and V cast to
+float32, K transposed) took three times the page's copy (PERF.md section
+6, PRs 33 and 35). So :func:`_softmax_block` computes the same float32
+result from the products that are not zero: K and V stay in the cache's
+dtype, the scores are one bfloat16 product with float32 accumulation,
+and the float32 probabilities go in as three exact bfloat16 pieces
+stacked into one product against V (:func:`_exact_dot`, :func:`_split3`).
+What is left of a page is one chain (q·kᵀ, its row max, exp, p·v) whose
+latency outlasts the copy; the paged kernel overlaps it with the next
+page's q·kᵀ, and then runs at the copy's pace.
 """
 
 from __future__ import annotations
@@ -55,44 +67,104 @@ from hpc_patterns_tpu.ops.tiling import live_rows, resolve_interpret
 _NEG_INF = -1e30
 
 
+def _split3(x):
+    """float32 ``x`` as three bfloat16 pieces whose float32 sum is ``x``
+    exactly: hi = bf16(x), mid = bf16(x − hi), lo = bf16(x − hi − mid).
+    Each subtraction is exact in float32 and each residue has 8 fewer
+    significant bits than the last, so lo holds the last 8 of x's 24."""
+    hi = x.astype(jnp.bfloat16)
+    rest = x - hi.astype(jnp.float32)
+    mid = rest.astype(jnp.bfloat16)
+    lo = (rest - mid.astype(jnp.float32)).astype(jnp.bfloat16)
+    return hi, mid, lo
+
+
+def _exact_dot(a, b, contract):
+    """``lax.dot_general(a, b)`` over ``contract`` = (a's dim, b's dim),
+    float32 out, with the terms of ``Precision.HIGHEST`` that are not
+    zero, in ONE product: ``b`` (the page) is bfloat16 already, so
+    HIGHEST's six bfloat16 passes reduce to a's three pieces against b.
+    A float32 ``a`` (g rows) is split by :func:`_split3`, the pieces
+    stacked to 3g rows (one push of b into the MXU serves all three) and
+    the three row blocks summed; a bfloat16 ``a`` is one exact product
+    with float32 accumulation. A float32 ``b`` (float32 pools, off the
+    chip's cells) takes HIGHEST itself."""
+    dims = (((contract[0],), (contract[1],)), ((), ()))
+    if b.dtype == jnp.float32:
+        return lax.dot_general(a.astype(jnp.float32), b, dims,
+                               precision=lax.Precision.HIGHEST,
+                               preferred_element_type=jnp.float32)
+    if a.dtype != jnp.float32:
+        return lax.dot_general(a, b, dims,
+                               preferred_element_type=jnp.float32)
+    g = a.shape[0]
+    out = lax.dot_general(jnp.concatenate(_split3(a), axis=0), b, dims,
+                          preferred_element_type=jnp.float32)
+    return out[:g] + out[g:2 * g] + out[2 * g:]
+
+
+def _scores(q, k, ks, scale: float):
+    """A block's scores, float32 (g, block_s): q·kᵀ contracting on D of
+    both, times ``scale`` and, for an int8 block, its lane-major
+    (1, block_s) per-row scales ``ks`` (None otherwise)."""
+    if ks is not None:
+        k = k.astype(jnp.bfloat16)
+    s = _exact_dot(q, k, (1, 1)) * scale
+    if ks is not None:
+        s = s * ks.astype(jnp.float32)
+    return s
+
+
+def _update(s, v, vs, m, l, acc, block_start, pos):
+    """The online-softmax state (m, l, acc) updated by a block's scores
+    ``s`` and values ``v`` (with lane-major scales ``vs`` for an int8
+    block, None otherwise), the block at logical rows [block_start, ...),
+    rows past ``pos`` masked."""
+    k_pos = block_start + lax.broadcasted_iota(jnp.int32, s.shape, 1)
+    s = jnp.where(k_pos <= pos, s, _NEG_INF)
+    m_new = jnp.maximum(m, s.max(axis=-1, keepdims=True))
+    p = jnp.exp(s - m_new)
+    rescale = jnp.exp(m - m_new)
+    l = l * rescale + p.sum(axis=-1, keepdims=True)
+    if vs is not None:
+        p = p * vs.astype(jnp.float32)
+        v = v.astype(jnp.bfloat16)
+    return m_new, l, acc * rescale + _exact_dot(p, v, (1, 0))
+
+
 def _softmax_block(q_ref, k_ref, v_ref, ks_ref, vs_ref, m_ref, l_ref,
                    acc_ref, block_start, pos, scale: float,
                    quantized: bool):
     """One online-softmax update over the cache block at logical rows
     [block_start, block_start + block_s): THE streamed-attention math,
-    shared by the linear kernel (one block per grid step) and the paged
-    kernel (``pages_per_step`` page blocks per grid step).
+    :func:`_scores` then :func:`_update`, shared by the linear kernel
+    (one block per grid step) and the paged kernel (one page of one K/V
+    head at a time, its scores a page ahead).
 
-    f32 score/value math (unlike the training kernel's native-dtype
-    matmuls): a decode step is cache-READ-bound — the f32 compute is
-    free next to the bf16 stream, and it reproduces the gather path's
-    f32 einsum numerics so greedy tokens match. ``quantized``: per-row
-    dequant folded into the LANE axis of the score and probability
-    blocks — s_ij = (q·k8_j)·kscale_j and out = (p∘vscaleᵀ)·v8; the
-    (1, block_s) scale rows ride lane-major, and the (block_s, D)
-    tiles are never rescaled elementwise (a sublane-oriented
-    (block_s, 1) scale multiply measured ~3x slower than bf16)."""
-    q = q_ref[:].astype(jnp.float32)
-    k = k_ref[:].astype(jnp.float32)
-    v = v_ref[:].astype(jnp.float32)
-    s = jnp.dot(q, k.T, preferred_element_type=jnp.float32,
-                precision=lax.Precision.HIGHEST) * scale
-    if quantized:
-        s = s * ks_ref[:].astype(jnp.float32)
-    k_pos = block_start + lax.broadcasted_iota(jnp.int32, s.shape, 1)
-    s = jnp.where(k_pos <= pos, s, _NEG_INF)
-    m = m_ref[:]
-    m_new = jnp.maximum(m, s.max(axis=-1, keepdims=True))
-    p = jnp.exp(s - m_new)
-    rescale = jnp.exp(m - m_new)
-    m_ref[:] = m_new
-    l_ref[:] = l_ref[:] * rescale + p.sum(axis=-1, keepdims=True)
-    if quantized:
-        p = p * vs_ref[:].astype(jnp.float32)
-    acc_ref[:] = acc_ref[:] * rescale + jnp.dot(
-        p, v, preferred_element_type=jnp.float32,
-        precision=lax.Precision.HIGHEST,
-    )
+    The float32 result of the float32 ``HIGHEST`` products, from the
+    products that are not zero (:func:`_exact_dot`): the (block_s, D)
+    K and V tiles are used in the cache's dtype as they are (int8 →
+    bfloat16 is exact), never cast to float32 nor transposed.
+    Scores: q·kᵀ contracting on D of both, ONE bfloat16 product with
+    float32 accumulation when q is bfloat16 (the serving path: the
+    HIGHEST product term for term, equal to it to the bit on the chip),
+    q's three pieces stacked when it is float32. Values: the float32
+    probabilities p split exactly into three bfloat16 pieces, stacked to
+    3g rows, ONE product against V, the three row blocks summed: the
+    same three partial products HIGHEST sums, in another order. A page
+    so costs one push of K and one of V into the MXU where HIGHEST on
+    float32 operands made six of each and a transpose (PERF.md section
+    6, PR 35).
+    ``quantized``: per-row dequant folded into the LANE axis of the
+    score and probability blocks — s_ij = (q·k8_j)·kscale_j and out =
+    (p∘vscaleᵀ)·v8 (p is scaled before its split); the (1, block_s)
+    scale rows ride lane-major, and the (block_s, D) tiles are never
+    rescaled elementwise (a sublane-oriented (block_s, 1) scale
+    multiply measured ~3x slower than bf16)."""
+    ks, vs = (ks_ref[:], vs_ref[:]) if quantized else (None, None)
+    m_ref[:], l_ref[:], acc_ref[:] = _update(
+        _scores(q_ref[:], k_ref[:], ks, scale), v_ref[:], vs, m_ref[:],
+        l_ref[:], acc_ref[:], block_start, pos)
 
 
 def _decode_kernel(pos_ref, q_ref, k_ref, v_ref, *rest, scale: float,
@@ -227,22 +299,6 @@ def flash_decode_attention(
     return out.reshape(B, H, D)
 
 
-class _At:
-    """``ref[at]`` standing where :func:`_softmax_block` takes a whole
-    block's ref: read and written through ``ref`` at that index (a sliced
-    VIEW, ``ref.at[at]``, of a block whose group is no whole number of
-    tiles does not lower)."""
-
-    def __init__(self, ref, *at):
-        self.ref, self.at = ref, at
-
-    def __getitem__(self, _):
-        return self.ref[self.at]
-
-    def __setitem__(self, _, value):
-        self.ref[self.at] = value
-
-
 def _decode_kernel_paged(rows_ref, pos_ref, table_ref, q_ref, *rest,
                          scale: float, pages: int, depth: int,
                          quantized: bool, ragged: bool):
@@ -301,21 +357,42 @@ def _decode_kernel_paged(rows_ref, pos_ref, table_ref, q_ref, *rest,
     l_ref[:] = jnp.zeros(l_ref.shape, jnp.float32)
     acc_ref[:] = jnp.zeros(acc_ref.shape, jnp.float32)
 
-    def attend(page, carry):
+    def scores(slot):
+        return [_scores(q_ref[h], bufs[0][slot, h],
+                        bufs[2][slot, h] if quantized else None, scale)
+                for h in range(kv_heads)]
+
+    # The scores run a page ahead of the update: a page's two products
+    # are one chain (q·kᵀ, its row max, exp, p·v), and its latency, not
+    # the MXU's pushes, set the pace a page; with the next page's q·kᵀ
+    # beside this page's update in one block the two chains overlap.
+    # Item t + 1 is waited for while item t is attended over, so the
+    # ring needs two slots (``depth`` >= 2).
+    n_pages = live_pages(r)
+    for c in copies(r, 0, cur[3] % depth):
+        c.wait()
+
+    def attend(page, s):
         fetch()
         slot = cur[3] % depth
-        for c in copies(r, page, slot):
-            c.wait()
-        for h in range(kv_heads):
-            _softmax_block(
-                _At(q_ref, h), *(_At(buf, slot, h) for buf in bufs),
-                *(() if quantized else (None, None)),
-                _At(m_ref, h), _At(l_ref, h), _At(acc_ref, h),
-                page * page_size, pos, scale, quantized)
-        cur[3] = cur[3] + 1
-        return carry
+        last = page + 1 >= n_pages
+        ahead = jnp.where(last, slot, (cur[3] + 1) % depth)
 
-    lax.fori_loop(0, live_pages(r), attend, 0)
+        @pl.when(jnp.logical_not(last))
+        def _():
+            for c in copies(r, page + 1, ahead):
+                c.wait()
+
+        s_ahead = scores(ahead)     # the row's last page: its own again
+        for h in range(kv_heads):
+            m_ref[h], l_ref[h], acc_ref[h] = _update(
+                s[h], bufs[1][slot, h],
+                bufs[3][slot, h] if quantized else None,
+                m_ref[h], l_ref[h], acc_ref[h], page * page_size, pos)
+        cur[3] = cur[3] + 1
+        return s_ahead
+
+    lax.fori_loop(0, n_pages, attend, scores(cur[3] % depth))
     o_ref[:] = acc_ref[:] / jnp.maximum(l_ref[:], 1e-30)
 
 
@@ -417,10 +494,12 @@ def flash_decode_paged(
     (the CAPACITY levers stack: int8 halves page bytes, paging frees
     the allocate-for-longest waste).
 
-    ``pages_per_step``: pages in flight at a time, the depth of the
-    kernel's ring of page buffers (``pages_per_step`` x kv_heads x
-    page bytes of VMEM for K and for V). Default: the linear kernel's
-    2048-row streaming block in pages. 1 fetches and attends in turn.
+    ``pages_per_step``: the depth of the kernel's ring of page buffers
+    (``pages_per_step`` x kv_heads x page bytes of VMEM for K and for
+    V), at least 2: the page after the one attended over is waited for
+    while it is attended over (its scores run a page ahead), and
+    ``pages_per_step`` - 2 more are in flight. Default: the linear
+    kernel's 2048-row streaming block in pages.
     """
     B, H, D = q.shape
     n_pool, Hkv, P, Dp = k_pool.shape
@@ -450,7 +529,7 @@ def flash_decode_paged(
         q, k_pool, v_pool, table,
         jnp.asarray(pos, jnp.int32).reshape(B if ragged else 1), active,
         k_scale_pool, v_scale_pool, float(scale),
-        max(1, min(int(pages_per_step), pages)),
+        max(2, min(int(pages_per_step), pages)),
         resolve_interpret(interpret, "flash_decode_paged"))
 
 

@@ -207,6 +207,38 @@ def test_flash_decode_paged_compiles_over_the_live_rows_at_the_cells_shapes(
     assert len(re.findall(r" reduce-window\(", text)) == LIVE_LIST_SUMS
 
 
+# the two other forms of the page's arithmetic (ops/flash_decode._exact_dot):
+# int8 pools, their pages cast to bfloat16 and their per-row scales copied
+# beside them, at serve-gen's and serve-assist's shapes; and a float32 q,
+# whose three bfloat16 pieces are stacked into one product
+@pytest.mark.parametrize("pool,slots,q_heads,pool_dtype,q_dtype", [
+    ((385, 2, 256, 128), 32, 24, jnp.int8, jnp.bfloat16),
+    ((513, 4, 256, 128), 32, 20, jnp.int8, jnp.bfloat16),
+    ((385, 2, 256, 128), 32, 24, jnp.bfloat16, jnp.float32),
+])
+def test_flash_decode_paged_compiles_on_int8_pools_and_a_float32_q(
+        one_chip, no_compile_cache, pool, slots, q_heads, pool_dtype,
+        q_dtype):
+    shape = lambda *s, dt=jnp.bfloat16: jax.ShapeDtypeStruct(
+        s, dt, sharding=one_chip)
+    scales = ([shape(pool[0], pool[1], 1, pool[2], dt=jnp.float32)] * 2
+              if pool_dtype == jnp.int8 else [None, None])
+
+    def layer(q, kp, vp, table, pos, active, ks, vs):
+        return flash_decode_paged(q, kp, vp, table, pos, active=active,
+                                  k_scale_pool=ks, v_scale_pool=vs,
+                                  scale=128 ** -0.5, interpret=False)
+
+    text = jax.jit(layer).lower(
+        shape(slots, q_heads, 128, dt=q_dtype), shape(*pool, dt=pool_dtype),
+        shape(*pool, dt=pool_dtype), shape(slots, 16, dt=jnp.int32),
+        shape(slots, dt=jnp.int32), shape(slots, dt=jnp.bool_), *scales
+    ).compile().as_text()
+    calls = [line for line in text.splitlines() if "tpu_custom_call" in line]
+    assert len(calls) == 1
+    assert re.search(r"%flash_decode_paged[.\d]* = ", calls[0])
+
+
 # the serving cells' K/V pools (pages + the trash page, K/V heads, page
 # 256, head 128) with their slots and query heads: serve-assist, serve-gen
 # (serve-code's pool is the same with 161 pages), serve-chat

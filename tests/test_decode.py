@@ -1155,14 +1155,57 @@ class TestPoolWrite:
         assert collectives()["all-gather"] > ours["all-gather"]
 
 
+def _parent_softmax_block(q_ref, k_ref, v_ref, ks_ref, vs_ref, m_ref, l_ref,
+                          acc_ref, block_start, pos, scale, quantized):
+    """The oracle of the kernels' arithmetic: ``_softmax_block`` as PR 34
+    left it, two float32 ``Precision.HIGHEST`` products over the page cast
+    to float32 (K transposed)."""
+    from jax import lax
+
+    from hpc_patterns_tpu.ops.flash_decode import _NEG_INF
+
+    q = q_ref[:].astype(jnp.float32)
+    k = k_ref[:].astype(jnp.float32)
+    v = v_ref[:].astype(jnp.float32)
+    s = jnp.dot(q, k.T, preferred_element_type=jnp.float32,
+                precision=lax.Precision.HIGHEST) * scale
+    if quantized:
+        s = s * ks_ref[:].astype(jnp.float32)
+    k_pos = block_start + lax.broadcasted_iota(jnp.int32, s.shape, 1)
+    s = jnp.where(k_pos <= pos, s, _NEG_INF)
+    m = m_ref[:]
+    m_new = jnp.maximum(m, s.max(axis=-1, keepdims=True))
+    p = jnp.exp(s - m_new)
+    rescale = jnp.exp(m - m_new)
+    m_ref[:] = m_new
+    l_ref[:] = l_ref[:] * rescale + p.sum(axis=-1, keepdims=True)
+    if quantized:
+        p = p * vs_ref[:].astype(jnp.float32)
+    acc_ref[:] = acc_ref[:] * rescale + jnp.dot(
+        p, v, preferred_element_type=jnp.float32,
+        precision=lax.Precision.HIGHEST,
+    )
+
+
+def _assert_within_1e6(got, want, err_msg=""):
+    """``got`` within 1e-6 of ``want``, relative to ``want``'s scale: the
+    bound the exact products are held to against the HIGHEST ones (the
+    same partial products summed in another order)."""
+    want = np.asarray(want)
+    np.testing.assert_allclose(np.asarray(got), want, rtol=1e-6,
+                               atol=1e-6 * np.abs(want).max(),
+                               err_msg=err_msg)
+
+
 def _parent_kernel_paged(pos_ref, table_ref, q_ref, *rest, scale, page_size,
                          unroll, quantized, hkv_per_row):
     """The paged kernel as it stood before the fetches followed the data
-    (PR 32's tree): ``unroll`` page blocks a grid step as separate refs,
-    every row walked, pages past the position clamped to the last."""
+    (PR 32's tree), with PR 34's arithmetic: ``unroll`` page blocks a grid
+    step as separate refs, every row walked, pages past the position
+    clamped to the last."""
     from jax.experimental import pallas as pl
 
-    from hpc_patterns_tpu.ops.flash_decode import _NEG_INF, _softmax_block
+    from hpc_patterns_tpu.ops.flash_decode import _NEG_INF
 
     del table_ref
     U = unroll
@@ -1189,9 +1232,9 @@ def _parent_kernel_paged(pos_ref, table_ref, q_ref, *rest, scale, page_size,
 
         @pl.when(start <= pos)
         def _(j=j, start=start):
-            _softmax_block(q_ref, k_refs[j], v_refs[j], ks_refs[j],
-                           vs_refs[j], m_ref, l_ref, acc_ref, start, pos,
-                           scale, quantized)
+            _parent_softmax_block(q_ref, k_refs[j], v_refs[j], ks_refs[j],
+                                  vs_refs[j], m_ref, l_ref, acc_ref, start,
+                                  pos, scale, quantized)
 
     @pl.when(si == pl.num_programs(1) - 1)
     def _():
@@ -1254,15 +1297,16 @@ def _parent_flash_decode_paged(q, k_pool, v_pool, table, pos, *,
 class TestPagedFetchSchedule:
     """``flash_decode_paged`` visits the rows that are live and, of each,
     the pages up to its position: whatever ``active``, ``pos`` and the
-    table hold, a live row's output is the parent kernel's to the bit
-    (the same updates in the same order) and the gather route's to
-    rounding, and a row that is not live comes out as zeros."""
+    table hold, a live row's output is the parent kernel's (the float32
+    HIGHEST products) to 1e-6, the call's over every row to the bit (the
+    same updates in the same order) and the gather route's to rounding,
+    and a row that is not live comes out as zeros."""
 
     P, D, PAGES, B = 8, 16, 16, 6
     # a page's first row and its last, early and on the last page of 16
     ENDS = (0, 7, 8, 23, 120, 127)
 
-    def _draw(self, case, group, kv_heads, dtype, fold):
+    def _draw(self, case, group, kv_heads, dtype, fold, q_dtype="float32"):
         P, D, pages, B = self.P, self.D, self.PAGES, self.B
         rng = np.random.default_rng(case)
         n_pool = B * pages + 1                    # the last: the trash page
@@ -1279,30 +1323,41 @@ class TestPagedFetchSchedule:
         else:
             pools = {n: p.astype(dtype) for n, p in pools.items()}
         q = (f32(B, fold, group // fold * kv_heads, D) if fold > 1
-             else f32(B, group * kv_heads, D))
+             else f32(B, group * kv_heads, D)).astype(q_dtype)
         table = jnp.asarray(rng.permutation(n_pool - 1).reshape(B, pages),
                             jnp.int32)
         return rng, q, pools, table
 
+    # q float32 takes the split of q; bfloat16, as the serving path hands
+    # it (project_qkv / apply_rope return the compute dtype), one product
     @pytest.mark.parametrize(
-        "case,group,kv_heads,dtype,pages_per_step,pos_form", [
-            (0, 5, 4, "bfloat16", 8, "ragged"),
-            (1, 5, 2, "int8", 1, "ragged"),
-            (2, 5, 4, "bfloat16", 3, 0),
-            (3, 12, 2, "bfloat16", 8, "ragged"),
-            (4, 12, 2, "int8", 3, "ragged"),
-            (5, 12, 4, "bfloat16", 1, 7),
-            (6, 12, 2, "int8", 8, 8),
-            (7, 16, 2, "bfloat16", 8, "ragged"),
-            (8, 16, 4, "int8", 8, "ragged"),
-            (9, 16, 2, "bfloat16", 3, 120),
-            (10, 16, 4, "int8", 1, 127),
-            (11, 32, 4, "bfloat16", 8, "ragged"),
-            (12, 32, 2, "bfloat16", 1, "ragged"),
-            (13, 32, 4, "bfloat16", 3, "ragged"),
+        "case,group,kv_heads,dtype,pages_per_step,pos_form,q_dtype", [
+            (0, 5, 4, "bfloat16", 8, "ragged", "float32"),
+            (1, 5, 2, "int8", 1, "ragged", "float32"),
+            (2, 5, 4, "bfloat16", 3, 0, "float32"),
+            (3, 12, 2, "bfloat16", 8, "ragged", "float32"),
+            (4, 12, 2, "int8", 3, "ragged", "float32"),
+            (5, 12, 4, "bfloat16", 1, 7, "float32"),
+            (6, 12, 2, "int8", 8, 8, "float32"),
+            (7, 16, 2, "bfloat16", 8, "ragged", "float32"),
+            (8, 16, 4, "int8", 8, "ragged", "float32"),
+            (9, 16, 2, "bfloat16", 3, 120, "float32"),
+            (10, 16, 4, "int8", 1, 127, "float32"),
+            (11, 32, 4, "bfloat16", 8, "ragged", "float32"),
+            (12, 32, 2, "bfloat16", 1, "ragged", "float32"),
+            (13, 32, 4, "bfloat16", 3, "ragged", "float32"),
+            (14, 5, 4, "bfloat16", 8, "ragged", "bfloat16"),
+            (15, 5, 4, "int8", 3, "ragged", "bfloat16"),
+            (16, 12, 2, "bfloat16", 8, "ragged", "bfloat16"),
+            (17, 12, 2, "int8", 8, 127, "bfloat16"),
+            (18, 16, 2, "bfloat16", 3, "ragged", "bfloat16"),
+            (19, 16, 2, "int8", 1, "ragged", "bfloat16"),
+            (20, 32, 4, "bfloat16", 8, "ragged", "bfloat16"),
+            (21, 32, 4, "int8", 3, "ragged", "bfloat16"),
         ])
     def test_live_rows_equal_the_parent_kernel_and_idle_rows_are_zero(
-            self, case, group, kv_heads, dtype, pages_per_step, pos_form):
+            self, case, group, kv_heads, dtype, pages_per_step, pos_form,
+            q_dtype):
         from types import SimpleNamespace
 
         from hpc_patterns_tpu.models.decode import _paged_attend_gather
@@ -1312,7 +1367,8 @@ class TestPagedFetchSchedule:
 
         fold = 4 if group == 32 else 1    # a block of 4 folded into 8
         B = self.B
-        rng, q, pools, table = self._draw(case, group, kv_heads, dtype, fold)
+        rng, q, pools, table = self._draw(case, group, kv_heads, dtype, fold,
+                                          q_dtype)
         scale = self.D ** -0.5
         kw = dict(k_scale_pool=pools.get("k_scale"),
                   v_scale_pool=pools.get("v_scale"), scale=scale,
@@ -1340,14 +1396,14 @@ class TestPagedFetchSchedule:
             SimpleNamespace(kv_heads=kv_heads, head_dim=self.D), scale)))
 
         every = np.asarray(attend())
-        np.testing.assert_array_equal(every, parent)
+        _assert_within_1e6(every, parent)
         np.testing.assert_allclose(every, gather, atol=2e-5)
         half = np.zeros(B, bool)
         half[rng.permutation(B)[:B // 2]] = True
         for live in (np.ones(B, bool), np.zeros(B, bool),
                      np.arange(B) == case % B, half):
             got = np.asarray(attend(active=jnp.asarray(live)))
-            np.testing.assert_array_equal(got[live], parent[live])
+            np.testing.assert_array_equal(got[live], every[live])
             assert not got[~live].any(), "an idle row's output is not zero"
 
     @pytest.mark.parametrize("dtype", ["bfloat16", "int8"])
@@ -1379,3 +1435,128 @@ class TestPagedFetchSchedule:
         got = run(dirty, strewn)
         assert np.isfinite(got).all()
         np.testing.assert_array_equal(got, clean)
+
+
+def _one_page(block, q, k, v, scales, state, start, pos, scale):
+    """``block`` (a ``_softmax_block``) run once, interpreted, over one
+    page from the online-softmax state ``state`` = (m, l, acc): returns
+    the state it leaves."""
+    from jax.experimental import pallas as pl
+
+    quantized = scales is not None
+    n_in = 5 if quantized else 3
+
+    def kernel(*refs):
+        ins, (m_in, l_in, a_in), (m, l, acc) = (
+            refs[:n_in], refs[n_in:n_in + 3], refs[n_in + 3:])
+        ks, vs = ins[3:] if quantized else (None, None)
+        m[:], l[:], acc[:] = m_in[:], l_in[:], a_in[:]
+        block(ins[0], ins[1], ins[2], ks, vs, m, l, acc, start, pos, scale,
+              quantized)
+
+    return pl.pallas_call(
+        kernel, interpret=True,
+        out_shape=tuple(jax.ShapeDtypeStruct(x.shape, x.dtype)
+                        for x in state),
+    )(q, k, v, *(scales or ()), *state)
+
+
+class TestExactProducts:
+    """``_softmax_block``'s two products are the float32 HIGHEST products'
+    nonzero terms in one bfloat16 product each: the values are HIGHEST's
+    to 1e-6 (the same three partial products of p's pieces summed in
+    another order), and the split of p into three bfloat16 pieces loses
+    nothing. The scores where q is bfloat16 are HIGHEST's one nonzero
+    product; on the chip both are one MXU product (equal to the bit there:
+    PERF.md section 6, PR 35), but this CPU sums a bfloat16 dot in another
+    order than a float32 one, so here they are held to 1e-6 as well."""
+
+    P, D = 16, 16
+
+    @pytest.mark.parametrize("low", [0.0, 20.0, 40.0])
+    def test_the_split_of_p_is_exact(self, low):
+        """hi + mid + lo == p in float32 for p in (0, 1]: what exp leaves
+        after the running max, down to e^-65 (and 1 itself). Below 2^-102
+        the last piece can be a subnormal, which is flushed, as in
+        HIGHEST's own split: a weight 1e-31 of the row's largest."""
+        from hpc_patterns_tpu.ops.flash_decode import _split3
+
+        rng = np.random.default_rng(int(low))
+        p = np.exp(-rng.uniform(low, low + 25.0, 4096)).astype(np.float32)
+        p[:4] = (1.0, np.nextafter(np.float32(1), np.float32(0)),
+                 np.float32(2.0 ** -126), np.float32(0.1))
+        pieces = _split3(jnp.asarray(p))
+        assert all(x.dtype == jnp.bfloat16 for x in pieces)
+        hi, mid, lo = (np.asarray(x, np.float32) for x in pieces)
+        np.testing.assert_array_equal((hi + mid) + lo, p)
+        np.testing.assert_array_equal(hi + (mid + lo), p)
+
+    @pytest.mark.parametrize("q_dtype", ["bfloat16", "float32"])
+    def test_scores_are_the_highest_product(self, q_dtype):
+        from jax import lax
+
+        from hpc_patterns_tpu.ops.flash_decode import _exact_dot
+
+        rng = np.random.default_rng(1)
+        q = jnp.asarray(rng.standard_normal((12, 128)), q_dtype)
+        k = jnp.asarray(rng.standard_normal((256, 128)), jnp.bfloat16)
+        got = np.asarray(_exact_dot(q, k, (1, 1)))
+        want = np.asarray(jnp.dot(q.astype(jnp.float32),
+                                  k.astype(jnp.float32).T,
+                                  precision=lax.Precision.HIGHEST))
+        _assert_within_1e6(got, want)
+
+    @pytest.mark.parametrize("group", [5, 12, 16, 32])
+    @pytest.mark.parametrize("form", ["bfloat16", "q float32", "int8"])
+    def test_one_page_update_is_the_parents(self, group, form):
+        """One online-softmax update from a running state, the row's
+        position inside the page: m, l (every score goes into l) and acc
+        the parent's to 1e-6."""
+        from hpc_patterns_tpu.ops.flash_decode import _softmax_block
+
+        P, D = self.P, self.D
+        rng = np.random.default_rng(group)
+        f32 = lambda *s: jnp.asarray(rng.standard_normal(s), jnp.float32)
+        q = f32(group, D).astype("float32" if form == "q float32"
+                                 else "bfloat16")
+        k, v, scales = f32(P, D), f32(P, D), None
+        if form == "int8":
+            k, v = (jnp.asarray(rng.integers(-127, 128, (P, D)), jnp.int8)
+                    for _ in range(2))
+            scales = (jnp.abs(f32(1, P)) / 64, jnp.abs(f32(1, P)) / 64)
+        else:
+            k, v = k.astype(jnp.bfloat16), v.astype(jnp.bfloat16)
+        state = (f32(group, 1), jnp.abs(f32(group, 1)) + 1, f32(group, D))
+        args = (q, k, v, scales, state, 3 * P, 3 * P + 9, D ** -0.5)
+        m, l, acc = _one_page(_softmax_block, *args)
+        pm, pl_, pacc = _one_page(_parent_softmax_block, *args)
+        _assert_within_1e6(m, pm)
+        _assert_within_1e6(l, pl_)
+        _assert_within_1e6(acc, pacc)
+        assert not np.array_equal(np.asarray(m), np.asarray(state[0]))
+
+    @pytest.mark.parametrize("group", [5, 12, 16, 32])
+    @pytest.mark.parametrize("dtype", ["bfloat16", "int8"])
+    def test_the_linear_kernel_holds_the_same_bounds(self, monkeypatch,
+                                                     group, dtype):
+        """``flash_decode_attention`` shares the arithmetic: against
+        itself with the parent's ``_softmax_block`` swapped in, at a
+        position inside its second block."""
+        from hpc_patterns_tpu.models.decode import _quantize_rows
+        from hpc_patterns_tpu.ops import flash_decode as F
+
+        B, Hkv, S, D = 2, 2, 64, self.D
+        rng = np.random.default_rng(group + 100)
+        f32 = lambda *s: jnp.asarray(rng.standard_normal(s), jnp.float32)
+        q = f32(B, group * Hkv, D).astype(jnp.bfloat16)
+        kc, vc, kw = f32(B, Hkv, S, D), f32(B, Hkv, S, D), {}
+        if dtype == "int8":
+            (kc, ks), (vc, vs) = (_quantize_rows(c, "int8") for c in (kc, vc))
+            kw = dict(k_scale=ks, v_scale=vs)
+        else:
+            kc, vc = kc.astype(jnp.bfloat16), vc.astype(jnp.bfloat16)
+        run = lambda: np.asarray(F.flash_decode_attention(
+            q, kc, vc, jnp.int32(45), block_s=32, **kw))
+        got = run()
+        monkeypatch.setattr(F, "_softmax_block", _parent_softmax_block)
+        _assert_within_1e6(got, run())
