@@ -21,6 +21,7 @@ from chipbench import spans  # noqa: E402
 from chipbench import weights_falcon_h1 as W  # noqa: E402
 from chipbench.counts import falcon_h1 as counts  # noqa: E402
 from chipbench.readers import span_attrs  # noqa: E402
+import manifest_rules as rules  # noqa: E402
 
 FIX = "tests/chipbench/fixtures"
 PEAKS = {"bf16_flops_per_s": 1e12, "hbm_bytes_per_s": 1e11}
@@ -327,36 +328,28 @@ def test_the_vocabulary_comes_in_blocks_that_tile_it():
         rtol=1e-4, atol=1e-5)
 
 
-# -- the manifest: this PR's entries after the accepted ones ---------------------
+# -- the manifest: this configuration's entries, found by name ------------------
 
 NEW = ["h1_prefill_mfu_pct", "h1_decode_mfu_pct", "h1_attn_prefill_ms",
        "h1_mlp_prefill_ms", "h1_attn_decode_ms_chunk",
        "h1_mlp_decode_ms_chunk", "h1_ssm_scan_prefill_roofline",
        "h1_ssm_step_decode_roofline", "h1_flash_fwd_roofline",
        "h1_flash_decode_paged_roofline", "h1_kv_over_state_bytes"]
+OWN = {"configs": ["falcon-h1-34b-stage"], "workloads": ["serve-assist"],
+       "per_layer": NEW}
+# the manifest as this configuration left it: less OWN, as it found it
+LEFT = json.loads((ROOT / f"{FIX}/accepted-manifest-pr31.json").read_text())
 
 
 def test_this_prs_entries_come_after_the_accepted_ones():
-    bench = json.loads((ROOT / "BENCHMARK.json").read_text())
-    assert bench["configs"][-1]["name"] == "falcon-h1-34b-stage"
-    cell = bench["workloads"][-1]
-    assert (cell["name"], cell["config"], cell["chips"]) == (
-        "serve-assist", "falcon-h1-34b-stage", 1)
-    assert len(cell["why"]) <= 200
-    per_layer = bench["per_layer"]
-    assert [m["name"] for m in per_layer[-len(NEW):]] == NEW
-    layers = {m["layer"] for m in per_layer[:-len(NEW)]}
-    for m in per_layer[-len(NEW):]:
-        assert m["layer"] in layers and m["workloads"] == ["serve-assist"]
-        assert set(m) == {"name", "unit", "better", "source", "layer",
-                          "moves", "workloads"}
-        assert (ROOT / "chipbench/metrics" / f"{m['name']}.json").exists()
-    for m in per_layer[:-len(NEW)] + bench["end_to_end"]:
-        # an accepted list only grows, and by its last entry; a metric
-        # whose count is another configuration's is not asked of this cell
-        lst = m.get("workloads", [])
-        assert "serve-assist" not in lst[:-1]
-        if lst[-1:] == ["serve-assist"] and m in per_layer:
-            spec = json.loads((ROOT / "chipbench/metrics"
-                               / f"{m['name']}.json").read_text())
-            assert "counts" not in spec.get("args", {})
+    bench = rules.manifest()
+    found = {s: [n for n in rules.names(LEFT, s) if n not in own]
+             for s, own in OWN.items()}
+    for section, own in OWN.items():
+        rules.check_own_after(bench, section, found[section], own)
+    rules.check_cell(bench, "serve-assist", "falcon-h1-34b-stage", 1)
+    for name in NEW:
+        rules.check_entry(bench, name, cells=["serve-assist"])
+    # a metric whose count is another configuration's is not asked of
+    # this cell
+    rules.check_joins(bench, found["per_layer"], "serve-assist")

@@ -20,6 +20,7 @@ from chipbench import spans  # noqa: E402
 from chipbench import weights_nemotron_h as W  # noqa: E402
 from chipbench.counts import hybrid  # noqa: E402
 from chipbench.readers import scope_share_of_peak  # noqa: E402
+import manifest_rules as rules  # noqa: E402
 
 FIX = "tests/chipbench/fixtures"
 PEAKS = {"bf16_flops_per_s": 1e12, "hbm_bytes_per_s": 1e11}
@@ -285,18 +286,17 @@ def test_reference_imports_nothing_of_the_program():
         assert "hpc_patterns_tpu" not in (ROOT / f).read_text()
 
 
-# -- the manifest: this PR's entries after the accepted ones ---------------------
+# -- the manifest: this configuration's entries, found by name ------------------
 
-ACCEPTED = {   # BENCHMARK.json as PR 25 left it (commit 6db83cf), by name
+ACCEPTED = {   # the manifest as this configuration found it, by name
     "configs": ["starcoder2-3b", "starcoder2-3b-train",
                 "hpcpat-allreduce-np4"],
     "workloads": ["serve-code", "train-4k", "allreduce-sweep"],
     "per_layer": [
         "loadgen_late_p95_ms", "admit_bubble_pct", "queue_p95_ms",
         "prefill_dev_ms", "decode_dev_ms_tok", "prefill_mfu_pct",
-        "decode_mfu_pct", "flash_prefill_roofline", "paged_decode_roofline",
-        "serve_idle_pct", "train_step_p50_ms", "train_mfu_pct",
-        "flash_train_roofline", "train_peak_hbm_GB", "train_idle_pct",
+        "decode_mfu_pct", "serve_idle_pct", "train_step_p50_ms",
+        "train_mfu_pct", "train_peak_hbm_GB", "train_idle_pct",
         "allreduce_small_us", "allreduce_large_ici_pct",
         "allreduce_idle_pct", "round_p50_ms", "admit_host_ms",
         "first_token_wait_ms", "serve_idle_host_pct",
@@ -312,35 +312,24 @@ ADDED = {
     "per_layer": [
         "hybrid_prefill_mfu_pct", "hybrid_decode_mfu_pct",
         "ssm_scan_prefill_roofline", "ssm_step_decode_roofline",
-        "moe_experts_prefill_roofline", "moe_experts_decode_roofline",
         "ssm_prefill_ms", "moe_prefill_ms", "ssm_decode_ms_chunk",
         "moe_decode_ms_chunk", "moe_local_picks_per_token",
         "moe_load_max_over_mean", "hybrid_flash_fwd_roofline",
         "hybrid_flash_decode_paged_roofline"],
 }
-SILENT = {"flash_prefill_roofline", "paged_decode_roofline",
-          "flash_train_roofline"}   # read nothing since PR 24: no new cell
 
 
 @pytest.mark.parametrize("section", list(ACCEPTED))
 def test_this_prs_entries_come_after_the_accepted_ones(section):
-    bench = json.loads((ROOT / "BENCHMARK.json").read_text())
-    names = [x["name"] for x in bench[section]]
-    assert names == ACCEPTED[section] + ADDED[section]
-    if section != "per_layer":
-        return
-    n = len(ACCEPTED[section])
-    layers = {m["layer"] for m in bench["per_layer"][:n]}
-    for m in bench["per_layer"][n:]:
-        assert m["layer"] in layers and m["workloads"] == ["serve-chat"]
-        assert set(m) == {"name", "unit", "better", "source", "layer",
-                          "moves", "workloads"}
-    for m in bench["per_layer"][:n]:   # an accepted list only grows
-        new = [w for w in m["workloads"] if w in ADDED["workloads"]]
-        assert m["workloads"][len(m["workloads"]) - len(new):] == new
-        assert not (new and m["name"] in SILENT)
+    """The accepted names stand first; this configuration's stand
+    anywhere after them, and later entries may follow."""
+    bench = rules.manifest()
+    rules.check_own_after(bench, section, ACCEPTED[section], ADDED[section])
+    if section == "workloads":
+        rules.check_cell(bench, "serve-chat", "nemotron3-super-ep4", 1)
+        rules.check_cell(bench, "serve-gen", "starcoder2-3b", 1)
+    elif section == "per_layer":
+        for name in ADDED["per_layer"]:
+            rules.check_entry(bench, name, cells=["serve-chat"])
         # the dense block's counts are wrong for the patterned model
-        if "serve-chat" in new:
-            spec = json.loads((ROOT / "chipbench/metrics"
-                               / f"{m['name']}.json").read_text())
-            assert "counts" not in spec.get("args", {})
+        rules.check_joins(bench, ACCEPTED["per_layer"], "serve-chat")

@@ -19,6 +19,7 @@ sys.path.insert(0, str(ROOT))
 from chipbench import spans  # noqa: E402
 from chipbench.readers import (idle_pct, idle_under, idle_unspanned,  # noqa: E402
                                span_attr_stat, span_self_ms)
+import manifest_rules as rules  # noqa: E402
 
 FIX = ROOT / "tests/chipbench/fixtures"
 METRICS = ROOT / "chipbench/metrics"
@@ -346,45 +347,28 @@ def test_unspanned_is_idle_unders_own_remainder(hand):
 @pytest.mark.parametrize("section", ["configs", "workloads", "end_to_end",
                                      "per_layer"])
 def test_the_accepted_entries_come_first_and_their_lists_only_grew(section):
-    """A PREFIX is pinned (``accepted-manifest-pr33.json`` is the parent's
-    ``BENCHMARK.json``), so the next appended entry does not break this."""
-    was, now = ACCEPTED[section], MANIFEST[section]
-    assert len(now) >= len(was)
-    for old, new in zip(was, now):
-        assert set(old) == set(new)
-        for key, value in old.items():
-            if key == "workloads":
-                assert new[key][:len(value)] == value, old["name"]
-            else:
-                assert new[key] == value, (old["name"], key)
-    for key in ("command", "paths", "run_seconds"):
-        assert MANIFEST[key] == ACCEPTED[key]
+    """A PREFIX is pinned (``accepted-manifest-pr33.json``, less the
+    entries taken out since), so the next appended entry does not break
+    this."""
+    rules.check_prefix(MANIFEST, ACCEPTED, section)
 
 
 def test_this_pr_adds_twelve_metrics_and_nothing_else():
     for section in ("configs", "workloads", "end_to_end"):
-        assert MANIFEST[section][:len(ACCEPTED[section])] == \
-            ACCEPTED[section]
-    n = len(ACCEPTED["per_layer"])
-    assert n == 69
-    assert MANIFEST["per_layer"][:n] == ACCEPTED["per_layer"]
-    mine = MANIFEST["per_layer"][n:n + len(NEW)]
-    assert [m["name"] for m in mine] == list(NEW)
+        rules.check_own_after(MANIFEST, section,
+                              rules.names(ACCEPTED, section), [])
+    rules.check_own_after(MANIFEST, "per_layer",
+                          rules.names(ACCEPTED, "per_layer"), list(NEW))
 
 
 @pytest.mark.parametrize("name", list(NEW))
 def test_a_new_entry_agrees_with_its_file_and_names_an_accepted_layer(name):
     unit, source, layer, moves, cells = NEW[name]
-    entry = next(m for m in MANIFEST["per_layer"] if m["name"] == name)
-    assert entry == {"name": name, "unit": unit, "better": "lower",
-                     "source": source, "layer": layer, "moves": moves,
-                     "workloads": cells}
+    entry = rules.by_name(MANIFEST["per_layer"], name)
+    assert {k: v for k, v in entry.items() if k != "workloads"} == {
+        "name": name, "unit": unit, "better": "lower", "source": source,
+        "layer": layer, "moves": moves}
     assert layer in {m["layer"] for m in ACCEPTED["per_layer"]}
-    spec = spec_of(name)
-    assert set(spec) == {"layer", "unit", "moves", "reader", "args"}
-    assert (spec["layer"], spec["unit"], spec["moves"]) == (layer, unit,
-                                                            moves)
-    assert (ROOT / "chipbench/readers" / f"{spec['reader']}.py").exists()
-    # every cell it lists reports the end-to-end metric it moves
-    e2e = next(e for e in MANIFEST["end_to_end"] if e["name"] == moves)
-    assert set(cells) <= set(e2e["workloads"])
+    # the file's fields, its reader, and every cell it lists reporting
+    # the end-to-end metric it moves; the list may have grown since
+    rules.check_entry(MANIFEST, name, cells=cells)

@@ -23,6 +23,7 @@ from chipbench import weights_sdar as W  # noqa: E402
 from chipbench.counts import sdar as counts  # noqa: E402
 from chipbench.drivers import serve_sdar  # noqa: E402
 from chipbench.reference import sdar as ref  # noqa: E402
+import manifest_rules as rules  # noqa: E402
 
 FIX = "tests/chipbench/fixtures"
 PEAKS = {"bf16_flops_per_s": 1e12, "hbm_bytes_per_s": 1e11}
@@ -509,55 +510,34 @@ NEW = ["sdar_prefill_mfu_pct", "sdar_decode_mfu_pct",
                                      "per_layer"])
 def test_the_accepted_entries_come_first_and_their_lists_only_grew(section):
     """A PREFIX is pinned, so that the next appended entry does not break
-    this test: what PR 31's manifest held stands first, in order, each
-    entry as it was but for a ``workloads`` list that may have grown at
-    its end."""
-    was, now = ACCEPTED[section], MANIFEST[section]
-    assert len(now) >= len(was)
-    for old, new in zip(was, now):
-        assert set(old) == set(new)
-        for key, value in old.items():
-            if key == "workloads":
-                assert new[key][:len(value)] == value, old["name"]
-            else:
-                assert new[key] == value, (old["name"], key)
-    for key in ("command", "paths", "run_seconds"):
-        assert MANIFEST[key] == ACCEPTED[key]
+    this test: what the accepted manifest held stands first, in order,
+    each entry as it was but for a ``workloads`` list that may have grown
+    at its end (less the entries taken out since)."""
+    rules.check_prefix(MANIFEST, ACCEPTED, section)
 
 
 def test_this_prs_entries_follow_the_accepted_ones():
-    n = len(ACCEPTED["per_layer"])
-    mine = MANIFEST["per_layer"][n:n + len(NEW)]
-    assert [m["name"] for m in mine] == NEW
-    layers = {m["layer"] for m in ACCEPTED["per_layer"]}
-    for m in mine:
-        assert m["layer"] in layers and m["workloads"] == ["serve-diffuse"]
-        assert set(m) == {"name", "unit", "better", "source", "layer",
-                          "moves", "workloads"}
-        spec = json.loads((ROOT / "chipbench/metrics"
-                           / f"{m['name']}.json").read_text())
-        assert (ROOT / "chipbench/readers" / f"{spec['reader']}.py").exists()
-        assert (spec["layer"], spec["unit"], spec["moves"]) == (
-            m["layer"], m["unit"], m["moves"])
-        if "counts" in spec["args"]:
-            module, fn = spec["args"]["counts"].split(".")
-            assert module == "sdar" and callable(getattr(counts, fn))
-    assert MANIFEST["configs"][len(ACCEPTED["configs"])]["name"] == \
-        "sdar-30b-a3b-stage"
-    assert MANIFEST["workloads"][len(ACCEPTED["workloads"])]["name"] == \
-        "serve-diffuse"
+    """Found by name, anywhere after the accepted entries."""
+    for section, own in (("configs", ["sdar-30b-a3b-stage"]),
+                         ("workloads", ["serve-diffuse"]),
+                         ("per_layer", NEW)):
+        rules.check_own_after(MANIFEST, section,
+                              rules.names(ACCEPTED, section), own)
+    rules.check_cell(MANIFEST, "serve-diffuse", "sdar-30b-a3b-stage", 1)
+    for name in NEW:
+        rules.check_entry(MANIFEST, name, cells=["serve-diffuse"])
+        args = rules.spec_of(name)["args"]
+        if "counts" in args:
+            assert args["counts"].split(".")[0] == "sdar"
+            assert callable(getattr(counts, args["counts"].split(".")[1]))
 
 
 def test_accepted_metrics_asked_of_the_cell_read_something_there():
     """The cell joined an accepted metric's list only where the metric's
     spec selects nothing of another program (``jit__chunk_step``) and
-    carries no other configuration's count."""
-    for m in ACCEPTED["per_layer"]:
-        now = next(x for x in MANIFEST["per_layer"] if x["name"] == m["name"])
-        if "serve-diffuse" not in now.get("workloads", ()):
-            continue
-        text = (ROOT / "chipbench/metrics" / f"{m['name']}.json").read_text()
-        assert "chunk_step" not in text and '"counts"' not in text, m["name"]
+    carries no other configuration's count; it reports both tails."""
+    rules.check_joins(MANIFEST, rules.names(ACCEPTED, "per_layer"),
+                      "serve-diffuse", foreign=("chunk_step",))
     for e in MANIFEST["end_to_end"]:
         if e["name"] in ("ttft_p95_ms", "tpot_p95_ms"):
-            assert e["workloads"][-1] == "serve-diffuse"
+            assert "serve-diffuse" in e["workloads"]

@@ -17,6 +17,7 @@ sys.path.insert(0, str(ROOT))
 from chipbench import reduce, spans  # noqa: E402
 from chipbench.readers import (idle_under, scope_ms, share_of_peak,  # noqa: E402
                                span_count, span_ms, span_pair_ms)
+import manifest_rules as rules  # noqa: E402
 
 FIX = ROOT / "tests/chipbench/fixtures"
 METRICS = ROOT / "chipbench/metrics"
@@ -171,19 +172,28 @@ def test_kernel_rooflines_by_name(train, metric, seconds, flops_of_fwd):
     assert 0 < got < 100
 
 
+# what the accepted ``flash_train_roofline`` read before it was taken out
+# (it read nothing once the kernels were named): both kernels' count over
+# ``%closed_call``
+SUMMED = {"counts": "flash_attention.train_work",
+          "time": {"line": "XLA Ops", "pattern": r"^%closed_call[.\d]* = ",
+                   "within": r"^jit_step\("},
+          "bound": "roofline"}
+
+
 def test_the_two_kernels_bracket_what_the_accepted_metric_summed(train):
     tr = train.as_trace()
     one = lambda m: share_of_peak.read(args_of(m), tr, TRAIN_FACTS,
                                        TRAIN_CONFIG, PEAKS)
-    both = dict(args_of("flash_train_roofline"))
+    both = dict(SUMMED)
     both["time"] = dict(both["time"],
                         pattern=r"^%flash_(fwd|bwd_fused)[.\d]* = ")
     summed = share_of_peak.read(both, tr, TRAIN_FACTS, TRAIN_CONFIG, PEAKS)
     assert one("flash_train_fwd_roofline") < summed \
         < one("flash_train_bwd_roofline")
     # and the accepted pattern itself finds no %closed_call any more
-    assert share_of_peak.read(args_of("flash_train_roofline"), tr,
-                              TRAIN_FACTS, TRAIN_CONFIG, PEAKS) is None
+    assert share_of_peak.read(SUMMED, tr, TRAIN_FACTS, TRAIN_CONFIG,
+                              PEAKS) is None
 
 
 def test_idle_classes_and_the_rest_are_the_idle_share(serve):
@@ -308,15 +318,23 @@ def test_on_the_parents_trace_a_new_metric_is_left_out(nameless, monkeypatch,
         assert reader.read(spec["args"], tr, facts, {}, PEAKS) is None
 
 
+# the first benchmark's metrics, which stand before these (less those
+# taken out)
+FIRST = ["loadgen_late_p95_ms", "admit_bubble_pct", "queue_p95_ms",
+        "prefill_dev_ms", "decode_dev_ms_tok", "prefill_mfu_pct",
+        "decode_mfu_pct", "serve_idle_pct", "train_step_p50_ms",
+        "train_mfu_pct", "train_peak_hbm_GB", "train_idle_pct",
+        "allreduce_small_us", "allreduce_large_ici_pct", "allreduce_idle_pct"]
+
+
 def test_new_entries_are_appended_and_name_a_layer_of_the_table():
-    bench = json.loads((ROOT / "BENCHMARK.json").read_text())
-    names = [m["name"] for m in bench["per_layer"]]
-    assert names[-len(NEW):] == NEW
-    layers = {m["layer"] for m in bench["per_layer"][:-len(NEW)]}
-    for m in bench["per_layer"][-len(NEW):]:
-        assert m["layer"] in layers and len(m["workloads"]) == 1
-        assert set(m) == {"name", "unit", "better", "source", "layer",
-                          "moves", "workloads"}
+    """Found by name after the first benchmark's, each in the one cell it
+    came for first; a list may have grown since."""
+    bench = rules.manifest()
+    rules.check_own_after(bench, "per_layer", FIRST, NEW)
+    for name in NEW:
+        cell = "train-4k" if "train" in name else "serve-code"
+        rules.check_entry(bench, name, cells=[cell])
 
 
 # -- the file, from a real trace made here ----------------------------------------------
