@@ -57,6 +57,7 @@ from hpc_patterns_tpu.models.transformer import (
     _rmsnorm,
     apply_rope,
     attn_proj,
+    attn_scope,
     gated_mlp,
     head_logits,
     attn_norm,
@@ -338,42 +339,54 @@ def prefill(params, prompt, cfg: TransformerConfig, max_len: int,
         if cfg.pos_embed == "learned":
             x = x + params["pos_embed"].astype(dt)[:T]
 
-    @scoped("attn")
-    def attend(hn, lp):
+    def attend(hn, lp, ak=None):
         """The normed input's attention before its output projection:
-        (o (B, T, H, Dh), K, V)."""
-        q, k, v = project_qkv(hn, lp, cfg)
-        if cfg.pos_embed == "rope":
-            # the cache stores POST-rope K: a key's rotation depends
-            # only on its own (fixed) position, so decode steps never
-            # re-rotate history
-            pos = jnp.arange(T, dtype=jnp.int32)
-            q = apply_rope(q, pos, cfg)
-            k = apply_rope(k, pos, cfg)
-        # long prompts go through the flash kernel (the dense oracle
-        # materializes the (T, T) scores — an 8k-token prompt would be
-        # a 17 GB allocation at B=8); short/ragged prompts and sharded
-        # (gather-mode) serving keep the einsum path, which consumes
-        # the narrow GQA K/V directly
-        # a model that generates by diffusion over blocks prefills under
-        # the block mask (unsharded: it is a patterned model)
-        mb = {"mask_block": cfg.block_len} if cfg.block_len else {}
-        if use_flash and T % 128 == 0:
-            from hpc_patterns_tpu.ops import flash_attention
+        (o (B, T, H, Dh), K, V). ``ak``: a patterned layer's
+        :class:`~hpc_patterns_tpu.models.transformer.AttnKind`."""
+        with jax.named_scope(attn_scope(ak)):
+            q, k, v = project_qkv(hn, lp, cfg,
+                                  None if ak is None else ak.heads)
+            if cfg.pos_embed == "rope":
+                # the cache stores POST-rope K: a key's rotation depends
+                # only on its own (fixed) position, so decode steps never
+                # re-rotate history
+                pos = jnp.arange(T, dtype=jnp.int32)
+                rope = None if ak is None else ak.rope
+                q = apply_rope(q, pos, cfg, rope)
+                k = apply_rope(k, pos, cfg, rope)
+            if ak is not None and ak.window:
+                # a window layer: unsharded (a patterned model), the kernel
+                # skips the tiles wholly behind every query's window
+                if use_flash and T % 128 == 0:
+                    from hpc_patterns_tpu.ops import flash_attention
 
-            if flash_sharded:
-                hspec = resolve_spec(P(None, None, cfg.axis_tp, None),
-                                     mesh, cfg.mesh_axes)
-                o = shard_map(
-                    partial(flash_attention, causal=True), mesh=mesh,
-                    in_specs=(hspec, hspec, hspec), out_specs=hspec,
-                    check_vma=False,  # pallas_call can't declare vma
-                )(q, k, v)
+                    return flash_attention(q, k, v, window=ak.window), k, v
+                return full_attention(q, k, v, causal=True,
+                                      window=ak.window), k, v
+            # long prompts go through the flash kernel (the dense oracle
+            # materializes the (T, T) scores — an 8k-token prompt would be
+            # a 17 GB allocation at B=8); short/ragged prompts and sharded
+            # (gather-mode) serving keep the einsum path, which consumes
+            # the narrow GQA K/V directly
+            # a model that generates by diffusion over blocks prefills under
+            # the block mask (unsharded: it is a patterned model)
+            mb = {"mask_block": cfg.block_len} if cfg.block_len else {}
+            if use_flash and T % 128 == 0:
+                from hpc_patterns_tpu.ops import flash_attention
+
+                if flash_sharded:
+                    hspec = resolve_spec(P(None, None, cfg.axis_tp, None),
+                                         mesh, cfg.mesh_axes)
+                    o = shard_map(
+                        partial(flash_attention, causal=True), mesh=mesh,
+                        in_specs=(hspec, hspec, hspec), out_specs=hspec,
+                        check_vma=False,  # pallas_call can't declare vma
+                    )(q, k, v)
+                else:
+                    o = flash_attention(q, k, v, causal=True, **mb)
             else:
-                o = flash_attention(q, k, v, causal=True, **mb)
-        else:
-            o = full_attention(q, k, v, causal=True, **mb)
-        return o, k, v
+                o = full_attention(q, k, v, causal=True, **mb)
+            return o, k, v
 
     @scoped("kv_write")
     def capture(k, v):
@@ -384,12 +397,10 @@ def prefill(params, prompt, cfg: TransformerConfig, max_len: int,
         pad = [(0, 0), (0, 0), (0, max_len - T), (0, 0)]
         return jnp.pad(kc, pad).astype(dt), jnp.pad(vc, pad).astype(dt)
 
-    def body(h, lp, mlp=True):
+    def body(h, lp):
         o, k, v = attend(attn_norm(h, lp, cfg), lp)
         h = h + attn_proj(o, lp, cfg, dt)
-        if mlp:
-            h = _mlp(h, lp, cfg)
-        return h, capture(k, v)
+        return _mlp(h, lp, cfg), capture(k, v)
 
     rows_last = lambda: jnp.broadcast_to(
         jnp.asarray(last_pos, jnp.int32), (B,))
@@ -403,11 +414,18 @@ def prefill(params, prompt, cfg: TransformerConfig, max_len: int,
                  jnp.arange(T, dtype=jnp.int32)[None, :] <= last[:, None])
         ks, vs, states, stats = [], [], [], []
         for kind, lp in zip(cfg.layer_pattern, params["layers"]):
-            if kind in "*R":   # "R": attention, then the routed experts
-                x, (kc, vc) = body(x, lp, mlp=False)
-                if kind == "R":
+            if kind in "*RDW":   # attention, then the kind's MLP
+                ak = cfg.attn_kind(kind)
+                hn = attn_norm(x, lp, cfg, ak)
+                o, k, v = attend(hn, lp, ak)
+                x = x + attn_proj(o, lp, cfg, dt, ak, hn)
+                kc, vc = capture(k, v)
+                mlp = LAYER_KINDS[kind].mlp
+                if mlp == "routed":
                     x, st = routed_mlp(x, lp, cfg, valid)
                     stats.append(st)
+                elif mlp == "gated":
+                    x = gated_mlp(x, lp, cfg)
             elif kind == "M":
                 x, st = ssm_mixer(x, lp, cfg, last)
             elif kind == "E":
@@ -511,23 +529,29 @@ def _token_step(params, pos, tokens, cfg: TransformerConfig,
             x, st = moe_mixer(x, lp, cfg, active)
             stats.append(st)
             continue
-        hn = attn_norm(x, lp, cfg)
-        with jax.named_scope("attn"):
-            q, k_new, v_new = project_qkv(hn, lp, cfg)  # (B, H/Hkv, Dh)
+        ak = cfg.attn_kind(kind)
+        hn = attn_norm(x, lp, cfg, ak)
+        with jax.named_scope(attn_scope(ak)):
+            # (B, H/Hkv, Dh)
+            q, k_new, v_new = project_qkv(hn, lp, cfg, ak.heads)
             if cfg.pos_embed == "rope":
-                q = apply_rope(q, pos, cfg)
-                k_new = apply_rope(k_new, pos, cfg)
+                q = apply_rope(q, pos, cfg, ak.rope)
+                k_new = apply_rope(k_new, pos, cfg, ak.rope)
         # GQA grouped attention against the UNEXPANDED cache: q head
         # k*g+j (project_qkv's order) reads kv head k directly — no
         # materialized n_heads-wide repeat, so per-step HBM traffic is
         # the kv_heads-narrow cache read (the saving GQA exists for).
         # Outside the scope: the paged attend_update names its own
         # halves (the cache write is ``kv_write``, the attention
-        # ``attn``), so the two stay disjoint
-        o, st = attend_update(q, k_new, v_new,
-                              layer_states[len(new_states)])
-        x = x + attn_proj(o.reshape(B, cfg.n_heads, cfg.head_dim), lp,
-                          cfg, dt)
+        # ``attn``), so the two stay disjoint. A window layer's goes
+        # to its ring (``window``: the paged route alone takes it)
+        state = layer_states[len(new_states)]
+        if ak.window:
+            o, st = attend_update(q, k_new, v_new, state, window=ak.window)
+        else:
+            o, st = attend_update(q, k_new, v_new, state)
+        x = x + attn_proj(o.reshape(B, ak.heads, cfg.head_dim), lp,
+                          cfg, dt, ak, hn)
         new_states.append(st)
         holds = LAYER_KINDS[kind]
         if holds.state:   # "H": the recurrence, off the same normed input
@@ -581,6 +605,11 @@ def decode_step(params, cache, pos, tokens, cfg: TransformerConfig,
     grid); all other einsums partition via GSPMD as before. Without a
     mesh, sharded params still work through pure GSPMD on the gather
     path."""
+    if cfg.window:
+        raise ValueError(
+            "a model with window layers decodes through the paged cache "
+            "(paged_decode_step), where a window layer's K/V is a ring of "
+            "pages a row; the linear cache holds every position")
     dt = jnp.dtype(cfg.dtype)
     B = tokens.shape[0]
     scale = 1.0 / (cfg.head_dim ** 0.5)
@@ -875,6 +904,16 @@ def greedy_generate(params, prompt, cfg: TransformerConfig,
 # Paged KV cache (block-table serving)
 # ---------------------------------------------------------------------------
 
+def ring_table(rows, pages_per_seq: int, ring: int):
+    """(len(rows), pages_per_seq) int32: logical page j of the row held by
+    slot ``rows[b]`` lies at ring page rows[b]·ring + j mod ring of a
+    window layer's pool. THE ring layout: a function of the slot and the
+    page alone, computed inside each program that reads or writes a
+    ring, never held as state."""
+    rows = jnp.asarray(rows, jnp.int32)[:, None] * ring
+    return rows + jnp.arange(pages_per_seq, dtype=jnp.int32)[None, :] % ring
+
+
 def init_paged_cache(cfg: TransformerConfig, batch: int,
                      pages_per_seq: int, page_size: int,
                      pool_pages: int | None = None, table=None):
@@ -894,7 +933,17 @@ def init_paged_cache(cfg: TransformerConfig, batch: int,
     pools (kernel-lane layout (pool_pages, kv_heads, 1, page_size)) —
     the two CAPACITY levers stack: quantization halves page bytes vs
     bf16 (quarters vs f32), paging frees the allocate-for-longest
-    waste (docs/quantization.md)."""
+    waste (docs/quantization.md).
+
+    A WINDOW layer (``cfg.window_layers``) sees its last ``cfg.window``
+    positions alone, which span at most R = ``cfg.ring_pages(page_size)``
+    pages, distinct mod R: its pool is a RING of R pages a row,
+    (batch x R, kv_heads, page_size, head_dim), held by the row from the
+    start and never taken from the arena: logical page j of row b lies
+    at ring page b·R + j mod R (:func:`ring_table`). A page written
+    over held positions the window has passed; whatever a ring page
+    holds past the row's position is masked like any stale row of the
+    arena."""
     if pool_pages is None:
         pool_pages = batch * pages_per_seq
     if table is None:
@@ -916,6 +965,11 @@ def init_paged_cache(cfg: TransformerConfig, batch: int,
     shape = (pool_pages, cfg.kv_heads, page_size, cfg.head_dim)
     fresh = lambda sh, d: tuple(jnp.zeros(sh, d)
                                 for _ in range(cfg.n_attn_layers))
+    if cfg.window:
+        R = cfg.ring_pages(page_size)
+        ring = (batch * R,) + shape[1:]
+        fresh = lambda sh, d: tuple(jnp.zeros(ring if win else sh, d)
+                                    for win in cfg.window_layers)
     cache = {"k": fresh(shape, dt), "v": fresh(shape, dt),
              "table": jnp.asarray(table, jnp.int32)}
     if quant:
@@ -929,7 +983,7 @@ def init_paged_cache(cfg: TransformerConfig, batch: int,
 
 
 def paged_prefill(params, prompt, cfg: TransformerConfig, cache,
-                  page_size: int, mesh=None, last_pos=None):
+                  page_size: int, mesh=None, last_pos=None, ring_rows=None):
     """Prompt pass writing into the paged cache: the ordinary prefill
     captures K/V for the prompt (a transient sized to the PROMPT, not
     the serving maximum), then each layer's pages scatter into the pool
@@ -939,7 +993,10 @@ def paged_prefill(params, prompt, cfg: TransformerConfig, cache,
     :func:`paged_decode_step`'s sharded route consumes in place).
     ``last_pos``: the bucketed-prompt route (see :func:`prefill`) —
     logits come from this position instead of the last, so a prompt
-    right-padded to a bucket rung still answers for its true end."""
+    right-padded to a bucket rung still answers for its true end.
+    ``ring_rows``: (B,) int32, the slots whose window-layer rings the
+    prompt rows fill (default: row b's own, b) — the engine's one-row
+    admission names its slot."""
     B, T = prompt.shape
     P = page_size  # shadows the PartitionSpec alias in this scope
     t_pad = -(-T // P) * P
@@ -979,6 +1036,20 @@ def paged_prefill(params, prompt, cfg: TransformerConfig, cache,
             )
         idx = table[:, :n_used]  # (B, n_used)
         out = {"table": table, **extra}
+        if cfg.window:
+            # a window layer's ring takes the R pages that end at the
+            # prompt's last true position: what the next step's window
+            # reaches back to (pages before the first clamp to it and
+            # write the same page again)
+            last = jnp.broadcast_to(jnp.asarray(
+                T - 1 if last_pos is None else last_pos, jnp.int32), (B,))
+            R = cfg.ring_pages(P)
+            src = jnp.clip(last[:, None] // P
+                           - jnp.arange(R, dtype=jnp.int32)[None, :],
+                           0, n_used - 1)                    # (B, R)
+            rows = jnp.arange(B) if ring_rows is None else ring_rows
+            ring_idx = jnp.take_along_axis(
+                ring_table(rows, n_used, R), src, axis=1)
         for name in ("k", "v"):
             pool = list(cache[name])
             for l in range(cfg.n_attn_layers):
@@ -988,6 +1059,12 @@ def paged_prefill(params, prompt, cfg: TransformerConfig, cache,
                     lin[name][l].reshape(B, cfg.kv_heads, n_used, P,
                                          cfg.head_dim),
                 )
+                if cfg.window and cfg.window_layers[l]:
+                    pages = jnp.take_along_axis(
+                        pages, src[:, :, None, None, None], axis=1)
+                    pool[l] = pool[l].at[ring_idx].set(
+                        pages.astype(pool[l].dtype))
+                    continue
                 pool[l] = pool[l].at[idx].set(pages.astype(pool[l].dtype))
             out[name] = tuple(pool)
         if _kv_quantized(cfg):
@@ -1245,7 +1322,7 @@ def _scale_write(pool, page_ids, page, offset, rows, pages: int,
 
 
 def _paged_attend_gather(q, k_pool, v_pool, ks_pool, vs_pool, table,
-                         pos, cfg: TransformerConfig, scale):
+                         pos, cfg: TransformerConfig, scale, window=0):
     """The pure-XLA paged attention step (``cfg.decode_attn ==
     "gather"``): each row's pages gather through the table into a
     contiguous (B, Hkv, pages·P, D) view and the step is
@@ -1259,7 +1336,10 @@ def _paged_attend_gather(q, k_pool, v_pool, ks_pool, vs_pool, table,
     kernel remains the default — its clamped index map reads
     position-proportional bytes; this view reads the full allocation.
     ``pos``: scalar or ragged (B,); int8 pools dequantize in the einsum
-    stream like the linear gather."""
+    stream like the linear gather. ``window`` > 0: the keys
+    ``pos - window < j <= pos`` alone (a window layer's ring: the view
+    holds each logical page's ring page, which past the window may hold
+    another page's rows, masked)."""
     B, pages = table.shape
     # g from q: a block step folds its positions into the group
     Hkv, Dh = cfg.kv_heads, cfg.head_dim
@@ -1284,8 +1364,10 @@ def _paged_attend_gather(q, k_pool, v_pool, ks_pool, vs_pool, table,
     s = jnp.einsum("bkgd,bksd->bkgs", qg.astype(jnp.float32), kd,
                    precision=lax.Precision.HIGHEST) * scale
     idx = lax.broadcasted_iota(jnp.int32, s.shape, 3)
-    visible = idx <= (pos[:, None, None, None] if jnp.ndim(pos)
-                      else pos)
+    at = pos[:, None, None, None] if jnp.ndim(pos) else pos
+    visible = idx <= at
+    if window:
+        visible = visible & (idx > at - window)
     s = jnp.where(visible, s, -1e30)
     p = jax.nn.softmax(s, axis=-1)
     o = jnp.einsum("bkgs,bksd->bkgd", p, vd,
@@ -1378,6 +1460,17 @@ def paged_decode_step(params, cache, pos, tokens, cfg: TransformerConfig,
     else:
         page_ids = jnp.take(table, page, axis=1)  # (B,)
     offset = pos % P
+    if cfg.window:
+        # a window layer's row lands in its ring: logical page ``page``
+        # through the ring layout, the same offset
+        win_table = ring_table(jnp.arange(table.shape[0]), table.shape[1],
+                               cfg.ring_pages(P))
+        win_ids = (jnp.take_along_axis(win_table, page[:, None], axis=1)
+                   [:, 0] if ragged else jnp.take(win_table, page, axis=1))
+        if cfg.decode_attn == "paged_flash" or mesh is not None:
+            raise ValueError(
+                "window layers decode through decode_attn 'flash' or "
+                "'gather', unsharded")
 
     quant = _kv_quantized(cfg)
     ident = identity_layout and not ragged
@@ -1409,16 +1502,21 @@ def paged_decode_step(params, cache, pos, tokens, cfg: TransformerConfig,
                 q, kp, vp, tbl, p, k_scale_pool=ksp, v_scale_pool=vsp,
                 scale=scale)
     else:
-        def kernel_fn(q, kp, vp, tbl, p, act, ksp, vsp):
+        def kernel_fn(q, kp, vp, tbl, p, act, ksp, vsp, **win):
             # the rows that do not count are not visited: zeros
             return flash_decode_paged(
                 q, kp, vp, tbl, p, active=act, k_scale_pool=ksp,
                 v_scale_pool=vsp, scale=scale,
-                pages_per_step=pages_per_step)
+                pages_per_step=pages_per_step, **win)
 
     @scoped("kv_write")
-    def write(k_new, v_new, state):
+    def write(k_new, v_new, state, window=0):
         k_pool, v_pool, ks_pool, vs_pool = state
+        if window:   # the ring: never the identity layout's view
+            return (_pool_write(k_pool, win_ids, page, offset, k_new,
+                                pages, False),
+                    _pool_write(v_pool, win_ids, page, offset, v_new,
+                                pages, False), ks_pool, vs_pool)
         if quant:
             k_new, k_s = _quantize_rows(k_new, cfg.kv_cache_dtype)
             v_new, v_s = _quantize_rows(v_new, cfg.kv_cache_dtype)
@@ -1432,10 +1530,24 @@ def paged_decode_step(params, cache, pos, tokens, cfg: TransformerConfig,
                              pages, ident, tp)
         return k_pool, v_pool, ks_pool, vs_pool
 
-    def attend_update(q, k_new, v_new, state):
-        state = write(k_new, v_new, state)
+    def attend_update(q, k_new, v_new, state, window=0):
+        state = write(k_new, v_new, state, window)
+        if window:
+            with jax.named_scope("attn_win"):
+                return attend_window(q, state, window), state
         with jax.named_scope("attn"):
             return attend(q, state), state
+
+    def attend_window(q, state, window):
+        """A window layer's step: the keys ``pos - window < j <= pos`` of
+        the row's ring (the kernel fetches the pages they lie on)."""
+        k_pool, v_pool = state[:2]
+        if kernel_route is None:
+            return _paged_attend_gather(q, k_pool, v_pool, None, None,
+                                        win_table, pos, cfg, scale,
+                                        window=window)
+        return kernel_fn(q, k_pool, v_pool, win_table, pos, active, None,
+                         None, window=window)
 
     def attend(q, state):
         k_pool, v_pool, ks_pool, vs_pool = state

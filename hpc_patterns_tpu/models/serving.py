@@ -647,16 +647,17 @@ def _spec_chunk(params, dparams, cache, dcache, pos, limit, cur, key,
 
 @partial(jax.jit, static_argnames=("cfg", "page_size", "mesh"),
          donate_argnums=(3,))
-def _prefill_one(params, prompt, last_pos, cache_one, *, cfg, page_size,
-                 mesh):
+def _prefill_one(params, prompt, last_pos, cache_one, ring_rows=None, *,
+                 cfg, page_size, mesh):
     """One-row prefill through the shared pool (jitted; compiles per
     distinct PADDED prompt length — the engine's bucket ladder bounds
     that count, see ``prompt_buckets``). ``last_pos`` (traced) redirects
     the returned logits to the last REAL token of a padded prompt.
     ``cache_one`` is donated: the pool IS the capacity lever, so
-    admissions must not double it."""
+    admissions must not double it. ``ring_rows`` (traced, (1,)): the
+    slot whose window-layer rings the row fills."""
     return paged_prefill(params, prompt, cfg, cache_one, page_size,
-                         mesh=mesh, last_pos=last_pos)
+                         mesh=mesh, last_pos=last_pos, ring_rows=ring_rows)
 
 
 @partial(jax.jit,
@@ -1067,10 +1068,20 @@ class EngineCore:
         #: bytes of per-row recurrent state held beside the pools
         self.state_bytes = sum(
             int(a.nbytes) for k in STATE_KEYS for a in self.cache.get(k, ()))
-        #: bytes of K/V (scales included) one token holds, all layers
+        #: bytes of K/V (scales included) one token holds, all layers that
+        #: take their pages from the arena
+        arena = [not w for w in cfg.window_layers]
         self.kv_bytes_per_token = sum(
             int(a.nbytes) for k in ("k", "v", "k_scale", "v_scale")
-            for a in self.cache.get(k, ())) // ((pool_pages + 1) * page_size)
+            for a, on in zip(self.cache.get(k, ()), arena)
+            if on) // ((pool_pages + 1) * page_size)
+        #: the window layers' rings (ring pages a slot, held by the slot
+        #: from the start: no page of the arena) and their bytes
+        self.window_kv_bytes = sum(
+            int(a.nbytes) for k in ("k", "v")
+            for a, on in zip(self.cache[k], arena) if not on)
+        if cfg.window:
+            self.ring_pages = cfg.ring_pages(page_size)
         #: what ``_count_route`` last saw of the expert route's sums
         self._route_seen = None
         if draft_params is not None:
@@ -1647,7 +1658,7 @@ class EngineCore:
         # whole pages from the matched prefix on, every layer's K and V
         span_attrs["kv_bytes"] = lambda: (
             -(-(padded - M) // self.page_size) * self.page_size
-            * self.kv_bytes_per_token)
+            * self.kv_bytes_per_token + self._ring_bytes_written(padded))
         # from the instant the request was due to this dispatch
         span_attrs["queued_ms"] = lambda: (
             time.perf_counter() - req.t_submit) * 1e3
@@ -1675,11 +1686,14 @@ class EngineCore:
                 # a diffusion row's prompt pass covers its WHOLE blocks
                 # (what lies behind them is padding to it)
                 Bk = self.cfg.block_len
+                # the slot's window-layer rings, where the model has them
+                ring = ({"ring_rows": np.full((1,), slot, np.int32)}
+                        if self.cfg.window else {})
                 logits, out = _prefill_one(
                     self.params, jnp.asarray(prompt)[None, :],
                     jnp.int32((T // Bk * Bk if Bk else T) - 1), one,
                     cfg=self.cfg, page_size=self.page_size,
-                    mesh=self.mesh,
+                    mesh=self.mesh, **ring,
                 )
         row_state = {k: out.pop(k) for k in STATE_KEYS if k in out}
         for k, v in out.items():
@@ -1787,6 +1801,8 @@ class EngineCore:
                 mx.gauge("engine.state_bytes").set(self.state_bytes)
             mx.gauge("engine.kv_bytes_per_token").set(
                 self.kv_bytes_per_token)
+            if self.window_kv_bytes:
+                mx.gauge("engine.window_kv_bytes").set(self.window_kv_bytes)
             mx.counter("serve.admitted").inc()
             if overlapped:
                 mx.counter("serve.admit_overlapped").inc()
@@ -1803,6 +1819,15 @@ class EngineCore:
                 req.seq_id, "admit_wait",
                 st.t_admit if t_pass is None else t_pass)
             rtr.stamp_transition(req.seq_id, "prefill")
+
+    def _ring_bytes_written(self, padded: int) -> int:
+        """Bytes an admission writes into its window layers' rings: the
+        prompt's last ring pages (at most ``ring_pages``), every window
+        layer's K and V."""
+        if not self.cfg.window:
+            return 0
+        pages = min(self.ring_pages, -(-padded // self.page_size))
+        return pages * self.window_kv_bytes // (self.slots * self.ring_pages)
 
     def _resolve_pending(self):
         """Host bookkeeping deferred from :meth:`_admit`: read back the
@@ -2110,6 +2135,14 @@ class EngineCore:
             # chunk's cursors, which _collect_chunk already resolved
             pos_start = np.array(self.pos)
         parts = [i for i, s in enumerate(self._slots) if s.active]
+        win = {}
+        if self.cfg.window:
+            # a window layer's pages of a K/V head the chunk's first step
+            # fetches: from the one holding pos - window + 1 to pos's
+            win["win_pages"] = lambda: (
+                pos_start[parts] // self.page_size + 1
+                - np.maximum(pos_start[parts] - self.cfg.window + 1, 0)
+                // self.page_size).sum()
         with metricslib.span("serve.decode_dispatch", chunk=self.chunk,
                              rows=len(parts), round=self._round,
                              # the live rows' positions, summed: what the
@@ -2117,8 +2150,8 @@ class EngineCore:
                              # pages of a K/V head it fetches for that
                              ctx_tokens=lambda: pos_start[parts].sum(),
                              kv_pages=lambda: (
-                                 pos_start[parts] // self.page_size + 1).sum()
-                             ), \
+                                 pos_start[parts] // self.page_size + 1).sum(),
+                             **win), \
                 tracelib.compile_watch("serving._chunk_step",
                                        _chunk_step, chunk=self.chunk):
             (self.cache, self.pos, self.limit, self.tokens, self.keys,
@@ -2570,6 +2603,11 @@ class EngineCore:
                 f"({self.cfg.layer_pattern!r}): a MigrationBundle carries "
                 "a row's pages and cursors; the row's recurrent state "
                 "would have to travel with them and has no wire form yet")
+        if self.cfg.window:
+            raise ValueError(
+                "migration with window layers: a MigrationBundle carries a "
+                "row's arena pages; its window layers' rings (held by the "
+                "slot, outside the arena) have no place in it yet")
 
     def _detach_row(self, slot: int) -> MigrationBundle:
         """Detach one active row into a :class:`MigrationBundle` and

@@ -57,16 +57,22 @@ class LayerKind(NamedTuple):
     """What a layer of one kind holds: K/V (a pool of pages in the
     cache), a row of recurrent state (convolution tail, S), and which
     MLP closes it ("" none, "gelu" the default block's, "gated" the
-    SiLU-gated one, "routed" a softmax top-k of SiLU-gated experts)."""
+    SiLU-gated one, "routed" a softmax top-k of SiLU-gated experts).
+    ``window``: its attention sees the last ``cfg.window`` positions
+    alone, with ``cfg.window_heads`` query heads and its own rotation
+    (:meth:`TransformerConfig.attn_kind`), and its K/V lives in a ring of
+    pages a slot instead of the arena (decode.init_paged_cache)."""
     kv: bool
     state: bool
     mlp: str
+    window: bool = False
 
 
 #: THE table of layer kinds, by the pattern's character: "B" spells the
 #: default block (no pattern names it). Read by the config's counts and
 #: by every loop over a pattern (forward_hidden, decode.prefill,
-#: decode._token_step, decode.init_layer_state)
+#: decode._token_step, decode.init_layer_state). "D" is global attention
+#: and the gated MLP, "W" window attention and the routed experts
 LAYER_KINDS = {
     "B": LayerKind(kv=True, state=False, mlp="gelu"),
     "*": LayerKind(kv=True, state=False, mlp=""),
@@ -74,7 +80,21 @@ LAYER_KINDS = {
     "E": LayerKind(kv=False, state=False, mlp=""),
     "H": LayerKind(kv=True, state=True, mlp="gated"),
     "R": LayerKind(kv=True, state=False, mlp="routed"),
+    "D": LayerKind(kv=True, state=False, mlp="gated"),
+    "W": LayerKind(kv=True, state=False, mlp="routed", window=True),
 }
+
+
+class AttnKind(NamedTuple):
+    """How one layer's attention is shaped: its query heads (the K/V
+    heads are the config's, shared), its window (0: every position
+    before it), its rotation ((theta, rotated dims, yarn) for
+    :func:`rope_frequencies`) and whether a per-head gate multiplies its
+    output."""
+    heads: int
+    window: int
+    rope: tuple
+    gate: bool
 
 
 @dataclasses.dataclass(frozen=True)
@@ -261,6 +281,24 @@ class TransformerConfig:
     moe_scale: float = 1.0
     # "R": the chosen gates sum to one (True) or stay the softmax's
     moe_renorm: bool = True
+    # "R" and "W": the chosen gates are multiplied by ``moe_scale``, and
+    # with ``moe_shared_d_ff`` > 0 one shared SiLU-gated expert of that
+    # width is added, whole on every chip (parallel/moe.gated_moe)
+    # "W": the window (position i sees i - window < j <= i), the query
+    # heads (0 = n_heads) and the rotation's theta (0 = rope_theta) of a
+    # window layer; its rotation covers the whole head
+    window: int = 0
+    window_heads: int = 0
+    window_rope_theta: float = 0.0
+    # the other attention layers' rotation: the share of each head that
+    # is rotated (the first rope_partial x head_dim dims, rotate-half
+    # pairing among them, the rest passes), and YaRN's (factor, original
+    # positions, beta_fast, beta_slow, attention_factor) (): none)
+    rope_partial: float = 1.0
+    rope_yarn: tuple = ()
+    # a per-head gate on every attention output: o_h <- sigmoid(h W_g)_h
+    # o_h before ``wo``, h the layer's normed input, W_g (d_model, heads)
+    attn_gate: bool = False
     # mesh axis names (data / sequence(context) / tensor / expert)
     axis_dp: str = "dp"
     axis_sp: str = "sp"
@@ -305,7 +343,31 @@ class TransformerConfig:
     @property
     def n_routed_layers(self) -> int:
         """Layers with a route over experts: the cache carries their sums."""
-        return sum(c in "ER" for c in self.pattern)
+        return sum(c == "E" or LAYER_KINDS[c].mlp == "routed"
+                   for c in self.pattern)
+
+    @property
+    def window_layers(self) -> tuple:
+        """For each layer that holds K/V, in order: is it a window layer
+        (its pool a ring of pages a slot, not the arena)?"""
+        return tuple(LAYER_KINDS[c].window for c in self.pattern
+                     if LAYER_KINDS[c].kv)
+
+    def ring_pages(self, page_size: int) -> int:
+        """Pages of a window layer's ring a slot: the window spans at most
+        ceil(window / page) + 1 pages, which are distinct mod that count."""
+        return -(-self.window // page_size) + 1
+
+    def attn_kind(self, kind: str) -> AttnKind:
+        """The attention of a layer of ``kind`` (LAYER_KINDS)."""
+        Dh = self.head_dim
+        if LAYER_KINDS[kind].window:
+            return AttnKind(self.window_heads or self.n_heads, self.window,
+                            (self.window_rope_theta or self.rope_theta, Dh,
+                             ()), self.attn_gate)
+        return AttnKind(self.n_heads, 0,
+                        (self.rope_theta, int(Dh * self.rope_partial),
+                         tuple(self.rope_yarn)), self.attn_gate)
 
     @property
     def experts_held(self) -> int:
@@ -342,9 +404,9 @@ class TransformerConfig:
             )
         pat = self.layer_pattern
         if pat:
-            if len(pat) != self.n_layers or set(pat) - set("*MEHR"):
+            if len(pat) != self.n_layers or set(pat) - set("*MEHRDW"):
                 raise ValueError(
-                    f"layer_pattern {pat!r}: one of '*MEHR' for each of "
+                    f"layer_pattern {pat!r}: one of '*MEHRDW' for each of "
                     f"the {self.n_layers} layers")
             if self.n_state_layers and not (
                     self.ssm_heads > 0
@@ -363,13 +425,38 @@ class TransformerConfig:
                     "an 'E' layer needs moe_experts >= moe_top_k > 0, a "
                     "held range inside the experts, and moe_latent, "
                     "moe_d_ff, moe_shared_d_ff > 0")
-            if "R" in pat and not (
+            if set("RW") & set(pat) and not (
                     0 < self.moe_top_k <= self.moe_experts
                     and self.moe_held_start + self.experts_held
                     <= self.moe_experts and self.moe_d_ff > 0):
                 raise ValueError(
-                    "an 'R' layer needs moe_experts >= moe_top_k > 0, a "
-                    "held range inside the experts and moe_d_ff > 0")
+                    "an 'R' or 'W' layer needs moe_experts >= moe_top_k > "
+                    "0, a held range inside the experts and moe_d_ff > 0")
+        if ("W" in pat) != (self.window > 0):
+            raise ValueError(
+                f"window {self.window} is read by 'W' layers alone: a "
+                f"pattern with one needs window > 0 ({pat!r})")
+        heads_w = self.window_heads or self.n_heads
+        if self.window and (heads_w % self.kv_heads
+                            or self.kv_cache_dtype != "compute"
+                            or self.pos_embed != "rope"):
+            raise ValueError(
+                f"a window layer needs window_heads {heads_w} a multiple "
+                f"of the K/V heads {self.kv_heads}, rope, and K/V pools in "
+                "the compute dtype (its ring is written without scales)")
+        if (self.rope_partial != 1.0 or self.rope_yarn) and not (
+                self.pos_embed == "rope" and len(self.rope_yarn) in (0, 5)
+                and int(self.head_dim * self.rope_partial) % 2 == 0
+                and 0 < self.rope_partial <= 1):
+            raise ValueError(
+                "rope_partial / rope_yarn need pos_embed 'rope', an even "
+                "rotated share of the head and YaRN's five numbers")
+        if (self.window or self.attn_gate or self.rope_partial != 1.0
+                or self.rope_yarn) and not pat:
+            raise ValueError(
+                "window, attn_gate, rope_partial and rope_yarn are read by a "
+                "patterned model's layers (layer_pattern), not the default "
+                "block's")
         if self.block_len:
             B = self.block_len
             if B < 2 or B > 128 or B & (B - 1):
@@ -391,6 +478,10 @@ class TransformerConfig:
                 raise ValueError(
                     "the block mask is written for attention 'full' and "
                     f"'flash', not {self.attention!r}")
+            if self.attn_gate or self.rope_partial != 1.0 or self.rope_yarn:
+                raise ValueError(
+                    "the block step (decode.paged_block_step) applies no "
+                    "attention gate and the default rotation alone")
         elif self.mask_id != -1:
             raise ValueError("mask_id is read with block_len > 0 only")
         if (len(self.ssm_multipliers), len(self.mlp_multipliers)) != (5, 2):
@@ -512,9 +603,13 @@ def _init_patterned(key, cfg: TransformerConfig):
         lp = {"ln1_scale": jnp.ones((D,), jnp.float32)}
         holds = LAYER_KINDS[kind]
         if holds.kv:
-            lp["wqkv"] = n((D, cfg.attn_width
-                            + 2 * cfg.kv_heads * cfg.head_dim), D ** -0.5)
-            lp["wo"] = n((cfg.attn_width, D), (2 * D * L) ** -0.5)
+            heads = cfg.attn_kind(kind).heads
+            width = heads * cfg.head_dim
+            lp["wqkv"] = n((D, width + 2 * cfg.kv_heads * cfg.head_dim),
+                           D ** -0.5)
+            lp["wo"] = n((width, D), (2 * D * L) ** -0.5)
+            if cfg.attn_gate:
+                lp["attn_gate"] = n((D, heads), D ** -0.5)
             if cfg.qk_norm:
                 lp["q_norm"] = jnp.ones((cfg.head_dim,), jnp.float32)
                 lp["k_norm"] = jnp.ones((cfg.head_dim,), jnp.float32)
@@ -547,6 +642,11 @@ def _init_patterned(key, cfg: TransformerConfig):
             lp["w_gate"] = n((held, D, F), D ** -0.5)
             lp["w_up"] = n((held, D, F), D ** -0.5)
             lp["w_down"] = n((held, F, D), (2 * F * L) ** -0.5)
+            if cfg.moe_shared_d_ff:
+                Fs = cfg.moe_shared_d_ff
+                lp["ws_gate"] = n((D, Fs), D ** -0.5)
+                lp["ws_up"] = n((D, Fs), D ** -0.5)
+                lp["ws_down"] = n((Fs, D), (2 * Fs * L) ** -0.5)
         if kind == "E":
             E, held = cfg.moe_experts, cfg.experts_held
             R, F, Fs = cfg.moe_latent, cfg.moe_d_ff, cfg.moe_shared_d_ff
@@ -745,27 +845,60 @@ def _rmsnorm(x, scale, eps: float = 1e-6):
     return (x * lax.rsqrt(var + eps).astype(x.dtype)) * scale.astype(x.dtype)
 
 
-def apply_rope(x, positions, cfg: TransformerConfig):
+def rope_frequencies(theta: float, dims: int, yarn: tuple = ()):
+    """(inv_freq (dims / 2,) float32, the factor cos and sin are scaled
+    by) of a rotation over ``dims`` dimensions. With ``yarn`` = (factor,
+    original positions, beta_fast, beta_slow, attention_factor), YaRN as
+    transformers' ``_compute_yarn_parameters`` (truncated): between the
+    dims ``floor(d ln(orig / (beta_fast 2 pi)) / (2 ln theta))`` and
+    ``ceil(d ln(orig / (beta_slow 2 pi)) / (2 ln theta))`` a linear ramp
+    blends the frequencies as they are (below) into the frequencies
+    divided by ``factor`` (above)."""
+    half = dims // 2
+    inv = theta ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
+    if not yarn:
+        return inv, 1.0
+    factor, orig, beta_fast, beta_slow, attention_factor = yarn
+
+    def dim_of(rotations):
+        return (dims * math.log(orig / (rotations * 2 * math.pi))
+                / (2 * math.log(theta)))
+
+    low = max(math.floor(dim_of(beta_fast)), 0)
+    high = min(math.ceil(dim_of(beta_slow)), dims - 1)
+    if low == high:
+        high += 0.001
+    ramp = jnp.clip((jnp.arange(half, dtype=jnp.float32) - low)
+                    / (high - low), 0.0, 1.0)
+    return inv / factor * ramp + inv * (1.0 - ramp), float(attention_factor)
+
+
+def apply_rope(x, positions, cfg: TransformerConfig, rope=None):
     """Rotary position embedding: rotate each (even, odd-half) feature
     pair of ``x`` (..., T, heads, head_dim) by angle pos·theta^(-2i/d).
     ``positions``: (..., T) int32 GLOBAL positions — scores then depend
     only on relative distance, which is what lets the same weights serve
     any context layout (ring/ulysses shards, KV-cache decode steps).
     Rotation is computed in f32 and cast back (bf16 angle resolution is
-    not enough at long range)."""
-    Dh = x.shape[-1]
-    half = Dh // 2
-    inv_freq = cfg.rope_theta ** (
-        -jnp.arange(0, half, dtype=jnp.float32) / half
-    )
-    angles = positions.astype(jnp.float32)[..., None] * inv_freq  # (..., T, half)
+    not enough at long range). ``rope`` (:class:`AttnKind`'s; ``None``:
+    the config's rope_theta over the whole head): a layer's own (theta,
+    rotated dims, yarn): the first dims rotate, paired by halves among
+    them, cos and sin scaled by YaRN's attention factor, the rest of the
+    head passes as it is."""
+    theta, dims, yarn = rope or (cfg.rope_theta, x.shape[-1], ())
+    inv, mscale = rope_frequencies(theta, dims, yarn)
+    half = dims // 2
+    angles = positions.astype(jnp.float32)[..., None] * inv  # (..., T, half)
     cos = jnp.cos(angles)[..., None, :]  # broadcast over heads
     sin = jnp.sin(angles)[..., None, :]
+    if mscale != 1.0:
+        cos, sin = cos * mscale, sin * mscale
     x1 = x[..., :half].astype(jnp.float32)
-    x2 = x[..., half:].astype(jnp.float32)
-    return jnp.concatenate(
-        [x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1
-    ).astype(x.dtype)
+    x2 = x[..., half:dims].astype(jnp.float32)
+    rot = [x1 * cos - x2 * sin, x1 * sin + x2 * cos]
+    if dims < x.shape[-1]:
+        rot.append(x[..., dims:].astype(jnp.float32))
+    return jnp.concatenate(rot, axis=-1).astype(x.dtype)
 
 
 def scaled(x, multiplier: float):
@@ -775,15 +908,16 @@ def scaled(x, multiplier: float):
     return x if multiplier == 1.0 else x * jnp.asarray(multiplier, x.dtype)
 
 
-def project_qkv(h, lp, cfg: TransformerConfig):
+def project_qkv(h, lp, cfg: TransformerConfig, heads: int | None = None):
     """Fused qkv projection + head split, GQA-narrow K/V (kv_heads, not
     yet expanded). THE qkv layout definition — shared by the training
     layer (_layer) and the decode path (models/decode.py) so the two can
     never disagree on the split or head order. ``h``: (..., d_model);
-    returns q (..., n_heads, Dh), k/v (..., kv_heads, Dh)."""
+    returns q (..., n_heads, Dh), k/v (..., kv_heads, Dh). ``heads``: a
+    layer's own query heads (:meth:`TransformerConfig.attn_kind`)."""
     lead = h.shape[:-1]
     dt = h.dtype
-    H, Hkv, Dh = cfg.n_heads, cfg.kv_heads, cfg.head_dim
+    H, Hkv, Dh = heads or cfg.n_heads, cfg.kv_heads, cfg.head_dim
     h = scaled(h, cfg.attention_in_multiplier)
     qkv = jnp.dot(h, matmul_weight(lp, "wqkv", dt))  # column-parallel
     q, k, v = jnp.split(qkv, [H * Dh, (H + Hkv) * Dh], axis=-1)
@@ -796,11 +930,18 @@ def project_qkv(h, lp, cfg: TransformerConfig):
     return q, k, v.reshape(*lead, Hkv, Dh)
 
 
-def _attention(q, k, v, cfg: TransformerConfig, mesh):
+def _attention(q, k, v, cfg: TransformerConfig, mesh, window: int = 0):
     """Dispatch to the configured attention impl. ring/ulysses wrap the
     rank-local kernels in ``shard_map`` over (dp, sp, tp) — sequence
     travels the ``sp`` ring while heads stay tensor-sharded. A model with
-    ``cfg.block_len`` runs unsharded under the block mask."""
+    ``cfg.block_len`` runs unsharded under the block mask, a window layer
+    (``window`` > 0) unsharded under its window."""
+    if window:
+        if cfg.attention == "flash":
+            from hpc_patterns_tpu.ops import flash_attention
+
+            return flash_attention(q, k, v, window=window)
+        return full_attention(q, k, v, causal=True, window=window)
     if cfg.block_len:
         if cfg.attention == "flash":
             from hpc_patterns_tpu.ops import flash_attention
@@ -951,35 +1092,44 @@ def _qkv_block(x, lp, cfg: TransformerConfig, mesh):
     return _qkv_heads(attn_norm(x, lp, cfg), lp, cfg, mesh)
 
 
-@scoped("attn")
-def _qkv_heads(h, lp, cfg: TransformerConfig, mesh):
+def attn_scope(ak: AttnKind | None) -> str:
+    """The ``jax.named_scope`` of a layer's attention: ``attn_win`` for a
+    window layer, so that a trace tells the two kinds apart, ``attn`` for
+    every other."""
+    return "attn_win" if ak is not None and ak.window else "attn"
+
+
+def _qkv_heads(h, lp, cfg: TransformerConfig, mesh, ak=None):
     """The normed input's fused qkv projection + rope + the GQA
-    narrow-vs-expand decision."""
-    B, T, D = h.shape
-    H = cfg.n_heads
-    q, k, v = project_qkv(h, lp, cfg)
-    if cfg.pos_embed == "rope":
-        # global positions: the layer always sees the full sequence (the
-        # sp shard_map lives inside _attention), so iota(T) is correct
-        # under every sharding
-        pos = lax.broadcasted_iota(jnp.int32, (T,), 0)
-        q = apply_rope(q, pos, cfg)
-        k = apply_rope(k, pos, cfg)
-    if cfg.kv_heads != H:
-        # GQA: every attention impl consumes the NARROW K/V (no expanded
-        # copy in HBM — the group-factor memory/bandwidth saving; the
-        # ring additionally circulates group-factor less K/V per step).
-        # The only layout constraint here: with heads tensor-sharded, tp
-        # must divide kv_heads so shards keep whole kv heads — else fall
-        # back to jnp.repeat expansion. (ulysses has its own internal
-        # per-rank fallback when its axis can't scatter the kv heads;
-        # decode does its own grouped-cache attention, models/decode.py.)
-        tp = max(mesh_axis_size(mesh, cfg.axis_tp), 1) if mesh is not None else 1
-        narrow = cfg.kv_heads % tp == 0
-        if not narrow:
-            k = jnp.repeat(k, H // cfg.kv_heads, axis=2)
-            v = jnp.repeat(v, H // cfg.kv_heads, axis=2)
-    return q, k, v
+    narrow-vs-expand decision. ``ak``: a patterned layer's
+    :class:`AttnKind` (its heads and rotation)."""
+    with jax.named_scope(attn_scope(ak)):
+        B, T, D = h.shape
+        H = cfg.n_heads if ak is None else ak.heads
+        q, k, v = project_qkv(h, lp, cfg, H)
+        if cfg.pos_embed == "rope":
+            # global positions: the layer always sees the full sequence (the
+            # sp shard_map lives inside _attention), so iota(T) is correct
+            # under every sharding
+            pos = lax.broadcasted_iota(jnp.int32, (T,), 0)
+            rope = None if ak is None else ak.rope
+            q = apply_rope(q, pos, cfg, rope)
+            k = apply_rope(k, pos, cfg, rope)
+        if cfg.kv_heads != H:
+            # GQA: every attention impl consumes the NARROW K/V (no expanded
+            # copy in HBM — the group-factor memory/bandwidth saving; the
+            # ring additionally circulates group-factor less K/V per step).
+            # The only layout constraint here: with heads tensor-sharded, tp
+            # must divide kv_heads so shards keep whole kv heads — else fall
+            # back to jnp.repeat expansion. (ulysses has its own internal
+            # per-rank fallback when its axis can't scatter the kv heads;
+            # decode does its own grouped-cache attention, models/decode.py.)
+            tp = max(mesh_axis_size(mesh, cfg.axis_tp), 1) if mesh is not None else 1
+            narrow = cfg.kv_heads % tp == 0
+            if not narrow:
+                k = jnp.repeat(k, H // cfg.kv_heads, axis=2)
+                v = jnp.repeat(v, H // cfg.kv_heads, axis=2)
+        return q, k, v
 
 
 def _post_attn(x, o, lp, cfg: TransformerConfig, mesh, act_spec):
@@ -1064,12 +1214,22 @@ def _post_block(x, o, lp, cfg: TransformerConfig, mesh, act_spec,
         return (c(x + h, act_spec), aux, *st)
 
 
-@scoped("attn")
-def attn_proj(o, lp, cfg: TransformerConfig, dt):
-    """The attention's output projection: o (..., H, Dh) -> (..., D)."""
-    o = jnp.dot(o.reshape(*o.shape[:-2], cfg.attn_width).astype(dt),
-                matmul_weight(lp, "wo", dt))
-    return scaled(o, cfg.attention_out_multiplier)
+def attn_proj(o, lp, cfg: TransformerConfig, dt, ak=None, hn=None):
+    """The attention's output projection: o (..., H, Dh) -> (..., D).
+    With a per-head gate (``ak.gate``) each head's output is first
+    multiplied by ``sigmoid(hn W_g)``, ``hn`` (..., D) the layer's normed
+    input (scope ``gate``)."""
+    with jax.named_scope(attn_scope(ak)):
+        if ak is not None and ak.gate:
+            with jax.named_scope("gate"):
+                g = jax.nn.sigmoid(jnp.dot(
+                    hn, matmul_weight(lp, "attn_gate", dt)
+                ).astype(jnp.float32))
+                o = (o.astype(jnp.float32) * g[..., None]).astype(dt)
+        o = jnp.dot(o.reshape(*o.shape[:-2], o.shape[-2] * o.shape[-1]
+                              ).astype(dt),
+                    matmul_weight(lp, "wo", dt))
+        return scaled(o, cfg.attention_out_multiplier)
 
 
 @scoped("mlp")
@@ -1111,11 +1271,11 @@ def ssm_mixer_step(x, lp, cfg: TransformerConfig, state, active=None):
     return x + out, state
 
 
-@scoped("attn")
-def attn_norm(x, lp, cfg: TransformerConfig):
+def attn_norm(x, lp, cfg: TransformerConfig, ak=None):
     """The pre-norm of a layer that has attention; an "H" layer's Mamba
     half reads the same normed input."""
-    return _rmsnorm(x, lp["ln1_scale"], cfg.norm_eps)
+    with jax.named_scope(attn_scope(ak)):
+        return _rmsnorm(x, lp["ln1_scale"], cfg.norm_eps)
 
 
 @scoped("ssm")
@@ -1170,11 +1330,14 @@ def routed_mlp(x, lp, cfg: TransformerConfig, valid=None):
     D = x.shape[-1]
     h = _rmsnorm(x, lp["ln2_scale"], cfg.norm_eps).reshape(-1, D)
     w = lambda name: matmul_weight(lp, name, dt)
+    shared = (None if "ws_gate" not in lp
+              else (w("ws_gate"), w("ws_up"), w("ws_down")))
     out, stats = moe.gated_moe(
         h, lp["router"], w("w_gate"), w("w_up"), w("w_down"),
         held_start=cfg.moe_held_start, top_k=cfg.moe_top_k,
         renorm=cfg.moe_renorm,
-        valid=None if valid is None else valid.reshape(-1))
+        valid=None if valid is None else valid.reshape(-1),
+        scale=cfg.moe_scale, shared=shared)
     return x + out.reshape(x.shape).astype(dt), stats
 
 
@@ -1316,12 +1479,17 @@ def forward_hidden(params, tokens, cfg: TransformerConfig, mesh=None):
                 "a patterned model runs unsharded (mesh=None): the state "
                 "and expert layers carry no sharding rules yet")
         for kind, lp in zip(cfg.layer_pattern, params["layers"]):
-            if kind in "*R":   # "R": attention, then the routed experts
-                q, k, v = _qkv_block(x, lp, cfg, None)
-                x = x + attn_proj(_attention(q, k, v, cfg, None), lp, cfg,
-                                  dt)
-                if kind == "R":
+            if kind in "*RDW":   # attention, then the kind's MLP
+                ak = cfg.attn_kind(kind)
+                hn = attn_norm(x, lp, cfg, ak)
+                q, k, v = _qkv_heads(hn, lp, cfg, None, ak)
+                x = x + attn_proj(_attention(q, k, v, cfg, None, ak.window),
+                                  lp, cfg, dt, ak, hn)
+                mlp = LAYER_KINDS[kind].mlp
+                if mlp == "routed":
                     x, _ = routed_mlp(x, lp, cfg)
+                elif mlp == "gated":
+                    x = gated_mlp(x, lp, cfg)
             elif kind == "M":
                 x, _ = ssm_mixer(x, lp, cfg)
             elif kind == "E":

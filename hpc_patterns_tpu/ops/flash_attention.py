@@ -61,7 +61,7 @@ _NEG_INF = -1e30
 # default sequential walk is the fast path; do not "optimize" this.
 
 
-def _causal_mask(s, q_start, k_start, mask_block: int = 1):
+def _causal_mask(s, q_start, k_start, mask_block: int = 1, window: int = 0):
     """Mask score block ``s`` so position (i, j) survives iff the global
     key index k_start+j is at or before the global query index q_start+i.
     Shared by the forward and both backward kernels — the mask must be
@@ -70,11 +70,16 @@ def _causal_mask(s, q_start, k_start, mask_block: int = 1):
     alone): the BLOCK mask, a key survives up to the last position of the
     query's block of that many positions. It divides the tiles and the
     offsets, so a tile's last query is its block's last and the tiles
-    that are skipped and elided stay the causal ones."""
+    that are skipped and elided stay the causal ones. ``window`` > 0
+    (the forward alone): a key survives only inside the query's window,
+    ``q - window < k <= q``."""
     q_pos = q_start + lax.broadcasted_iota(jnp.int32, s.shape, 0)
     k_pos = k_start + lax.broadcasted_iota(jnp.int32, s.shape, 1)
     if mask_block > 1:
         q_pos = q_pos // mask_block * mask_block + (mask_block - 1)
+    if window:
+        return jnp.where((k_pos <= q_pos) & (k_pos > q_pos - window), s,
+                         _NEG_INF)
     return jnp.where(k_pos <= q_pos, s, _NEG_INF)
 
 
@@ -88,15 +93,27 @@ def _kv_row(H, Hkv):
     return lambda bh: (bh // H) * Hkv + (bh % H) // group
 
 
-def _kv_index_map(block_q, block_k, causal, H, Hkv):
+def _kv_index_map(block_q, block_k, causal, H, Hkv, window: int = 0):
     """kv-block index map for grid (bh, qi, ki): causal clamps ki to the
     last block visible from this query block, so every fully-future grid
     step revisits the previous block and Pallas skips its HBM fetch.
     The row map sends each q head to its (possibly shared) kv head — GQA
-    streams the NARROW cache, no expanded copy in HBM."""
+    streams the NARROW cache, no expanded copy in HBM. ``window`` > 0:
+    ki is also clamped UP to the first block inside the first query's
+    window, so the blocks wholly behind it are never fetched either."""
     row = _kv_row(H, Hkv)
     if not causal:
         return lambda bh, qi, ki, offs: (row(bh), ki, 0)
+    if window:
+        def idx_w(bh, qi, ki, offs):
+            q_start_g = offs[0] + qi * block_q
+            last = jnp.maximum(
+                (q_start_g + block_q - 1 - offs[1]) // block_k, 0)
+            first = jnp.clip((q_start_g - window + 1 - offs[1]) // block_k,
+                             0, last)
+            return row(bh), jnp.minimum(jnp.maximum(ki, first), last), 0
+
+        return idx_w
 
     def idx(bh, qi, ki, offs):
         q_end_g = offs[0] + (qi + 1) * block_q - 1
@@ -131,7 +148,8 @@ def _q_index_map(block_q, block_k, causal, n_q, H, Hkv):
 
 
 def _kernel(offs_ref, q_ref, k_ref, v_ref, o_ref, *rest, scale: float,
-            causal: bool, with_lse: bool, mask_block: int = 1):
+            causal: bool, with_lse: bool, mask_block: int = 1,
+            window: int = 0):
     # grid (B·H, n_q, n_kv): K/V stream through VMEM one block per grid
     # step (no whole-sequence residency — T is bounded by HBM, not VMEM);
     # the online-softmax state (m, l, acc) carries across the kv axis in
@@ -157,6 +175,11 @@ def _kernel(offs_ref, q_ref, k_ref, v_ref, o_ref, *rest, scale: float,
     # compute is skipped here and its fetch was already elided by the
     # clamped index map (the streamed analog of the loop-bound skip)
     visible = (k_start_g <= q_start_g + block_q - 1) if causal else True
+    if window:
+        # a block wholly behind the first query's window: skipped too
+        # (its fetch elided by the index map's clamp from below)
+        visible = jnp.logical_and(
+            visible, k_start_g + block_k - 1 > q_start_g - window)
 
     @pl.when(visible)
     def _():
@@ -168,7 +191,7 @@ def _kernel(offs_ref, q_ref, k_ref, v_ref, o_ref, *rest, scale: float,
         v = v_ref[:]
         s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
         if causal:
-            s = _causal_mask(s, q_start_g, k_start_g, mask_block)
+            s = _causal_mask(s, q_start_g, k_start_g, mask_block, window)
         m = m_ref[:]
         m_new = jnp.maximum(m, s.max(axis=-1, keepdims=True))
         p = jnp.exp(s - m_new)
@@ -431,7 +454,8 @@ def _sds(shape, dtype, vma):
     return jax.ShapeDtypeStruct(shape, dtype, vma=vma)
 
 
-def _masked_scores(qr, kr, offs, scale, causal, mask_block: int = 1):
+def _masked_scores(qr, kr, offs, scale, causal, mask_block: int = 1,
+                   window: int = 0):
     """(N, Tq, Tk) scaled scores with the global causal mask — the dense
     mirror of the kernels' per-block ``_causal_mask`` walk."""
     s = jnp.einsum(
@@ -442,12 +466,15 @@ def _masked_scores(qr, kr, offs, scale, causal, mask_block: int = 1):
         k_pos = offs[1] + lax.broadcasted_iota(jnp.int32, s.shape, 2)
         if mask_block > 1:
             q_pos = q_pos // mask_block * mask_block + (mask_block - 1)
-        s = jnp.where(k_pos <= q_pos, s, _NEG_INF)
+        keep = k_pos <= q_pos
+        if window:
+            keep = keep & (k_pos > q_pos - window)
+        s = jnp.where(keep, s, _NEG_INF)
     return s
 
 
 def _dense_forward(qr, kr, vr, offs, *, causal, scale, need_lse, out_dtype,
-                   mask_block: int = 1):
+                   mask_block: int = 1, window: int = 0):
     """jnp mirror of ``_kernel`` (same clamps and dead-row semantics),
     used where Pallas interpret mode can't run — inside ``shard_map`` on
     CPU (its vma tracking rejects kernel-internal constants). Real-TPU
@@ -455,7 +482,7 @@ def _dense_forward(qr, kr, vr, offs, *, causal, scale, need_lse, out_dtype,
     exactly for f32 inputs; for bf16 inputs the kernel's native-dtype
     matmuls round p to bf16 where this mirror keeps f32 — equal only to
     bf16 precision."""
-    s = _masked_scores(qr, kr, offs, scale, causal, mask_block)
+    s = _masked_scores(qr, kr, offs, scale, causal, mask_block, window)
     m = s.max(-1, keepdims=True)
     p = jnp.exp(s - m) * (s > _NEG_INF / 2)  # fully-masked rows stay 0
     l = jnp.maximum(p.sum(-1, keepdims=True), 1e-30)
@@ -483,7 +510,7 @@ def _dense_backward(qr, kr, vr, dor, lse, delta, offs, *, causal, scale):
 
 
 def _forward_impl(q, k, v, offs, *, causal, scale, block_q, block_k,
-                  interpret, need_lse, mask_block: int = 1):
+                  interpret, need_lse, mask_block: int = 1, window: int = 0):
     """Shared forward. ``offs``: (1, 2) int32 [q_offset, k_offset].
     Returns (out, residuals) — residuals in kernel layout (B·H, T, D),
     lse (B·H, Tq, 1) f32; both None-lse when ``need_lse`` is False (the
@@ -509,11 +536,12 @@ def _forward_impl(q, k, v, offs, *, causal, scale, block_q, block_k,
     kernel = functools.partial(
         _kernel, scale=scale, causal=causal, with_lse=need_lse,
         **({"mask_block": mask_block} if mask_block > 1 else {}),
+        **({"window": window} if window else {}),
     )
     # index maps see the prefetched offsets: for causal, clamp the kv
     # block index to the last visible block — consecutive clamped steps
     # revisit the same block, so Pallas elides the HBM fetch entirely
-    kv_idx = _kv_index_map(block_q, block_k, causal, H, Hkv)
+    kv_idx = _kv_index_map(block_q, block_k, causal, H, Hkv, window)
     blk_q = pl.BlockSpec((None, block_q, D),
                          lambda bh, qi, ki, offs: (bh, qi, 0),
                          memory_space=pltpu.VMEM)
@@ -525,7 +553,8 @@ def _forward_impl(q, k, v, offs, *, causal, scale, block_q, block_k,
         vr_e = _expand_rows(vr, B, Hkv, group)
         outr, lse = _dense_forward(qr, kr_e, vr_e, offs, causal=causal,
                                    scale=scale, need_lse=need_lse,
-                                   out_dtype=q.dtype, mask_block=mask_block)
+                                   out_dtype=q.dtype, mask_block=mask_block,
+                                   window=window)
         out = outr.reshape(B, H, Tq, D).transpose(0, 2, 1, 3)
         return out, (qr, kr, vr, outr, lse)
     out_specs = [blk_q]
@@ -839,6 +868,7 @@ def flash_attention(
     block_q_bwd: int | None = None,
     block_k_bwd: int | None = None,
     mask_block: int = 1,
+    window: int = 0,
 ):
     """Softmax attention over (batch, seq, heads, head_dim) inputs.
 
@@ -854,8 +884,16 @@ def flash_attention(
     tiles): the BLOCK mask in place of the causal one, position i sees j
     iff ``j // mask_block <= i // mask_block``: the whole blocks before
     it and all of its own. Forward only (no VJP is defined for it).
+
+    ``window`` > 0 (causal): position i sees i - window < j <= i alone; a
+    K/V tile wholly behind a query tile's window is neither fetched nor
+    computed. Forward only, as ``mask_block``.
     """
-    if mask_block > 1:
+    if window or mask_block > 1:   # the forward-only masks
+        if window and (not causal or mask_block > 1 or window < 1):
+            raise ValueError(
+                f"window {window}: a positive width with causal=True and "
+                "no block mask")
         if not causal or mask_block > 128 or mask_block & (mask_block - 1):
             raise ValueError(
                 f"mask_block {mask_block}: a power of two up to 128, with "
@@ -863,7 +901,7 @@ def flash_attention(
         return _forward_impl(q, k, v, _zero_offs(), causal=True, scale=scale,
                              block_q=block_q, block_k=block_k,
                              interpret=interpret, need_lse=False,
-                             mask_block=mask_block)[0]
+                             mask_block=mask_block, window=window)[0]
     return _flash_with_vjp(q, k, v, causal, scale, block_q, block_k,
                            interpret, bwd, block_q_bwd, block_k_bwd)
 

@@ -115,13 +115,17 @@ def _scores(q, k, ks, scale: float):
     return s
 
 
-def _update(s, v, vs, m, l, acc, block_start, pos):
+def _update(s, v, vs, m, l, acc, block_start, pos, window: int = 0):
     """The online-softmax state (m, l, acc) updated by a block's scores
     ``s`` and values ``v`` (with lane-major scales ``vs`` for an int8
     block, None otherwise), the block at logical rows [block_start, ...),
-    rows past ``pos`` masked."""
+    rows past ``pos`` masked, and with ``window`` > 0 the rows at or
+    before ``pos - window`` too."""
     k_pos = block_start + lax.broadcasted_iota(jnp.int32, s.shape, 1)
-    s = jnp.where(k_pos <= pos, s, _NEG_INF)
+    if window:
+        s = jnp.where((k_pos <= pos) & (k_pos > pos - window), s, _NEG_INF)
+    else:
+        s = jnp.where(k_pos <= pos, s, _NEG_INF)
     m_new = jnp.maximum(m, s.max(axis=-1, keepdims=True))
     p = jnp.exp(s - m_new)
     rescale = jnp.exp(m - m_new)
@@ -301,7 +305,7 @@ def flash_decode_attention(
 
 def _decode_kernel_paged(rows_ref, pos_ref, table_ref, q_ref, *rest,
                          scale: float, pages: int, depth: int,
-                         quantized: bool, ragged: bool):
+                         quantized: bool, ragged: bool, window: int = 0):
     # grid (visited rows,): grid step i is row rows_ref[i] with all of its
     # K/V heads. The pools stay in HBM and the kernel copies the pages
     # itself. The call's work is ONE stream of (row, page) items in
@@ -310,7 +314,10 @@ def _decode_kernel_paged(rows_ref, pos_ref, table_ref, q_ref, *rest,
     # brings them all) and its copies start depth - 1 items before it is
     # attended over, so the fetches run on across the rows: a row's first
     # page is on its way while the row before it is attended over. ``cur``
-    # (SMEM, carried over the grid) is the fetch cursor.
+    # (SMEM, carried over the grid) is the fetch cursor. ``window`` > 0: a
+    # row's pages are those from the one holding ``pos - window + 1`` to
+    # the one holding ``pos``, and its keys at or before ``pos - window``
+    # are masked; the cursor counts pages from the row's first.
     n = 4 if quantized else 2               # k, v [, k scales, v scales]
     pools, o_ref, bufs = rest[:n], rest[n], rest[n + 1:2 * n + 1]
     sems, cur, m_ref, l_ref, acc_ref = rest[2 * n + 1:]
@@ -320,12 +327,18 @@ def _decode_kernel_paged(rows_ref, pos_ref, table_ref, q_ref, *rest,
     def row_pos(r):
         return pos_ref[r] if ragged else pos_ref[0]
 
+    def first_page(r):
+        return jnp.maximum(row_pos(r) - window + 1, 0) // page_size
+
     def live_pages(r):
         # the fetch cursor and the walk must count a row alike whatever
         # its position holds: at least its first page, at most its table
-        return jnp.clip(row_pos(r) // page_size + 1, 1, pages)
+        n = jnp.clip(row_pos(r) // page_size + 1, 1, pages)
+        return n - first_page(r) if window else n
 
     def copies(r, page, slot):
+        if window:   # ``page`` counts from the row's first page
+            page = first_page(r) + page
         at = table_ref[r * pages + page]
         return [pltpu.make_async_copy(pool.at[at], buf.at[slot],
                                       sems.at[j, slot])
@@ -372,6 +385,8 @@ def _decode_kernel_paged(rows_ref, pos_ref, table_ref, q_ref, *rest,
     for c in copies(r, 0, cur[3] % depth):
         c.wait()
 
+    start = first_page(r) if window else 0
+
     def attend(page, s):
         fetch()
         slot = cur[3] % depth
@@ -388,7 +403,9 @@ def _decode_kernel_paged(rows_ref, pos_ref, table_ref, q_ref, *rest,
             m_ref[h], l_ref[h], acc_ref[h] = _update(
                 s[h], bufs[1][slot, h],
                 bufs[3][slot, h] if quantized else None,
-                m_ref[h], l_ref[h], acc_ref[h], page * page_size, pos)
+                m_ref[h], l_ref[h], acc_ref[h],
+                (start + page if window else page) * page_size, pos,
+                window)
         cur[3] = cur[3] + 1
         return s_ahead
 
@@ -398,9 +415,9 @@ def _decode_kernel_paged(rows_ref, pos_ref, table_ref, q_ref, *rest,
 
 # jitted: the layers of a program call it with one signature, so the outer
 # trace lowers the kernel once
-@functools.partial(jax.jit, static_argnums=(8, 9, 10))
+@functools.partial(jax.jit, static_argnums=(8, 9, 10, 11))
 def _paged_call(q, k_pool, v_pool, table, pos, active, k_scale_pool,
-                v_scale_pool, scale, depth, interpret):
+                v_scale_pool, scale, depth, interpret, window=0):
     # ``pos`` (B,) a position a row, or (1,) one for all (B == 1: the same)
     B, H, D = q.shape
     _, Hkv, P, _ = k_pool.shape
@@ -418,7 +435,8 @@ def _paged_call(q, k_pool, v_pool, table, pos, active, k_scale_pool,
     out = pl.pallas_call(
         functools.partial(_decode_kernel_paged, scale=scale, pages=pages,
                           depth=depth, quantized=quantized,
-                          ragged=pos.shape[0] == B),
+                          ragged=pos.shape[0] == B,
+                          **({"window": window} if window else {})),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
             grid=(count,),
@@ -460,6 +478,7 @@ def flash_decode_paged(
     scale: float | None = None,
     pages_per_step: int | None = None,
     interpret: bool | None = None,
+    window: int = 0,
 ):
     """Single-query attention against a PAGED KV cache.
 
@@ -500,6 +519,12 @@ def flash_decode_paged(
     while it is attended over (its scores run a page ahead), and
     ``pages_per_step`` - 2 more are in flight. Default: the linear
     kernel's 2048-row streaming block in pages.
+
+    ``window`` > 0 (static): a row attends over its keys
+    ``pos - window < j <= pos`` alone, and only the pages they lie on,
+    from ``max(0, pos - window + 1) // page_size`` to ``pos // page_size``,
+    are fetched (a window layer's ring: the table sends each of those
+    logical pages to its ring page).
     """
     B, H, D = q.shape
     n_pool, Hkv, P, Dp = k_pool.shape
@@ -530,7 +555,8 @@ def flash_decode_paged(
         jnp.asarray(pos, jnp.int32).reshape(B if ragged else 1), active,
         k_scale_pool, v_scale_pool, float(scale),
         max(2, min(int(pages_per_step), pages)),
-        resolve_interpret(interpret, "flash_decode_paged"))
+        resolve_interpret(interpret, "flash_decode_paged"),
+        *((int(window),) if window else ()))
 
 
 def fold_block(q, kv_heads: int):

@@ -375,19 +375,33 @@ def latent_moe(h, router_w, router_b, w_down, w_up, w1, w2, ws1, ws2, *,
 
 
 def gated_moe(h, router_w, w_gate, w_up, w_down, *, held_start: int,
-              top_k: int, renorm: bool = True, valid=None):
+              top_k: int, renorm: bool = True, valid=None,
+              scale: float = 1.0, shared=None):
     """A layer of softmax-routed SiLU-gated experts on its share of them:
     h (N, D) -> (out (N, D) float32, stats).
 
         idx, g = softmax_route(h)               over all experts
-        out = sum_k g_k Wd_k (silu(Wg_k h) * (Wu_k h))      the held picks
+        out = sum_k scale g_k Wd_k (silu(Wg_k h) * (Wu_k h))  the held picks
+              + Ws_d (silu(Ws_g h) * (Ws_u h))  with ``shared``
 
-    No shared expert and no latent width: the experts read the hidden
-    state. On one chip the layer runs without its exchange: what the
-    experts held elsewhere would add is not here."""
+    No latent width: the experts read the hidden state. ``shared`` =
+    (Ws_g, Ws_u, Ws_d): one SiLU-gated expert that every token takes,
+    whole on every chip, added as it is (scope ``shared``). With
+    ``scale`` 1 and no shared expert nothing of either is emitted. On one
+    chip the layer runs without its exchange: what the experts held
+    elsewhere would add is not here."""
     with jax.named_scope("route"):
         idx, gates = softmax_route(h, router_w, top_k=top_k, renorm=renorm)
+        if scale != 1.0:
+            gates = gates * scale
     with jax.named_scope("experts"):
-        return held_experts(h, idx, gates, w_up, w_down,
-                            held_start=held_start, activation=silu,
-                            valid=valid, w_gate=w_gate)
+        out, stats = held_experts(h, idx, gates, w_up, w_down,
+                                  held_start=held_start, activation=silu,
+                                  valid=valid, w_gate=w_gate)
+    if shared is not None:
+        ws_gate, ws_up, ws_down = shared
+        with jax.named_scope("shared"):
+            mid = silu(jnp.dot(h, ws_gate)) * jnp.dot(h, ws_up)
+            out = out + jnp.dot(mid, ws_down,
+                                preferred_element_type=jnp.float32)
+    return out, stats

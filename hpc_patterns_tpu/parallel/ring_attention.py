@@ -231,13 +231,14 @@ def _ring_attention_flash(q, k, v, axis, *, size, me, q_offset, causal,
 
 
 def full_attention(q, k, v, *, causal: bool = False, scale: float | None = None,
-                   mask_block: int = 1):
+                   mask_block: int = 1, window: int = 0):
     """Single-device oracle: plain softmax attention over the full
     sequence, used by tests to validate the ring result (§4.2 style).
     K/V may be GQA-narrow (kv_heads dividing q's heads) — grouped-query
     scores, never an expanded K/V copy. ``mask_block`` > 1 (with
     ``causal``): the BLOCK mask, position i sees j iff
-    j // mask_block <= i // mask_block."""
+    j // mask_block <= i // mask_block. ``window`` > 0 (with ``causal``):
+    position i sees i - window < j <= i alone."""
     _check_gqa(q, k, v)
     B, T, H, D = q.shape
     if scale is None:
@@ -248,7 +249,10 @@ def full_attention(q, k, v, *, causal: bool = False, scale: float | None = None,
         s_idx = lax.broadcasted_iota(jnp.int32, s.shape, 3)
         if mask_block > 1:   # the last position of the query's block
             t_idx = t_idx // mask_block * mask_block + (mask_block - 1)
-        s = jnp.where(s_idx <= t_idx, s, _NEG_INF)
+        keep = s_idx <= t_idx
+        if window:
+            keep = keep & (s_idx > t_idx - window)
+        s = jnp.where(keep, s, _NEG_INF)
     p = jax.nn.softmax(s, axis=-1)
     out = _grouped_pv(p, v)
     return jnp.einsum("bhtd->bthd", out).astype(q.dtype)

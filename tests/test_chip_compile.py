@@ -381,3 +381,28 @@ def test_a_block_steps_kv_rows_land_in_their_pool_in_place(
     memory = compiled.memory_analysis()
     assert memory.temp_size_in_bytes < 64 << 20
     assert memory.alias_size_in_bytes >= 4 * 2 * pool[0] * pool[1] * 256 * 128
+
+
+# laguna-s-2.1-ep2: a window layer's 72 query heads over 8 K/V heads of 128,
+# window 512; the prefill at 4,096 and the ring of 3 pages of 256 a slot of
+# 64 slots (192 pages) a decode step reads
+@pytest.mark.parametrize("kernel", ["flash_fwd", "flash_decode_paged"])
+def test_attention_kernels_compile_under_a_window(one_chip, no_compile_cache,
+                                                  kernel):
+    shape = lambda *s, dt=jnp.bfloat16: jax.ShapeDtypeStruct(
+        s, dt, sharding=one_chip)
+    if kernel == "flash_fwd":
+        fn = lambda q, k, v: flash_attention(q, k, v, window=512,
+                                             interpret=False)
+        args = [shape(1, 4096, 72, 128), shape(1, 4096, 8, 128),
+                shape(1, 4096, 8, 128)]
+    else:
+        fn = lambda q, kp, vp, table, pos, act: flash_decode_paged(
+            q, kp, vp, table, pos, active=act, scale=128 ** -0.5,
+            window=512, interpret=False)
+        args = [shape(64, 72, 128), shape(192, 8, 256, 128),
+                shape(192, 8, 256, 128), shape(64, 16, dt=jnp.int32),
+                shape(64, dt=jnp.int32), shape(64, dt=jnp.bool_)]
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    calls = [line for line in text.splitlines() if "tpu_custom_call" in line]
+    assert len(calls) == 1 and re.search(rf"%{kernel}[.\d]* = ", calls[0])
