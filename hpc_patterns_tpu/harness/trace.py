@@ -212,8 +212,9 @@ class TraceRecorder:
         (async dispatch — the device may start later). Returns the
         stamp to hand to :meth:`mark_complete`. ``track`` selects a
         device SUBTRACK (``TID_DEVICE + track``): windows that may
-        overlap in time — an admission prefill behind an in-flight
-        decode chunk — must live on different subtracks, because
+        overlap in time — an admission's window (dispatch to its first
+        token's readback) and a decode chunk's — must live on
+        different subtracks, because
         Chrome-trace sync slices on one track must nest."""
         ts = time.perf_counter()
         self._push("i", "device", f"{name}.dispatch", ts,

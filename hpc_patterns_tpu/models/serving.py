@@ -43,14 +43,16 @@ Production shape (round 6), three coupled levers:
   bounded by the LADDER size, not the number of distinct prompt
   lengths in the stream (causality keeps the true-prefix K/V and the
   last-real-token logits exact — decode.prefill's ``last_pos`` route);
-- **overlapped admission**: the decode chunk is DISPATCHED first and
-  admissions (table upload, prefill, first-token pick) are enqueued
-  behind it — JAX async dispatch keeps the device queue fed while the
-  host does admission work, and the first-token readback is deferred
-  to the next sync point instead of stalling the loop per admission.
-  The admission-bubble fraction (host admission time exposed with no
-  decode work in flight) is measured per ``run()`` and emitted through
-  the metrics registry;
+- **admissions ahead of the chunk**: a round enqueues its admissions
+  (table upload, prefill, first-token pick) FIRST and then the decode
+  chunk for every live row, the rows just admitted included — JAX
+  async dispatch keeps the device queue fed, a first token waits for
+  its own prefill and not for a whole chunk, and its readback comes
+  after the chunk's dispatch, with the chunk queued behind it. The
+  chunk's start cursors are the host's own mirror of ``pos``, so
+  nothing is read back before the dispatch. The admission-bubble
+  fraction (host admission time with no decode work in flight) is
+  measured per ``run()`` and emitted through the metrics registry;
 - **sampling in the engine**: per-row temperature and per-row PRNG key
   streams (``temperature``/``top_k``/``seed``; per-request overrides
   via :meth:`ContinuousBatcher.submit`). Each row consumes its key
@@ -422,7 +424,6 @@ class _Slot:
     prefix: list = field(default_factory=list)  # pre-preemption tokens
     padded_len: int = 0      # the admission rung this row prefilled at
     shared_pages: int = 0    # leading table entries mapped SHARED
-    cursor: int = 0          # a diffusion row's block start, host mirror
 
 
 @partial(jax.jit,
@@ -789,12 +790,16 @@ class EngineCore:
     causally invisible and overwritten as the row generates). None =
     exact lengths (one compile per distinct length).
 
-    ``overlap``: dispatch the decode chunk BEFORE doing admissions, so
-    table uploads + prefills + first-token picks enqueue behind the
-    in-flight chunk instead of stalling it (JAX async dispatch); the
-    first-token host readback defers to the next sync point. The
-    exposed (un-overlapped) admission time is reported as
-    ``last_bubble_frac`` and the ``serve.admit_bubble_frac`` gauge.
+    ``overlap``: enqueue a round's admissions (table uploads +
+    prefills + first-token picks) and THEN the decode chunk for every
+    live row, the admitted ones included, before reading back the
+    first tokens: a first token waits for its own prefill, with the
+    chunk queued behind it (JAX async dispatch), and the chunk's start
+    cursors come from the host mirror ``_pos_host``. ``overlap=False``
+    is the serial order (admit, read the first tokens, dispatch). The
+    exposed admission time (host admission work with no chunk in
+    flight) is reported as ``last_bubble_frac`` and the
+    ``serve.admit_bubble_frac`` gauge.
 
     ``temperature``/``top_k``/``seed``: sampling in the engine.
     temperature <= 0 (default) is greedy — the token-exact serving
@@ -1107,6 +1112,9 @@ class EngineCore:
         self._prefill_total_tokens = 0
         self._table = table  # host mirror
         self.pos = jnp.zeros((slots,), jnp.int32)
+        #: host mirror of ``pos``: written wherever ``pos`` is, so a
+        #: chunk's dispatch knows its start cursors without a readback
+        self._pos_host = np.zeros((slots,), np.int32)
         self.limit = jnp.zeros((slots,), jnp.int32)
         self.tokens = jnp.zeros((slots,), jnp.int32)
         self.keys = jnp.zeros((slots, 2), jnp.uint32)
@@ -1526,7 +1534,8 @@ class EngineCore:
         metricslib.get_metrics().gauge("serve.queue_depth").set(
             len(self._queue))
 
-    def _try_admit(self, overlapped: bool = False) -> int:
+    def _try_admit(self, overlapped: bool = False,
+                   ahead: bool = False) -> int:
         """ONE admission pass per scheduler round: shed, then walk the
         queue in admission order — priority classes first, resumes
         before fresh arrivals within a class, FCFS with skip inside
@@ -1547,12 +1556,14 @@ class EngineCore:
         need — such a request waits for the next round's pass (it
         keeps its place in the admission order, so nothing starves;
         re-walking the queue per admission would put the trie work
-        back in the admission window). Returns the number
-        admitted."""
+        back in the admission window). ``overlapped``: a decode chunk
+        is in flight behind which the admissions enqueue; ``ahead``:
+        this round's chunk, carrying rows admitted earlier, is
+        dispatched after them. Returns the number admitted."""
         with metricslib.span("serve.admit_pass", round=self._round):
-            return self._admit_pass(overlapped)
+            return self._admit_pass(overlapped, ahead)
 
-    def _admit_pass(self, overlapped: bool) -> int:
+    def _admit_pass(self, overlapped: bool, ahead: bool) -> int:
         self._shed_expired()
         # one pass-start stamp: every request seated THIS round closes
         # its queued segment here — the span from pass start to its
@@ -1586,13 +1597,13 @@ class EngineCore:
             # holding ndarrays, so list.remove/__eq__ would be both
             # ambiguous and wrong here
             self._queue = [r for r in self._queue if r is not req]
-            self._admit(free_slot, req, overlapped, chain=chain,
+            self._admit(free_slot, req, overlapped, ahead, chain=chain,
                         t_pass=t_pass)
             admitted += 1
         return admitted
 
     def _admit(self, slot: int, req: Request, overlapped: bool,
-               chain: list[int] | None = None,
+               ahead: bool, chain: list[int] | None = None,
                t_pass: float | None = None):
         """Dispatch-only admission: every device op (table upload,
         prefill, first-token pick, cursor seeding) enqueues without a
@@ -1652,7 +1663,7 @@ class EngineCore:
         M = m * self.page_size
         span_attrs = dict(prompt_len=T, padded_len=padded, matched=M,
                           seq_id=req.seq_id, slot=slot,
-                          overlapped=overlapped)
+                          overlapped=overlapped, ahead=ahead)
         if self.state_bytes:
             span_attrs["state_bytes"] = self.state_bytes // self.slots
         # whole pages from the matched prefix on, every layer's K and V
@@ -1720,8 +1731,8 @@ class EngineCore:
                 if k != "table":
                     self.dcache[k] = v
         first_dev = None
-        # the install donates the cursors, which the chunk in flight may
-        # still own: a host span that can wait for the device
+        # the install donates the cursors, which a program still queued
+        # may own: a host span that can wait for the device
         install = metricslib.span("serve.admit_row", seq_id=req.seq_id,
                                   slot=slot)
         if self.cfg.block_len:
@@ -1737,7 +1748,7 @@ class EngineCore:
                     self.pos, self.limit, self.blk, self.msk, self.fidx,
                     self.nfw, slot, start, T + req.max_new,
                     jnp.asarray(first), T - start)
-            self._slots[slot].cursor = start
+            self._pos_host[slot] = start
             self.stats[req.seq_id]["blocks"] = []
         else:
             key = req.key if req.key is not None else self.request_key(
@@ -1754,6 +1765,7 @@ class EngineCore:
                     slot, T, req.max_new, state, row_state or None,
                     eos_id=self.eos_id, greedy=self.greedy,
                     top_k=self.top_k)
+            self._pos_host[slot] = T
             if state is not None:
                 self.cache.update(state)
         st = self._slots[slot]
@@ -1776,9 +1788,9 @@ class EngineCore:
             # all admission device work (table upload, prefill, first-
             # token pick) is now enqueued; the first-token readback in
             # _resolve_pending closes this window. Per-slot SUBTRACK
-            # (track=slot+1): overlapped admissions run concurrently
-            # with the decode chunk (track 0) by design, and Chrome
-            # sync slices on one track must nest
+            # (track=slot+1): an admission's window runs across the
+            # decode chunk's (track 0) by design, and Chrome sync
+            # slices on one track must nest
             st.t_dispatch = rec.mark_dispatch(
                 "serve.admit", {"seq_id": req.seq_id, "slot": slot,
                                 "padded_len": padded,
@@ -1789,7 +1801,7 @@ class EngineCore:
         self._emit(kind="serve_admit", seq_id=req.seq_id, slot=slot,
                    pages=need, prompt_len=T, padded_len=padded,
                    budget=req.max_new, overlapped=overlapped,
-                   free_pages=len(self.free_pages),
+                   ahead=ahead, free_pages=len(self.free_pages),
                    queued=len(self._queue), priority=req.priority,
                    resumed=req.resume_prefix is not None,
                    matched_tokens=M, shared_pages=m)
@@ -1806,6 +1818,8 @@ class EngineCore:
             mx.counter("serve.admitted").inc()
             if overlapped:
                 mx.counter("serve.admit_overlapped").inc()
+            if ahead:
+                mx.counter("serve.admit_ahead").inc()
             if m:
                 mx.counter("serve.prefix_matched_pages").inc(m)
                 mx.counter("serve.prefill_skip_tokens").inc(M)
@@ -1831,10 +1845,10 @@ class EngineCore:
 
     def _resolve_pending(self):
         """Host bookkeeping deferred from :meth:`_admit`: read back the
-        first tokens (by now computed behind — or overlapped with — the
-        decode chunk), stamp TTFT, and finish rows that were done at
-        admission (budget 1, or eos as the first token; the device-side
-        limit already froze them out of the chunks)."""
+        first tokens (computed ahead of this round's decode chunk, which
+        is queued behind them), stamp TTFT, and finish rows that were
+        done at admission (budget 1, or eos as the first token; the
+        device-side limit already froze them out of the chunks)."""
         for slot in self._pending:
             st = self._slots[slot]
             with metricslib.span("serve.first_token", seq_id=st.seq_id,
@@ -1902,6 +1916,7 @@ class EngineCore:
                 self.dcache["table"] = self._upload_table()
             self._slots[slot] = _Slot()
             self.pos = self.pos.at[slot].set(0)
+            self._pos_host[slot] = 0
             self.limit = self.limit.at[slot].set(0)
 
     def _upload_table(self, slot: int | None = None):
@@ -2122,18 +2137,10 @@ class EngineCore:
     def _dispatch_chunk(self):
         """Enqueue one ``chunk`` dispatch for the currently active rows
         and return the in-flight handle (participants, their start
-        cursors, the un-read token block) — no readback here."""
-        with metricslib.span("serve.cursor_sync", site="dispatch",
-                             round=self._round):
-            # a true COPY, not np.asarray: on CPU that returns a
-            # zero-copy view of the device buffer, and _chunk_step
-            # DONATES it — an executable that honors the donation
-            # (cache-loaded ones do) overwrites the "snapshot" in place
-            # with the post-chunk cursors
-            # jaxlint: disable=host-sync-in-dispatch — the copy is the
-            # PR 2 donation-alias fix; it syncs only on the PREVIOUS
-            # chunk's cursors, which _collect_chunk already resolved
-            pos_start = np.array(self.pos)
+        cursors, the un-read token block, the rows themselves) — no
+        readback here: the start cursors are the host's mirror."""
+        # a COPY: the collect reads these after the mirror moves on
+        pos_start = self._pos_host.copy()
         parts = [i for i, s in enumerate(self._slots) if s.active]
         win = {}
         if self.cfg.window:
@@ -2165,10 +2172,13 @@ class EngineCore:
         t_disp = (rec.mark_dispatch(
             "serve.chunk", {"chunk": self.chunk, "rows": len(parts)})
             if rec is not None else 0.0)
-        return parts, pos_start, out, t_disp
+        # the rows the chunk carries, by identity: a first token read
+        # after the dispatch can finish a row admitted this round, and
+        # an install behind the chunk can seat another in its slot
+        return parts, pos_start, out, t_disp, [self._slots[i] for i in parts]
 
     def _collect_chunk(self, inflight):
-        parts, pos_start, out, t_disp = inflight
+        parts, pos_start, out, t_disp, rows = inflight
         with metricslib.span("serve.decode_round", chunk=self.chunk):
             out = np.asarray(out)  # (chunk, slots); readback = sync
         rec = tracelib.active()
@@ -2190,9 +2200,8 @@ class EngineCore:
         now = time.perf_counter()
         with metricslib.span("serve.collect_rows", rows=len(parts),
                              round=self._round):
-            for i in parts:
-                st = self._slots[i]
-                if not st.active:
+            for i, st in zip(parts, rows):
+                if self._slots[i] is not st:
                     continue
                 valid = int(np.clip(limit_new[i] - pos_start[i], 0,
                                     self.chunk))
@@ -2200,27 +2209,29 @@ class EngineCore:
                 rec_s = self.stats.get(st.seq_id)
                 if rec_s is not None and valid:
                     rec_s.setdefault("token_ts", []).extend([now] * valid)
+                self._pos_host[i] = pos_start[i] + valid
                 if pos_start[i] + valid >= limit_new[i]:
                     self._finish(i)
 
     def _dispatch_block(self):
         """:meth:`_dispatch_chunk` for a block-diffusion model: ``chunk``
         block FORWARDS of :func:`_block_chunk` in one dispatch. The live
-        rows' block starts are the host's own mirror (``_Slot.cursor``):
+        rows' block starts are the host's own mirror (``_pos_host``):
         nothing is read back here."""
         parts = [i for i, s in enumerate(self._slots) if s.active]
+        start = self._pos_host[parts]
         # the stored positions the live rows attend over beside their own
         # blocks
-        ctx = sum(self._slots[i].cursor for i in parts)
+        ctx = int(start.sum())
         # the pages of a K/V head a forward fetches: up to its block's end
         end = self.cfg.block_len - 1
         with metricslib.span("serve.decode_dispatch", chunk=self.chunk,
                              forwards=self.chunk, rows=len(parts),
                              block=self.cfg.block_len, round=self._round,
                              ctx_tokens=ctx,
-                             kv_pages=lambda: sum(
-                                 (self._slots[i].cursor + end)
-                                 // self.page_size + 1 for i in parts)), \
+                             kv_pages=lambda: int(
+                                 ((start + end) // self.page_size
+                                  + 1).sum())), \
                 tracelib.compile_watch("serving._block_chunk",
                                        _block_chunk, forwards=self.chunk):
             (self.cache, self.pos, self.blk, self.msk, self.fidx, self.nfw,
@@ -2264,20 +2275,22 @@ class EngineCore:
                 end = st.prompt_len + st.budget
                 rec_s = self.stats.get(st.seq_id)
                 new = 0
+                cursor = int(self._pos_host[i])
                 for f in np.nonzero(commit[:, i])[0]:
-                    lo = max(0, st.prompt_len - st.cursor)
-                    hi = min(B, end - st.cursor)
+                    lo = max(0, st.prompt_len - cursor)
+                    hi = min(B, end - cursor)
                     st.out.extend(int(t) for t in toks[f, i, lo:hi])
                     new += hi - lo
-                    st.cursor += B
+                    cursor += B
                     if rec_s is not None:
                         rec_s["blocks"].append(
                             np.stack([toks[f, i], fidx[f, i]]))
+                self._pos_host[i] = cursor
                 if new and len(st.out) == new:
                     self._first_tokens(i, now)
                 if rec_s is not None and new:
                     rec_s.setdefault("token_ts", []).extend([now] * new)
-                if st.cursor >= end:
+                if cursor >= end:
                     self._finish(i)
         self._emit(kind="serve_block_chunk", round=self._round,
                    rows=len(parts), ctx_tokens=ctx, forwards=self.chunk,
@@ -2408,6 +2421,7 @@ class EngineCore:
                 st = self._slots[i]
                 if not st.active:
                     continue
+                self._pos_host[i] = pos_np[i]
                 accepted = 0
                 for k in range(advs.shape[0]):
                     v = int(advs[k, i])
@@ -2425,9 +2439,11 @@ class EngineCore:
         """ONE scheduler round — the core's unit of work, shared by
         :meth:`ContinuousBatcher.run` and the serving plane's router
         (which interleaves rounds across replicas): chaos probe,
-        preemption policy, decode-chunk dispatch (overlap mode:
-        FIRST, so admissions enqueue behind it), one admission pass,
-        deferred first-token readbacks, collect.
+        preemption policy, one admission pass, then (overlap mode) the
+        decode-chunk dispatch for every live row, the admitted ones
+        included, so the prefills run ahead of it, then the deferred
+        first-token readbacks, collect. ``overlap=False`` reads the
+        first tokens before the dispatch.
 
         ``decode=False`` is the PREFILL-ROLE round: admissions run
         (table upload, bucket-padded prefill, first-token pick) but no
@@ -2436,11 +2452,11 @@ class EngineCore:
         called with ``overlapped`` (True iff a decode chunk is in
         flight) AFTER admissions and BEFORE the chunk readback — the
         plane installs arrived KV migrations here, so the install's
-        device work enqueues behind the in-flight chunk exactly like an
-        overlapped admission. Returns ``{"admitted", "exposed_s"
-        (admission host time with nothing in flight), "stalled" (queue
-        waits but nothing admitted and nothing runs — the transport
-        decides whether that is a deadlock), "active"}``.
+        device work enqueues behind the in-flight chunk. Returns
+        ``{"admitted", "exposed_s" (admission host time with nothing in
+        flight), "stalled" (queue waits but nothing admitted and
+        nothing runs — the transport decides whether that is a
+        deadlock), "active"}``.
 
         The whole round is one ``serve.round`` span, its phases the
         children (docs/observability.md has the tree)."""
@@ -2485,30 +2501,34 @@ class EngineCore:
             dispatch, collect = self._dispatch_block, self._collect_block
         inflight = None
         t_chunk0 = 0.0
-        if decode and self.overlap and any(s.active for s in self._slots):
+        # overlap mode: the admissions' device work goes ahead of the
+        # chunk, which then carries the admitted rows too, and a first
+        # token's readback waits for its own prefill alone
+        admit_first = decode and self.overlap
+        live = any(s.active for s in self._slots)
+        t0 = time.perf_counter()
+        admitted = self._try_admit(ahead=admit_first and live)
+        if admit_first and any(s.active for s in self._slots):
+            exposed_s = time.perf_counter() - t0
             inflight = dispatch()
             t_chunk0 = time.perf_counter()
-        t0 = time.perf_counter()
-        admitted = self._try_admit(overlapped=inflight is not None)
         self._resolve_pending()
-        exposed_s = 0.0
-        stalled = False
         if inflight is None:
             exposed_s = time.perf_counter() - t0
             if decode and any(s.active for s in self._slots):
                 inflight = dispatch()
                 t_chunk0 = time.perf_counter()
-            elif not any(s.active for s in self._slots):
-                stalled = (bool(self._queue) and not admitted
-                           and not self._swapped
-                           and not self._prefetching)
+        stalled = (inflight is None
+                   and not any(s.active for s in self._slots)
+                   and bool(self._queue) and not admitted
+                   and not self._swapped and not self._prefetching)
         if self.residency is not None:
             self._install_prefetched(inflight is not None)
         if pre_collect is not None:
             pre_collect(inflight is not None)
         if inflight is not None:
             # children: the readback (serve.decode_round /
-            # serve.spec_round), the second one of the cursors
+            # serve.spec_round), the limits' readback
             # (serve.cursor_sync), the walk over the rows
             # (serve.collect_rows, a finished row's serve.finish in it)
             with metricslib.span("serve.collect", rows=len(inflight[0]),
@@ -2824,6 +2844,7 @@ class EngineCore:
                         jnp.asarray(pl)[m:] if m else jnp.asarray(pl))
                     for pool, pl in zip(pools, payload))
         self.pos = self.pos.at[slot].set(jnp.int32(bundle.pos))
+        self._pos_host[slot] = bundle.pos
         self.limit = self.limit.at[slot].set(jnp.int32(bundle.limit))
         self.tokens = self.tokens.at[slot].set(jnp.int32(bundle.token))
         self.keys = self.keys.at[slot].set(
@@ -3155,16 +3176,19 @@ class ContinuousBatcher(EngineCore):
         Returns ``finished``: {seq_id: np.ndarray of emitted tokens
         (<= max_new; ends at eos_id when enabled)}.
 
-        Loop shape (``overlap=True``): DISPATCH the chunk for the rows
-        already running, then do this round's admissions behind it —
+        Loop shape (``overlap=True``): this round's admissions first —
         the table uploads, bucket-padded prefills, and first-token
-        picks all enqueue while the chunk executes, and the chunk's
-        readback is the sync point that also resolves them. Admission
-        host time with no decode in flight (the first wave, or an
-        admission-only iteration) is the ADMISSION BUBBLE; its fraction
-        of the run lands in ``last_bubble_frac`` and the
-        ``serve.admit_bubble_frac`` gauge. ``overlap=False`` keeps the
-        serial order (admit, then decode) — the measurable baseline.
+        picks enqueue — then the chunk for every live row, the
+        admitted ones included, queued behind them; the first-token
+        readbacks come after that dispatch and wait for the prefills
+        alone, and the chunk's readback is the round's last sync.
+        Admission host time with no decode in flight (all of it, in
+        this order) is the ADMISSION BUBBLE; the device runs the
+        prefills under all of it but the host work before the first
+        prefill's dispatch. Its fraction of the run lands in
+        ``last_bubble_frac`` and the ``serve.admit_bubble_frac`` gauge.
+        ``overlap=False`` keeps the serial order (admit, read the first
+        tokens, then decode) — the measurable baseline.
 
         ``arrivals``: OPEN-loop traffic — ``(t_rel_s, submit_kwargs)``
         pairs; each is submitted once the run clock passes its arrival
@@ -3183,7 +3207,7 @@ class ContinuousBatcher(EngineCore):
         ``engine_round`` site fires first (a seeded stalled-host fault
         pauses the real loop), then the preemption policy runs at the
         chunk boundary (nothing in flight), then the ordinary
-        dispatch/admit/collect round."""
+        admit/dispatch/collect round."""
         t_run0 = time.perf_counter()
         t_exposed = 0.0
         pending_arrivals = (deque(sorted(arrivals, key=lambda a: a[0]))

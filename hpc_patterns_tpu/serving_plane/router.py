@@ -34,10 +34,10 @@ The migration pipeline per plane round (the overlap discipline):
    ``migration.migrate_pages`` async ``device_put``, or the socket
    codec's byte round-trip), before that replica's decode chunk of
    the round;
-3. the decode replica's round dispatches its chunk FIRST, then
-   installs arrived bundles BEHIND it (``service_round``'s
-   ``pre_collect`` hook → ``install_migration``), exactly like
-   round-6 overlapped admission — the handoff hides behind compute;
+3. the decode replica's round dispatches its chunk (after its own
+   admissions, if it has any), then installs arrived bundles BEHIND
+   it (``service_round``'s ``pre_collect`` hook →
+   ``install_migration``) — the handoff hides behind compute;
 4. after the chunk readback the install is confirmed
    (``block_until_ready`` on the seeded cursors — completion
    measurement, the ``_ready_in_span`` contract) and the migration

@@ -1,6 +1,6 @@
 """The engine's span tree (models/serving.py through harness/metrics.span):
 one ``serve.round`` a scheduler round with its phases as children down to
-the statement that waits (a cursor's readback, a table's upload, a
+the statement that waits (the limits' readback, a table's upload, a
 finished row's release), a request's spans under one ``seq_id``, the no-op
 path when nothing listens, ``jit.compiled`` from compile_watch and
 ``jit.event`` from the jax.monitoring listener when the registry mirrors
@@ -89,7 +89,6 @@ TREE = [
     "serve.idle_wait",
     "serve.round",
     "serve.round/serve.preempt_policy",
-    "serve.round/serve.cursor_sync",
     "serve.round/serve.admit_pass",
     "serve.round/serve.admit_pass/serve.table_upload",
     "serve.round/serve.admit_pass/serve.prefill",
@@ -134,7 +133,6 @@ def test_round_rises_by_one_a_round_and_children_share_it(recorded):
 @pytest.mark.parametrize("span,keys", [
     ("serve.arrivals", {"n"}),
     ("serve.arrivals/serve.submit", {"seq_id", "late_ms"}),
-    ("serve.round/serve.cursor_sync", {"site", "round"}),
     ("serve.round/serve.admit_pass/serve.table_upload", {"bytes"}),
     ("serve.round/serve.admit_pass/serve.admit_row", {"seq_id", "slot"}),
     ("serve.round/serve.collect/serve.cursor_sync", {"site", "round"}),
@@ -144,8 +142,8 @@ def test_round_rises_by_one_a_round_and_children_share_it(recorded):
     (f"{FINISH}/serve.release/serve.table_upload", {"bytes"}),
     ("serve.round", {"round", "rows", "queued"}),
     ("serve.round/serve.admit_pass/serve.prefill",
-     {"seq_id", "slot", "overlapped", "prompt_len", "padded_len", "matched",
-      "queued_ms"}),
+     {"seq_id", "slot", "overlapped", "ahead", "prompt_len", "padded_len",
+      "matched", "queued_ms"}),
     ("serve.round/serve.first_token", {"seq_id", "slot"}),
     ("serve.round/serve.decode_dispatch",
      {"rows", "chunk", "round", "ctx_tokens", "kv_pages"}),
@@ -162,8 +160,6 @@ def test_spans_carry_their_attributes(recorded, span, keys):
 def test_the_new_attributes_say_what_they_name(recorded):
     begun, _ = recorded
     by = lambda path: [a for p, a in begun if p == path]
-    assert {a["site"] for a in by("serve.round/serve.cursor_sync")} \
-        == {"dispatch"}
     assert {a["site"] for a in by(
         "serve.round/serve.collect/serve.cursor_sync")} == {"collect"}
     # a table of 2 slots x 3 pages of int32, or one row of it

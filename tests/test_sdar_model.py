@@ -351,6 +351,31 @@ def test_engine_tokens_and_forward_indices_are_generates(served, refs):
             assert rec[1].tolist() == fidx.tolist()
 
 
+def test_the_serial_order_serves_the_same_blocks(model, refs):
+    """An admitted row joins the chunk of its own round (``overlap``) or
+    of the next (serial order): the same blocks, settled by the same
+    forwards, and the tokens are the reference's in both."""
+    cfg, params, m = model
+    rule = dict(rule="static", steps=2, threshold=0.9)
+    prompts = [tokens(P, seed=10 + i) for i, (P, _) in enumerate(REQUESTS)]
+    runs = []
+    for overlap in (True, False):
+        eng = engine(cfg, params, overlap=overlap, unmask_rule="static",
+                     unmask_steps=2)
+        for p, (_, n) in zip(prompts, REQUESTS):
+            eng.submit(p, n)
+        runs.append((eng.run(), eng.stats))
+    (out, stats), (serial, serial_stats) = runs
+    for sid, (p, (_, n)) in enumerate(zip(prompts, REQUESTS)):
+        want, _ = ref.generate(params, p, n, m, forward_fn=refs[0],
+                               pad_to=PAD, **rule)
+        assert out[sid].tolist() == serial[sid].tolist() == want.tolist()
+        assert len(stats[sid]["blocks"]) == len(serial_stats[sid]["blocks"])
+        for got, base in zip(stats[sid]["blocks"],
+                             serial_stats[sid]["blocks"]):
+            np.testing.assert_array_equal(got, base)
+
+
 def test_a_row_hands_out_its_budget_whatever_the_block(served):
     eng, out, prompts, rule, (cfg, _, _) = served
     B = cfg.block_len

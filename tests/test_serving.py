@@ -8,16 +8,22 @@ assisted SAMPLING is the one law-only surface: the rejection-sampling
 rounds preserve the emitted distribution, not the draws — its oracle
 is distributional."""
 
+from types import SimpleNamespace
+
 import numpy as np
 import pytest
 
 import jax
 import jax.numpy as jnp
 
+from hpc_patterns_tpu.harness import metrics as metricslib
+from hpc_patterns_tpu.harness import trace as tracelib
 from hpc_patterns_tpu.models import TransformerConfig, init_params
+from hpc_patterns_tpu.models import serving
 from hpc_patterns_tpu.models.decode import paged_generate
 from hpc_patterns_tpu.models.serving import (
     ContinuousBatcher,
+    EngineCore,
     bucket_ladder,
     prefill_cache_size,
 )
@@ -481,7 +487,7 @@ class TestOverlappedAdmission:
     def test_admit_telemetry_has_overlap_fields(self):
         # first wave admits with nothing in flight (exposed — the
         # bubble); a request admitted into a freed slot while the OTHER
-        # row's chunk is dispatched records overlapped=True
+        # row runs is enqueued ahead of that row's next chunk
         cfg, params = _setup()
         events = []
         eng = ContinuousBatcher(params, cfg, slots=2, pool_pages=8,
@@ -496,9 +502,187 @@ class TestOverlappedAdmission:
         assert [e["seq_id"] for e in admits] == [0, 1, 2]
         for e in admits:
             assert e["padded_len"] == 8 and e["prompt_len"] == 5
-        assert admits[0]["overlapped"] is False
-        assert admits[1]["overlapped"] is False
-        assert admits[2]["overlapped"] is True
+        # no chunk is in flight when an admission is enqueued: the third
+        # goes AHEAD of the chunk that carries the running row
+        assert [e["overlapped"] for e in admits] == [False, False, False]
+        assert [e["ahead"] for e in admits] == [False, False, True]
+
+
+def _through_eos(tokens, eos):
+    """What an engine with ``eos`` emits of a standalone run's tokens:
+    everything up to and including the first end token."""
+    hit = np.flatnonzero(tokens == eos)
+    return tokens[:hit[0] + 1] if hit.size else tokens
+
+
+class TestAdmissionsAheadOfTheChunk:
+    """``overlap=True``: a round enqueues its admissions, then the chunk
+    for every live row (the admitted ones included), then reads the
+    first tokens back; the chunk's start cursors are the host's mirror
+    of ``pos``, written wherever ``pos`` is."""
+
+    def test_the_pos_mirror_is_the_devices_after_every_round(self):
+        # one run through two slots with an end token, a budget-1 row
+        # finished at admission in a round with a live row, a migrated
+        # row installed in the slot that row freed in the same round (a
+        # slot the chunk in flight still carries), a preemption and a
+        # resume, and every slot reused
+        cfg, params = _setup()
+        eos = 34   # first emitted by A at its 14th token, after its resume
+        pA = np.array([59, 3, 39, 9, 19], np.int32)
+        pB = np.arange(5, dtype=np.int32) + 20
+        pM = np.arange(6, dtype=np.int32) + 30
+        pP = np.arange(8, dtype=np.int32) + 7
+        geometry = dict(slots=2, pool_pages=6, pages_per_seq=4,
+                        page_size=8, chunk=2, eos_id=eos,
+                        prompt_buckets=(8, 16, 24, 32))
+        donor = EngineCore(params, cfg, **geometry)
+        donor.submit(pM, 6, seq_id=50)
+        donor.service_round(decode=False)
+        bundle = donor.export_migration(donor.exportable_slots()[0])
+        events = []
+        eng = ContinuousBatcher(params, cfg, preempt=True,
+                                emit=lambda **kw: events.append(kw),
+                                **geometry)
+        installed = []
+
+        def round_(pre_collect=None):
+            eng.service_round(pre_collect=pre_collect)
+            live = [i for i, s in enumerate(eng._slots) if s.active]
+            np.testing.assert_array_equal(eng._pos_host[live],
+                                          np.asarray(eng.pos)[live])
+
+        a = eng.submit(pA, 20, priority=1)
+        round_()
+        b = eng.submit(pB, 1, priority=1)
+        round_(lambda overlapped: installed.append(
+            eng.install_migration(bundle)))
+        p = eng.submit(pP, 4)
+        while eng.has_work():
+            round_()
+        admits = [e for e in events if e["kind"] == "serve_admit"]
+        slot_of = {e["seq_id"]: e["slot"] for e in admits}
+        assert installed == [slot_of[b]]
+        assert [e["seq_id"] for e in admits] == [a, b, p, a]
+        assert [e["ahead"] for e in admits] == [False, True, True, True]
+        assert eng.stats[a]["preemptions"] == 1
+        for sid, prompt, budget in ((a, pA, 20), (b, pB, 1), (50, pM, 6),
+                                    (p, pP, 4)):
+            want = _through_eos(_standalone(params, cfg, prompt, budget),
+                                eos)
+            np.testing.assert_array_equal(eng.finished[sid], want,
+                                          err_msg=f"seq {sid}")
+        assert len(eng.finished[a]) == 14
+        assert sorted(eng.free_pages) == list(range(6))
+
+    @pytest.mark.parametrize("overlap", [True, False])
+    def test_an_admission_is_enqueued_before_the_chunk_that_carries_it(
+            self, monkeypatch, overlap):
+        # with a row live, the admitted row's prefill and install are
+        # enqueued before this round's chunk, which carries the row: its
+        # second token comes out of the same round. overlap=False reads
+        # the first token before the chunk's dispatch
+        calls = []
+
+        def logged(name, fn):
+            def call(*a, **kw):
+                calls.append(name)
+                return fn(*a, **kw)
+            return call
+
+        for name in ("_prefill_one", "_admit_row", "_chunk_step"):
+            monkeypatch.setattr(serving, name,
+                                logged(name, getattr(serving, name)))
+        monkeypatch.setattr(
+            EngineCore, "_resolve_pending",
+            logged("first_tokens", EngineCore._resolve_pending))
+        cfg, params = _setup()
+        eng = ContinuousBatcher(params, cfg, slots=2, pool_pages=6,
+                                pages_per_seq=3, page_size=8, chunk=2,
+                                overlap=overlap)
+        eng.submit(np.arange(5, dtype=np.int32), 8)
+        eng.service_round()
+        calls.clear()
+        b = eng.submit(np.arange(6, dtype=np.int32) + 9, 8)
+        eng.service_round()
+        chunk_then_read = (["_chunk_step", "first_tokens"] if overlap
+                           else ["first_tokens", "_chunk_step"])
+        assert calls == ["_prefill_one", "_admit_row"] + chunk_then_read
+        [row] = [s for s in eng._slots if s.seq_id == b]
+        assert len(row.out) == 3   # the first token and the chunk's two
+
+    @pytest.mark.parametrize("sampled", [False, True],
+                             ids=["greedy", "sampled"])
+    def test_tokens_are_the_serial_orders_and_standalone(self, sampled):
+        cfg, params = _setup()
+        kw = dict(temperature=0.8, top_k=8, seed=3) if sampled else {}
+        reqs = _requests(cfg, 7, seed=37)
+        outs = []
+        for overlap in (True, False):
+            eng = ContinuousBatcher(params, cfg, slots=2, pool_pages=6,
+                                    pages_per_seq=3, page_size=8, chunk=2,
+                                    overlap=overlap, **kw)
+            ids = [eng.submit(p, m) for p, m in reqs]
+            got = eng.run()
+            outs.append([got[sid] for sid in ids])
+        for sid, ((prompt, max_new), x, y) in enumerate(zip(reqs, *outs)):
+            want = _standalone(
+                params, cfg, prompt, max_new,
+                **(dict(key=eng.request_key(sid), temperature=0.8, top_k=8)
+                   if sampled else {}))
+            np.testing.assert_array_equal(x, want, err_msg=f"seq {sid}")
+            np.testing.assert_array_equal(y, want, err_msg=f"seq {sid}")
+
+    def test_the_admit_ahead_counter_counts_admissions_ahead_of_live_rows(
+            self):
+        cfg, params = _setup()
+        eng = ContinuousBatcher(params, cfg, slots=2, pool_pages=8,
+                                pages_per_seq=4, page_size=8, chunk=2,
+                                prompt_buckets=(8, 12))
+        for budget in (2, 8, 2):
+            eng.submit(np.arange(5, dtype=np.int32), budget)
+        m = metricslib.configure(enabled=True)
+        try:
+            eng.run()
+            counters = m.snapshot()["counters"]
+        finally:
+            metricslib.configure(enabled=False)
+        assert counters["serve.admitted"] == 3
+        assert counters["serve.admit_ahead"] == 1
+        assert "serve.admit_overlapped" not in counters
+
+    def test_the_drivers_warm_up_leaves_nothing_to_compile_in_the_window(
+            self):
+        # the benchmark's own warm-up (chipbench/drivers/serve.warm) on a
+        # tiny engine, then a window whose admissions go ahead of live
+        # rows: nothing is traced, lowered or compiled in the window
+        from chipbench.drivers import serve as driver
+
+        cfg, params = _setup()
+        eng = driver.Engine(params, cfg, slots=2, pool_pages=8,
+                            pages_per_seq=4, page_size=8, chunk=2,
+                            prompt_buckets=(8, 16), overlap=True)
+        rng = np.random.RandomState(41)
+        prompts = [rng.randint(0, cfg.vocab, size=n).astype(np.int32)
+                   for n in (5, 11, 7, 14, 6)]
+        driver.warm(eng, [SimpleNamespace(prompt=p) for p in prompts],
+                    cfg.vocab)
+        compiled = prefill_cache_size()
+        n0 = len(tracelib.compile_events())
+        admits = []
+        record = eng._emit
+        eng._emit = lambda **kw: (admits.append(kw.get("ahead")),
+                                  record(**kw))
+        eng.run(arrivals=[(0.0, dict(prompt=p, max_new=m, seq_id=i))
+                          for i, (p, m) in enumerate(zip(prompts,
+                                                         (12, 3, 5, 4, 6)))])
+        assert sorted(eng.finished) == [0, 1, 2, 3, 4] + sorted(
+            sid for sid in eng.finished if sid >= driver.WARM_ID0)
+        assert any(admits)
+        assert [(kind, fn) for _, kind, _, fn in
+                tracelib.compile_events()[n0:]
+                if kind in ("trace", "lower", "backend_compile")] == []
+        assert prefill_cache_size() == compiled
 
 
 class TestPreemptionAndResume:
